@@ -1,5 +1,5 @@
 """Wire-codec subsystem: host-side compression + device-side
-decompression for the H2D tunnel, unified with the TCP shuffle and
+decompression for the H2D wire, unified with the TCP shuffle and
 spill tiers through one codec registry and one per-codec stats
 surface.  See registry.py for the architecture and
 docs/wire_compression.md for the operator view."""

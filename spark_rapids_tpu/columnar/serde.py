@@ -11,7 +11,7 @@ Format: MAGIC | version | codec | json header (names, dtypes, shapes)
 the shared wire-codec registry (columnar/compression/ — byte codecs:
 none, zlib; zstd/lz4 are not in this image, zlib is the stdlib
 stand-in), so TCP shuffle and the spill tiers report through the same
-per-codec stats surface as the H2D tunnel."""
+per-codec stats surface as the H2D wire."""
 
 from __future__ import annotations
 

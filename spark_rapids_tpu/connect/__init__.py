@@ -3,7 +3,7 @@
 The reference's defining capability is "the user's job, unchanged" — a
 real Spark hands its plans to the plugin seam (ref: SQLPlugin.scala:
 26-31) and the plugin accelerates whatever Catalyst produced.  The
-TPU-idiomatic mirror (ROADMAP #5, VERDICT missing #1) is this package:
+TPU-idiomatic mirror is this package:
 an external process serializes a plan, ships it over TCP, and the FULL
 serving stack executes it —
 

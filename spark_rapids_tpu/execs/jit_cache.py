@@ -314,8 +314,8 @@ def cached_jit(key: tuple, make_fn: Callable[[], Callable],
             # (absorb_once) for INJECTED compile faults: spill
             # unpinned buffers, re-check once.  Real XLA compilation
             # happens lazily at the wrapper's first invocation — a
-            # real compile OOM therefore surfaces at the CALLER, where
-            # the batch ladder / task retry / CPU degrade handle it
+            # real compile OOM therefore surfaces at the CALLER, and
+            # retry.classify calls it fatal: it propagates
             from spark_rapids_tpu.execs.retry import absorb_once
             from spark_rapids_tpu.robustness import faults as _faults
 

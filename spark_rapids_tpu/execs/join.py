@@ -262,7 +262,7 @@ class _HashJoinBase(TpuExec):
         probe for batch k+1 is dispatched before batch k's single
         pair-count readback, so JAX's async dispatch runs probe(k+1)
         concurrently with the readback wait — the one structural
-        serialization BENCH_r05 traced the Q3 deficit to (ref: the
+        serialization of the join stream loop (ref: the
         reference gets the same overlap from JoinGatherer's bounded
         gathers + the stream iterator's prefetch).
 

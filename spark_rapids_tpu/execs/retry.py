@@ -26,9 +26,11 @@ TPU analog — an ESCALATION LADDER, cheapest rung first:
    (the sick-executor blacklisting analog, applied in session.py).
 
 - `classify(exc)` / `is_retryable(exc)`: device/transient failures
-  (XLA RESOURCE_EXHAUSTED, UNAVAILABLE/DEADLINE_EXCEEDED link hiccups,
-  connection resets, our own reservation failures) are RETRYABLE;
-  everything else (assertion, user error) fails fast.  tpulint SRC008
+  (a RESOURCE_EXHAUSTED allocation at run time, UNAVAILABLE/
+  DEADLINE_EXCEEDED link hiccups, connection resets, our own
+  reservation failures) are RETRYABLE; everything else (assertion,
+  user error, and a RESOURCE_EXHAUSTED raised while COMPILING — the
+  same program overruns the same memory every time) fails fast.  tpulint SRC008
   flags broad `except` clauses in execs//io//shuffle/ that swallow
   exceptions without consulting this gate.
 - every rung reports absorbed injected faults to
@@ -79,6 +81,24 @@ SPLIT_MIN_ROWS = register(
     "never split further — the failure escalates to the whole-task "
     "retry (and ultimately the per-query CPU fallback) instead.",
     check=lambda v: v >= 1)
+
+#: substrings that mark a failure raised WHILE COMPILING a program: an
+#: XLA or Mosaic compile that overruns HBM or VMEM also says
+#: RESOURCE_EXHAUSTED, but the same program overruns the same memory on
+#: every attempt — spilling, splitting and backing off change nothing,
+#: and a CPU degrade would answer the query while the chip did none of
+#: it.  Checked BEFORE the retryable markers; such an error is fatal
+#: and propagates.  On a v5e (libtpu 0.0.34) a Pallas kernel over its
+#: VMEM says "RESOURCE_EXHAUSTED: Ran out of memory in memory space
+#: vmem while allocating on stack for %tpu_custom_call..."; XLA:TPU
+#: prefixes its own with "XLA:TPU compile permanent error".  What an
+#: allocation that fails at RUN time says there is "Error allocating
+#: device buffer: Attempting to allocate 1.00G. That was not
+#: possible.", which matches neither.
+_COMPILE_MARKERS = (
+    "compile permanent error",
+    "Ran out of memory in memory space",
+)
 
 #: substrings of device/transient error text that justify a retry.
 #: Deliberately NOT "INTERNAL": compiler/unsupported-HLO failures are
@@ -147,6 +167,8 @@ def is_retryable(exc: BaseException) -> bool:
         return True
     if isinstance(exc, RuntimeError):  # XlaRuntimeError subclasses it
         text = str(exc)
+        if any(m in text for m in _COMPILE_MARKERS):
+            return False
         return any(m in text for m in _RETRYABLE_MARKERS)
     return False
 

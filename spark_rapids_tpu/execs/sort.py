@@ -42,7 +42,7 @@ from spark_rapids_tpu.columnar.column import (
 from spark_rapids_tpu.config import register
 from spark_rapids_tpu.execs.base import MetricTimer, TOTAL_TIME, TpuExec
 from spark_rapids_tpu.exprs.base import EvalContext, Expression, bind_references
-from spark_rapids_tpu.ops.sort import SortOrder, sort_batch
+from spark_rapids_tpu.ops.sort import SortOrder, sort_batch, stable_argsort
 
 SORT_SINGLE_BATCH_ROWS = register(
     "spark.rapids.tpu.sql.sort.singleBatchRows", 1 << 21,
@@ -176,7 +176,7 @@ class TpuSortExec(_SortMixin):
         pid = bucket_ids(aug, bounds, self.aug_orders, n_parts - 1)
         live = aug.row_mask()
         key = jnp.where(live, pid, jnp.int32(n_parts))
-        order = jnp.argsort(key, stable=True)
+        order = stable_argsort(key)
         grouped = aug.gather(order, aug.num_rows)
         counts = jax.ops.segment_sum(live.astype(jnp.int32), key,
                                      num_segments=n_parts + 1)[:n_parts]
@@ -674,8 +674,8 @@ class TpuTopNExec(_SortMixin):
 
     Exactness argument: per batch, rows are pruned against the batch's
     n-th best PRIMARY key value under a monotone scalar image of the
-    primary order (floats canonicalize NaN to +inf and collapse ±0 —
-    order-preserving, possibly tie-collapsing).  Any row strictly worse
+    primary order (its float32 rounding, NaN canonicalized to +inf —
+    order-preserving, tie-collapsing).  Any row strictly worse
     than n rows on the primary alone cannot be in the global top n
     regardless of tiebreak keys, so keeping every row at-or-beyond the
     threshold (ties included, NULLs per null-placement) is a provable
@@ -715,28 +715,26 @@ class TpuTopNExec(_SortMixin):
     # -- traceable ------------------------------------------------------- #
 
     def _primary_scalar(self, kc):
-        """Monotone 'larger = selected by top_k' image of the primary
-        sort order (descending keeps the value sense; ascending flips
-        with overflow-safe bitwise NOT for ints)."""
+        """Monotone 'larger = selected by top_k' float32 image of the
+        primary sort order.  Rounding to float32 never reorders, it
+        only collapses near-ties, which the superset argument allows;
+        and top_k over a 32-bit operand is the one the TPU has a
+        kernel for (64-bit operands go through a two-operand sort that
+        compiles for a minute and a half)."""
         k0 = self.keys[0]
         d = kc.data
         if jnp.issubdtype(d.dtype, jnp.floating):
-            v = jnp.where(jnp.isnan(d), jnp.inf, d).astype(jnp.float64)
-            return v if k0.descending else -v
-        v = d.astype(jnp.int64)
-        return v if k0.descending else ~v
+            d = jnp.where(jnp.isnan(d), jnp.inf, d)
+        v = d.astype(jnp.float32)
+        return v if k0.descending else -v
 
     def _candidates(self, batch: ColumnarBatch) -> ColumnarBatch:
         ctx = EvalContext.for_batch(batch)
         kc = self.keys[0].expr.eval(ctx)
         live = batch.row_mask()
         valid = kc.validity & live
-        s = self._primary_scalar(kc)
-        if jnp.issubdtype(s.dtype, jnp.floating):
-            lo = jnp.asarray(-jnp.inf, s.dtype)
-        else:
-            lo = jnp.asarray(jnp.iinfo(jnp.int64).min, s.dtype)
-        sm = jnp.where(valid, s, lo)
+        sm = jnp.where(valid, self._primary_scalar(kc),
+                       jnp.float32(-jnp.inf))
         k = min(self.n, batch.capacity)
         thr = jax.lax.top_k(sm, k)[0][k - 1]
         mask = valid & (sm >= thr)

@@ -12,6 +12,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.column import AnyColumn, Column
@@ -59,8 +60,11 @@ def _leap(y):
     return ((y % 4 == 0) & (y % 100 != 0)) | (y % 400 == 0)
 
 
-_DAYS_IN_MONTH = jnp.asarray([31, 28, 31, 30, 31, 30, 31, 31, 30, 31, 30,
-                              31], jnp.int32)
+#: a host (numpy) constant: a jnp array at module level would initialise
+#: a JAX backend on import, and whoever imports the package first would
+#: own the chip
+_DAYS_IN_MONTH = np.asarray([31, 28, 31, 30, 31, 30, 31, 31, 30, 31, 30,
+                             31], np.int32)
 
 
 @dataclasses.dataclass(repr=False)

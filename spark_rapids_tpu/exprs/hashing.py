@@ -181,6 +181,15 @@ def hash_string_bytes(chars: jax.Array, lengths: jax.Array,
                                     seeds)
     if fast is not None:
         return fast
+    return hash_string_bytes_jnp(chars, lengths, seeds)
+
+
+def hash_string_bytes_jnp(chars: jax.Array, lengths: jax.Array,
+                          seeds: jax.Array) -> jax.Array:
+    """The jnp form of :func:`hash_string_bytes` (per-row uint32 seeds):
+    what every non-TPU backend runs, and the reference the Pallas
+    kernel is held bit-equal to on the chip."""
+    n, width = chars.shape
     h1 = seeds
     lengths = lengths.astype(jnp.int32)
     aligned = lengths - (lengths % 4)

@@ -9,11 +9,14 @@ The TPU version asks the PJRT client instead of CUDA:
 - `select_device(conf)` picks this process's chip
   (`spark.rapids.tpu.deviceOrdinal`, -1 = first of the preferred
   platform) — the 1-accelerator-per-executor model;
-- `initialize(conf)` sizes the spill store's HBM budget as a FRACTION
-  of the selected chip's actual memory when the runtime reports it
-  (memory_stats()['bytes_limit']), falling back to the static conf —
-  the computeRmmInitSizes analog — and installs a BufferStore wired to
-  that budget;
+- `store_budget(conf)` sizes the spill store's HBM budget as a
+  FRACTION of the selected chip's actual memory
+  (memory_stats()['bytes_limit']) — the computeRmmInitSizes analog;
+  the CPU backend, which reports host RAM, and an explicitly set
+  memory.hbm.budgetBytes keep the conf figure.  Every BufferStore
+  built without an explicit budget (the one a plain TpuSession gets)
+  is sized by it; `initialize(conf)` installs such a store eagerly
+  and returns the device it was sized for;
 - `HostBufferPool` is the pinned-host-pool analog: recycled numpy
   staging buffers for SYNCHRONOUS host paths (the spill serializer,
   columnar/serde.py).  jax exposes no true pinned allocations and its
@@ -94,17 +97,25 @@ def discover() -> list[DeviceInfo]:
 
     out = []
     for i, d in enumerate(jax.devices()):
-        mem = None
-        try:
-            stats = d.memory_stats()
-            if stats:
-                mem = stats.get("bytes_limit") or stats.get(
-                    "bytes_reservable_limit")
-        except Exception:
-            pass
+        # None on backends that keep no allocator statistics (CPU)
+        stats = d.memory_stats()
+        mem = (stats.get("bytes_limit")
+               or stats.get("bytes_reservable_limit")) if stats else None
         out.append(DeviceInfo(i, d.platform, getattr(d, "device_kind",
                                                      d.platform), mem))
     return out
+
+
+def device_fields() -> dict:
+    """The device JAX handed this process, as it reports it: what every
+    launcher's JSON carries, so that no round can be read as a chip
+    round without having been one."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
 
 
 def select_device(conf=None):
@@ -117,6 +128,13 @@ def select_device(conf=None):
     if 0 <= ordinal < len(devs):
         return devs[ordinal]
     return devs[0]
+
+
+def selected_info(conf=None) -> DeviceInfo:
+    """DeviceInfo of this process's device."""
+    import jax
+
+    return discover()[jax.devices().index(select_device(conf))]
 
 
 def effective_batch_size_rows(conf=None) -> int:
@@ -132,13 +150,7 @@ def effective_batch_size_rows(conf=None) -> int:
     rows = int(conf.get(BATCH_SIZE_ROWS))
     if not conf.get(BATCH_ROWS_AUTO) or rows != BATCH_SIZE_ROWS.default:
         return rows
-    try:
-        import jax
-
-        dev = select_device(conf)
-        info = discover()[jax.devices().index(dev)]
-    except Exception:
-        return rows
+    info = selected_info(conf)
     if not info.memory_bytes or info.platform == "cpu":
         # CPU test backends report host RAM as "device" memory
         return rows
@@ -148,28 +160,37 @@ def effective_batch_size_rows(conf=None) -> int:
     return int(min(max(scaled, rows), conf.get(MAX_CAPACITY)))
 
 
-def initialize(conf=None) -> "DeviceInfo":
-    """Size and install the process BufferStore from the selected
-    device's reported memory; returns the chosen device's info."""
-    from spark_rapids_tpu.memory.store import (
-        BufferStore,
-        HBM_BUDGET_BYTES,
-        reset_store,
-    )
+def store_budget(conf=None) -> int:
+    """The spill store's device budget in bytes: memory.fraction of the
+    selected chip's reported HBM.  The conf figure stands when it was
+    set explicitly, and on the CPU backend (which reports host RAM as
+    "device" memory, or nothing).  A real chip that reports no limit
+    is an error: a budget guessed for another chip is how a 16 GB part
+    gets a store that overruns it."""
+    from spark_rapids_tpu.memory.store import HBM_BUDGET_BYTES
 
     conf = conf or get_conf()
-    dev = select_device(conf)
-    import jax
-
-    ordinal = jax.devices().index(dev)
-    info = discover()[ordinal]
     budget = conf.get(HBM_BUDGET_BYTES)
-    if info.memory_bytes and info.platform != "cpu":
-        # CPU test backends report host RAM as "device" memory — the
-        # fraction sizing only makes sense against a real chip's HBM
-        budget = int(info.memory_bytes * conf.get(MEMORY_FRACTION))
-    reset_store(BufferStore(device_budget=budget))
-    return info
+    if budget != HBM_BUDGET_BYTES.default:
+        return budget
+    info = selected_info(conf)
+    if info.platform == "cpu":
+        return budget
+    if not info.memory_bytes:
+        raise RuntimeError(
+            f"{info.platform} device {info.ordinal} ({info.kind}) reports "
+            "no memory limit; set spark.rapids.tpu.memory.hbm.budgetBytes")
+    return int(info.memory_bytes * conf.get(MEMORY_FRACTION))
+
+
+def initialize(conf=None) -> DeviceInfo:
+    """Install the process BufferStore, sized by store_budget, now;
+    returns the chosen device's info."""
+    from spark_rapids_tpu.memory.store import BufferStore, reset_store
+
+    conf = conf or get_conf()
+    reset_store(BufferStore(device_budget=store_budget(conf)))
+    return selected_info(conf)
 
 
 class HostBufferPool:

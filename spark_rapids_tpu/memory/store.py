@@ -82,7 +82,10 @@ HBM_BUDGET_BYTES = register(
     "spark.rapids.tpu.memory.hbm.budgetBytes", 12 << 30,
     "Device-memory budget the buffer store manages batches within "
     "(ref: spark.rapids.memory.gpu.pool sizing, RapidsConf.scala:413). "
-    "Proactive: reservations beyond this trigger synchronous spill.")
+    "Proactive: reservations beyond this trigger synchronous spill.  "
+    "Left at its default on a TPU, the budget is memory.fraction of "
+    "the HBM the chip reports (memory/device_manager.store_budget); "
+    "the CPU backend and an explicit setting use this figure.")
 HOST_SPILL_BYTES = register(
     "spark.rapids.tpu.memory.host.spillStorageSize", 4 << 30,
     "Host-memory bound for spilled batches before they continue to disk "
@@ -467,8 +470,11 @@ class BufferStore:
         from spark_rapids_tpu.config import get_conf
 
         conf = get_conf()
-        self.device_budget = device_budget if device_budget is not None \
-            else conf.get(HBM_BUDGET_BYTES)
+        if device_budget is None:
+            from spark_rapids_tpu.memory.device_manager import store_budget
+
+            device_budget = store_budget(conf)
+        self.device_budget = device_budget
         self.host_budget = host_budget if host_budget is not None \
             else conf.get(HOST_SPILL_BYTES)
         self._spill_dir = spill_dir or conf.get(SPILL_DIR) or None

@@ -3,9 +3,10 @@
 Compiled lazily with the system toolchain into a per-source-hash
 shared object and loaded through ctypes (pybind11 is unavailable;
 a plain C ABI keeps the binding dependency-free).  Every native entry
-point has a numpy fallback in its caller, so a missing compiler only
-costs performance, never correctness — the same posture the reference
-takes toward its optional JNI acceleration libraries.
+point has a numpy fallback in its caller, which a machine WITHOUT a
+compiler takes: that is the one routing rule.  Where g++ exists, a
+build or load that fails is an error — a scan that quietly decodes
+through the slow path is a different program from the one measured.
 """
 
 from __future__ import annotations
@@ -13,6 +14,7 @@ from __future__ import annotations
 import ctypes
 import hashlib
 import os
+import shutil
 import subprocess
 import threading
 from typing import Optional
@@ -22,46 +24,44 @@ _tried = False
 _lock = threading.Lock()
 
 
-def _build(src: str, out: str) -> bool:
+def _build(src: str, out: str) -> None:
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
            src, "-o", out]
-    try:
-        r = subprocess.run(cmd, capture_output=True, timeout=120)
-        return r.returncode == 0 and os.path.exists(out)
-    except Exception:
-        return False
+    r = subprocess.run(cmd, capture_output=True, timeout=120)
+    if r.returncode != 0 or not os.path.exists(out):
+        raise RuntimeError(
+            f"native host codec failed to build ({' '.join(cmd)}):\n"
+            + r.stderr.decode(errors="replace")[-2000:])
 
 
 def load() -> Optional[ctypes.CDLL]:
-    """The host codec library, building it on first use; None when no
-    toolchain is available (callers fall back to numpy)."""
+    """The host codec library, building it on first use; None when the
+    machine has no g++ (callers fall back to numpy).  With g++ present
+    a failed build or load raises."""
     global _lib, _tried
     with _lock:
         if _tried:
             return _lib
-        _tried = True
         here = os.path.dirname(__file__)
         src = os.path.join(here, "hostcodec.cpp")
-        try:
-            with open(src, "rb") as f:
-                tag = hashlib.sha256(f.read()).hexdigest()[:16]
-        except OSError:
-            return None
+        with open(src, "rb") as f:
+            tag = hashlib.sha256(f.read()).hexdigest()[:16]
         build_dir = os.path.join(here, "_build")
         out = os.path.join(build_dir, f"hostcodec-{tag}.so")
         if not os.path.exists(out):
-            try:
-                os.makedirs(build_dir, exist_ok=True)
-            except OSError:
+            if shutil.which("g++") is None:
+                _tried = True
                 return None
-            if not _build(src, out):
-                return None
-        try:
-            lib = ctypes.CDLL(out)
-        except OSError:
-            return None
+            os.makedirs(build_dir, exist_ok=True)
+            # build beside the target and rename: two processes racing
+            # the first build must never load a half-written object
+            tmp = f"{out}.{os.getpid()}.tmp"
+            _build(src, tmp)
+            os.replace(tmp, out)
+        lib = ctypes.CDLL(out)
         _declare(lib)
         _lib = lib
+        _tried = True
         return _lib
 
 

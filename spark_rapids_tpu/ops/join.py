@@ -35,7 +35,11 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import AnyColumn, Column, StringColumn
 from spark_rapids_tpu.ops.groupby import _keys_equal_adjacent
-from spark_rapids_tpu.ops.sort import SortOrder, sort_permutation
+from spark_rapids_tpu.ops.sort import (
+    SortOrder,
+    sort_permutation,
+    stable_argsort,
+)
 
 
 def _pad_string_widths(a: StringColumn, b: StringColumn
@@ -82,7 +86,7 @@ def compute_gids(build_keys: list[AnyColumn], stream_keys: list[AnyColumn],
     # dead rows must not pollute groups: push them last by re-sorting on
     # (dead, key) — emulate by stable argsort on dead flag after key sort
     dead_sorted = jnp.take(~live, perm)
-    perm = jnp.take(perm, jnp.argsort(dead_sorted, stable=True))
+    perm = jnp.take(perm, stable_argsort(dead_sorted))
 
     sorted_cols = [c.gather(perm) for c in combined]
     live_sorted = jnp.take(live, perm)
@@ -135,8 +139,7 @@ def join_state(build: ColumnarBatch, stream: ColumnarBatch,
     starts = jnp.cumsum(counts) - counts
     # stable order of build rows by gid: row at starts[g]+j is the j-th
     # build row with gid g
-    build_sort = jnp.argsort(jnp.where(joinable_b, gid_b, capc),
-                             stable=True)
+    build_sort = stable_argsort(jnp.where(joinable_b, gid_b, capc))
 
     cnt = jnp.where(joinable_s, jnp.take(counts, gid_s), 0)
     matched_s = cnt > 0

@@ -11,9 +11,11 @@ full-width masked vector passes over HBM, while the kernel below walks
 the byte matrix ONCE per VMEM-resident row block.
 
 Kernels are bit-compatible with the jnp reference implementations in
-exprs/hashing.py (the same mix functions are imported), and every
-kernel has a jnp fallback: pallas.enabled=false, a non-TPU backend, or
-an awkward shape routes to the reference path.
+exprs/hashing.py (the same mix functions are imported).  Routing is by
+rule, never by failure: pallas.enabled=false, a non-TPU backend, or a
+string column wider than _MAX_WIDTH takes the jnp path; everything else
+on a TPU takes the kernel, and a kernel that fails to lower there is an
+error the caller sees.
 """
 
 from __future__ import annotations
@@ -41,13 +43,18 @@ _BLOCK_N = 1024  # rows per grid step: (8, 128) row tiles; W*1KB << VMEM
 _MAX_WIDTH = 128
 
 
+#: programs traced through the kernel route since process start — what
+#: chip_smoke.py reads to show a query really reached the kernel
+_ROUTED = 0
+
+
+def routed_count() -> int:
+    return _ROUTED
+
+
 def pallas_available() -> bool:
-    if not get_conf().get(PALLAS_ENABLED):
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return bool(get_conf().get(PALLAS_ENABLED)) \
+        and jax.default_backend() == "tpu"
 
 
 def _hash_string_kernel(chars_ref, lengths_ref, seed_ref, out_ref):
@@ -174,9 +181,11 @@ def maybe_pallas_hash_string(chars, lengths, seeds):
     the padded matrix — and the pad tail is masked by construction:
     padding rows hash garbage nobody reads (length 0 -> fmix of an
     empty string); the slice drops them inside the same program."""
+    global _ROUTED
     n, width = chars.shape
     if width > _MAX_WIDTH or not pallas_available():
         return None
+    _ROUTED += 1
     if n % _BLOCK_N != 0:
         pad = -n % _BLOCK_N
         chars = jnp.concatenate(

@@ -25,6 +25,7 @@ import numpy as np
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.exprs.base import EvalContext, Expression, bind_references
 from spark_rapids_tpu.exprs.hashing import partition_ids
+from spark_rapids_tpu.ops.sort import stable_argsort
 
 
 class Partitioning:
@@ -150,7 +151,7 @@ def split_batch_dispatch(batch: ColumnarBatch, pids: jax.Array,
     dispatch batch k+1's sort while batch k's counts are in flight."""
     live = batch.row_mask()
     key = jnp.where(live, pids, jnp.int32(n_parts))
-    order = jnp.argsort(key, stable=True)
+    order = stable_argsort(key)
     grouped = batch.gather(order, batch.num_rows)
     counts = jax.ops.segment_sum(live.astype(jnp.int32), key,
                                  num_segments=n_parts)

@@ -28,32 +28,22 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6 stable API
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import AnyColumn, Column, StringColumn
 from spark_rapids_tpu.exprs.hashing import partition_ids
+from spark_rapids_tpu.ops.sort import stable_argsort
 from spark_rapids_tpu.parallel.mesh import DATA_AXIS
-
-#: older jax spells shard_map's replication-check flag `check_rep`
-#: (the newer name is `check_vma`); probe once at import
-_SM_CHECK_KW = ("check_vma" if "check_vma"
-                in __import__("inspect").signature(shard_map).parameters
-                else "check_rep")
 
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map with the replication check off, spelled portably
-    across jax versions — every collective step / SPMD stage program
-    builds through this one wrapper."""
+    """shard_map with the replication check off — every collective
+    step / SPMD stage program builds through this one wrapper."""
     return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **{_SM_CHECK_KW: False})
+                     out_specs=out_specs, check_vma=False)
 
 
 def _sharded_jit(mapped) -> Callable:
@@ -205,7 +195,7 @@ def route_shard(batch: ColumnarBatch, pid: jax.Array,
     live = batch.row_mask()
     pid = jnp.where(live, pid, jnp.int32(n_dest))  # dead rows -> dropped
 
-    order = jnp.argsort(pid, stable=True)
+    order = stable_argsort(pid)
     spid = jnp.take(pid, order)
     # rank of each row within its destination group
     first_pos = jnp.searchsorted(spid, spid, side="left")
@@ -240,7 +230,7 @@ def route_shard(batch: ColumnarBatch, pid: jax.Array,
             recv_cols.append(Column(a2a(c.data), a2a(c.validity), c.dtype))
 
     # compact occupied rows to a prefix (stable: preserves sender order)
-    corder = jnp.argsort(~occ, stable=True)
+    corder = stable_argsort(~occ)
     n_out = jnp.sum(occ).astype(jnp.int32)
     out_live = jnp.arange(n_dest * cap, dtype=jnp.int32) < n_out
     out_cols: list[AnyColumn] = []

@@ -1,9 +1,8 @@
 """Speculative output sizing: predict data-dependent output counts so
 stream loops never block on a per-batch sizing readback.
 
-BENCH_r05 traced the two worst numbers in the suite (Q3 join at 0.248x
-CPU, Q1 at 0.566x) to the one remaining structural serialization: the
-per-batch device->host SIZING sync (join pair count, aggregate partial
+The join-heavy Q3 and the grouped Q1 keep one structural
+serialization: the per-batch device->host SIZING sync (join pair count, aggregate partial
 row count, exchange split counts) that the software pipeline can only
 defer by a single batch — the expansion/shrink for batch k still waits
 on batch k's count before it can dispatch.  The reference never pays

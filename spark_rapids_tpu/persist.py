@@ -104,7 +104,8 @@ PERSIST_XLA_CACHE = register(
     "<persist.dir>/xla on activation, so the backend compilation of "
     "restored (and fresh) programs is itself a disk hit in later "
     "processes.  Process-global jax config: the first activating "
-    "conf wins for the process lifetime (docs/warm_start.md).")
+    "conf wins for the process lifetime (docs/warm_start.md).  A "
+    "cache placed by JAX_COMPILATION_CACHE_DIR stays where it is.")
 
 #: bump when the entry layout changes: old-format files read as
 #: honest misses instead of parse errors
@@ -602,10 +603,12 @@ def active(conf=None) -> Optional[PersistStore]:
 def _activate_xla_cache_locked(root: str, conf) -> None:
     """Point jax's persistent compilation cache at <root>/xla (first
     activating dir wins for the process — the config is jax-global).
-    Failures are non-fatal: the AOT tier still works, restored
-    modules just pay a backend re-compile."""
+    A cache placed from outside (JAX_COMPILATION_CACHE_DIR) stays
+    where it was put.  Failures are non-fatal: the AOT tier still
+    works, restored modules just pay a backend re-compile."""
     global _XLA_CACHE_DIR, _XLA_PREV
-    if not bool(conf.get(PERSIST_XLA_CACHE)) or _XLA_CACHE_DIR:
+    if not bool(conf.get(PERSIST_XLA_CACHE)) or _XLA_CACHE_DIR \
+            or os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
     xdir = os.path.join(root, "xla")
     try:

@@ -82,15 +82,11 @@ class TpuPlugin:
         self.device_info = None
         self.block_server = None
         self.heartbeat_client = None
-        try:
-            # device discovery + memory-budget sizing (the
-            # GpuDeviceManager.initializeGpuAndMemory step); never
-            # fatal — a budget-from-conf store works everywhere
-            from spark_rapids_tpu.memory import device_manager
+        # device discovery + memory-budget sizing (the
+        # GpuDeviceManager.initializeGpuAndMemory step)
+        from spark_rapids_tpu.memory import device_manager
 
-            self.device_info = device_manager.initialize(self.conf)
-        except Exception:
-            pass
+        self.device_info = device_manager.initialize(self.conf)
         self._maybe_start_network_shuffle()
         atexit.register(self.shutdown)
 

@@ -185,7 +185,7 @@ class ShuffleBlockServer:
         """{'raw': bytes before codec, 'wire': framed bytes sent,
         'codec': this server's frame codec, 'codecs': the process-wide
         per-codec registry stats} — the shuffle tier's view of the ONE
-        stats surface the H2D tunnel and spill tiers also report
+        stats surface the H2D wire and spill tiers also report
         through (columnar/compression/; docs/wire_compression.md)."""
         from spark_rapids_tpu.columnar import compression as WC
 

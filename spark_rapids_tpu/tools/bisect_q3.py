@@ -1,7 +1,7 @@
-"""BENCH_r05 -> r06 q3 regression bisect: A/B the suspect layers.
+"""q3 regression bisect: A/B the suspect layers.
 
-BENCH_r06 ran q3 at 0.117x vs CPU where BENCH_r05 ran 0.248x — a 2.3x
-wall-clock regression on the join+groupby milestone.  The layers that
+BENCH_r06 ran q3 at 0.117x vs CPU where the round before it ran
+0.248x (neither record names its device).  The layers that
 landed between the rounds (fusion + buffer donation in PR11, SPMD
 stage execution in PR14) each ship a kill switch, so the regression is
 bisectable by CONF, not by checkout: every arm below re-runs the exact
@@ -83,6 +83,9 @@ def run_arm(fixture_dir: str, ev_dir: str, overrides: dict) -> dict:
     bench.reset_all_counters()
     tpu_ts, tpu_r = bench._time_collect(df, "tpu", 3)
     out = {"q3_tpu_s_median": round(statistics.median(tpu_ts), 4)}
+    from spark_rapids_tpu.memory.device_manager import device_fields
+
+    out.update(device_fields())
     out.update(bench._stats(tpu_ts, "q3_tpu"))
     out.update(bench._ledger_fields("q3", 3))
     out.update(bench._fusion_fields("q3", 3))
@@ -140,6 +143,7 @@ def main(out_path: str = "BISECT_q3_r07.json") -> int:
     os.makedirs(fixture)
     _make_fixture(fixture)
     ev_dirs = {}
+    failed: list = []
     for label, overrides in ARMS:
         ev_dir = os.path.join(tmp, f"ev_{label}")
         os.makedirs(ev_dir)
@@ -150,17 +154,19 @@ def main(out_path: str = "BISECT_q3_r07.json") -> int:
             "print('ARM_RESULT ' + json.dumps(run_arm(%r, %r, "
             "json.loads(sys.argv[1]))))"
             % (REPO, fixture, ev_dir))
+        # each arm's child owns the device in turn (this parent
+        # stays off jax) and runs on whatever platform the
+        # environment gives it — its record names it
         proc = subprocess.run(
             [sys.executable, "-c", child, json.dumps(overrides)],
-            capture_output=True, text=True, cwd=REPO,
-            env={**os.environ, "JAX_PLATFORMS":
-                 os.environ.get("JAX_PLATFORMS", "cpu")})
+            capture_output=True, text=True, cwd=REPO)
         line = next((ln for ln in proc.stdout.splitlines()
                      if ln.startswith("ARM_RESULT ")), None)
         if line is None:
             results["arms"][label] = {
                 "error": (proc.stderr or proc.stdout)[-2000:]}
             print(f"{label}: FAILED", file=sys.stderr)
+            failed.append(label)
             continue
         results["arms"][label] = json.loads(line[len("ARM_RESULT "):])
         print(f"{label}: q3_tpu_s_median="
@@ -176,6 +182,9 @@ def main(out_path: str = "BISECT_q3_r07.json") -> int:
     with open(out_path, "w") as f:
         json.dump(results, f, indent=1, sort_keys=True)
     print(f"wrote {out_path}")
+    if failed:
+        print(f"arms failed: {failed}", file=sys.stderr)
+        return 1
     return 0
 
 

@@ -126,7 +126,10 @@ def run_once(data_dir: str,
     jc = cache_stats()
     if persist_dir is not None:
         P.flush(timeout=30.0)
+    from spark_rapids_tpu.memory.device_manager import device_fields
+
     return {
+        **device_fields(),
         "wall_ms": round(wall_ms, 3),
         "digest": table_digest(r),
         "rows": r.num_rows,

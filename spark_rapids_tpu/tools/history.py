@@ -626,8 +626,8 @@ def _hc_dispatch_overhead(q: QueryRecord) -> Optional[str]:
     """HC010: dispatch-overhead-dominated query — the ledger recorded
     many program launches but the chip was busy for only a small
     share of the wall, so per-dispatch overhead (trace/compile-cache
-    lookup, host argument marshalling, link round trips on tunneled
-    backends) dominated.  The fusion/bucketing work of ROADMAP #2
+    lookup, host argument marshalling, blocking readbacks)
+    dominated.  The fusion/bucketing work of ROADMAP #2
     exists to collapse exactly this shape."""
     totals = q.program_totals()
     disp = totals.get("dispatches") or 0

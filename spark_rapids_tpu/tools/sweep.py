@@ -1,6 +1,6 @@
 """The 99-query TPC-DS sweep: classify every query's fate.
 
-BASELINE config #5's missing artifact (ROADMAP #5, VERDICT missing #2):
+BASELINE config #5's artifact:
 drive all 99 TPC-DS query texts (tools/tpcds_queries.py) through the
 SQL frontend against the deterministic mini catalog
 (tools/tpcds_schema.py) and classify each as

@@ -62,21 +62,15 @@ LEDGER_ENABLED = register(
     "per-query `programs` section (docs/device_ledger.md).  Off (the "
     "default) the only per-dispatch cost is one attribute read.")
 
-LEDGER_HBM_BYTES_PER_S = register(
-    "spark.rapids.tpu.trace.ledger.hbmBytesPerSec", 819e9,
-    "HBM bandwidth roofline of the chip (bytes/s; default: TPU v5e "
-    "~819 GB/s).  The single source of the roofline denominator: "
-    "bench.py's coarse hbm_roofline_fraction and the ledger's "
-    "attributed per-program fractions both divide by this, so the "
-    "constant cannot drift between them.",
-    check=lambda v: v > 0)
-
-LEDGER_PEAK_FLOPS = register(
-    "spark.rapids.tpu.trace.ledger.peakFlopsPerSec", 197e12,
-    "Compute roofline of the chip (FLOPs/s; default: TPU v5e bf16 "
-    "~197 TFLOP/s) — denominator of the ledger's attributed "
-    "flops-side roofline fraction.",
-    check=lambda v: v > 0)
+#: Published peaks of one chip, keyed by the ``device_kind`` JAX
+#: reports: (HBM bandwidth in bytes/s, bf16 matrix throughput in
+#: FLOP/s).  Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s
+#: bf16, 16 GB of HBM at 819 GB/s per chip).  A device that is not in
+#: the table has no roofline: its fractions are None, never another
+#: chip's.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (819e9, 197e12),
+}
 
 LEDGER_ROOFLINE_FLOOR = register(
     "spark.rapids.tpu.trace.ledger.health.rooflineFloor", 0.001,
@@ -98,21 +92,26 @@ LEDGER_OCCUPANCY_FLOOR = register(
     "(docs/occupancy.md).",
     check=lambda v: 0 <= v <= 1)
 
-#: the conf default, importable without a conf in hand (bench.py's
-#: module-level docs reference the same number the conf carries)
-DEFAULT_HBM_BYTES_PER_S = float(LEDGER_HBM_BYTES_PER_S.default)
+def device_peaks() -> tuple[Optional[float], Optional[float]]:
+    """(HBM bytes/s, FLOP/s) of this process's device from
+    DEVICE_PEAKS; (None, None) for a device the table does not hold."""
+    from spark_rapids_tpu.memory.device_manager import select_device
+
+    return DEVICE_PEAKS.get(select_device().device_kind, (None, None))
 
 
 def roofline_fraction(bytes_per_s: float,
-                      hbm_bytes_per_s: Optional[float] = None) -> float:
+                      hbm_bytes_per_s: Optional[float] = None
+                      ) -> Optional[float]:
     """THE roofline formula: achieved bytes/s over the chip's HBM
-    bandwidth.  One definition shared by bench.py's coarse cold/warm
-    quotients and the ledger's per-program attribution, so the formula
-    and the constant cannot drift apart."""
+    bandwidth (this device's, from DEVICE_PEAKS, unless given).  One
+    definition shared by bench.py's coarse cold/warm quotients and the
+    ledger's per-program attribution.  None on a device without a
+    published peak."""
     if hbm_bytes_per_s is None:
-        from spark_rapids_tpu.config import get_conf
-
-        hbm_bytes_per_s = float(get_conf().get(LEDGER_HBM_BYTES_PER_S))
+        hbm_bytes_per_s = device_peaks()[0]
+    if hbm_bytes_per_s is None:
+        return None
     return bytes_per_s / hbm_bytes_per_s
 
 
@@ -629,13 +628,12 @@ def summarize(programs: dict[str, dict], top_n: int = 5,
     Totals: device-time totals, a device-time-WEIGHTED roofline
     fraction, and the top-N programs by device time with their
     share."""
-    from spark_rapids_tpu.config import get_conf
-
-    conf = get_conf()
-    if hbm_bytes_per_s is None:
-        hbm_bytes_per_s = float(conf.get(LEDGER_HBM_BYTES_PER_S))
-    if peak_flops is None:
-        peak_flops = float(conf.get(LEDGER_PEAK_FLOPS))
+    if hbm_bytes_per_s is None or peak_flops is None:
+        table_hbm, table_flops = device_peaks()
+        if hbm_bytes_per_s is None:
+            hbm_bytes_per_s = table_hbm
+        if peak_flops is None:
+            peak_flops = table_flops
     enriched: dict[str, dict] = {}
     total_device_ms = 0.0
     total_dispatch_ms = 0.0
@@ -654,11 +652,13 @@ def summarize(programs: dict[str, dict], top_n: int = 5,
             fps = p["flops"] * p["dispatches"] / device_s
             e["bytes_per_s"] = round(bps, 1)
             e["flops_per_s"] = round(fps, 1)
-            e["roofline"] = round(
-                roofline_fraction(bps, hbm_bytes_per_s), 6)
-            e["flops_fraction"] = round(fps / peak_flops, 9)
-            weighted_roofline += e["roofline"] * p["device_ms"]
-            weighted_known_ms += p["device_ms"]
+            e["roofline"] = round(bps / hbm_bytes_per_s, 6) \
+                if hbm_bytes_per_s else None
+            e["flops_fraction"] = round(fps / peak_flops, 9) \
+                if peak_flops else None
+            if e["roofline"] is not None:
+                weighted_roofline += e["roofline"] * p["device_ms"]
+                weighted_known_ms += p["device_ms"]
         else:
             e["bytes_per_s"] = e["flops_per_s"] = None
             e["roofline"] = e["flops_fraction"] = None
@@ -702,11 +702,8 @@ def per_op(programs: dict[str, dict],
     explain('analyze') per-operator roofline column: per op —
     dispatches, device_ms, and the attributed roofline over the op's
     own device time (cost-model bytes x dispatches / device time)."""
-    from spark_rapids_tpu.config import get_conf
-
     if hbm_bytes_per_s is None:
-        hbm_bytes_per_s = float(
-            get_conf().get(LEDGER_HBM_BYTES_PER_S))
+        hbm_bytes_per_s = device_peaks()[0]
     acc: dict[str, dict] = {}
     for p in programs.values():
         op = p.get("op")
@@ -724,9 +721,8 @@ def per_op(programs: dict[str, dict],
     for op, a in acc.items():
         device_s = a["device_ms"] / 1e3
         roof = None
-        if device_s > 0 and a["bytes_total"] > 0:
-            roof = round(roofline_fraction(
-                a["bytes_total"] / device_s, hbm_bytes_per_s), 6)
+        if device_s > 0 and a["bytes_total"] > 0 and hbm_bytes_per_s:
+            roof = round(a["bytes_total"] / device_s / hbm_bytes_per_s, 6)
         out[op] = {"dispatches": a["dispatches"],
                    "device_ms": round(a["device_ms"], 3),
                    "roofline": roof,

@@ -42,6 +42,42 @@ def test_initialize_installs_store():
         reset_store()
 
 
+def _as_chip(monkeypatch, memory_bytes):
+    monkeypatch.setattr(
+        DM, "selected_info",
+        lambda conf=None: DM.DeviceInfo(0, "tpu", "TPU v5 lite",
+                                        memory_bytes))
+
+
+def test_plain_store_is_sized_from_the_chip(monkeypatch):
+    """The store a plain TpuSession gets (get_store() with no plugin
+    in sight) takes memory.fraction of the chip's reported limit."""
+    conf = get_conf()
+    limit = 16 * 10**9
+    _as_chip(monkeypatch, limit)
+    reset_store()
+    try:
+        assert get_store().device_budget == int(
+            limit * conf.get(DM.MEMORY_FRACTION))
+    finally:
+        reset_store()
+
+
+def test_explicit_budget_wins_over_the_chip(monkeypatch):
+    conf = get_conf()
+    _as_chip(monkeypatch, 16 * 10**9)
+    conf.set(HBM_BUDGET_BYTES.key, 1 << 20)
+    assert DM.store_budget(conf) == 1 << 20
+
+
+def test_chip_without_a_memory_limit_is_an_error(monkeypatch):
+    import pytest
+
+    _as_chip(monkeypatch, None)
+    with pytest.raises(RuntimeError, match="reports no memory limit"):
+        DM.store_budget(get_conf())
+
+
 def test_host_buffer_pool_recycles():
     pool = DM.HostBufferPool(max_bytes=1 << 20)
     a = pool.take(5000)

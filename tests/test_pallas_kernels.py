@@ -46,10 +46,24 @@ def test_pallas_string_hash_chained_seeds():
     assert np.array_equal(np.asarray(got), np.asarray(ref))
 
 
-def test_pallas_gate_on_cpu():
-    from spark_rapids_tpu.ops.pallas_kernels import pallas_available
+def test_pallas_gate_is_a_rule_not_a_rescue(monkeypatch):
+    import spark_rapids_tpu.ops.pallas_kernels as PK
 
-    assert pallas_available() is False  # tests pin the CPU backend
+    # a non-TPU backend takes the jnp path: that is the routing rule
+    assert PK.pallas_available() is False  # tests pin the CPU backend
+    n = _BLOCK_N
+    chars, lengths = _string_matrix(n, 8, seed=5)
+    seeds = jnp.full((n,), 42, jnp.uint32)
+    assert PK.maybe_pallas_hash_string(chars, lengths, seeds) is None
+
+    # a backend that cannot be asked is an error the caller sees, not
+    # a quiet "use jnp"
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(PK.jax, "default_backend", no_backend)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        PK.pallas_available()
 
 
 def test_empty_and_full_width_strings():
