@@ -398,81 +398,44 @@ def _link_probe() -> dict:
     }
 
 
-class _StageTaps:
-    """Wall-clock accumulated in the scan host decode, the wire
-    encode+upload, and the final result fetch, for ONE collect."""
-
-    def __init__(self):
-        import spark_rapids_tpu.io.scan as scan_mod
-        import spark_rapids_tpu.plan.planner as planner_mod
-        from spark_rapids_tpu.columnar.arrow import to_arrow
-        from spark_rapids_tpu.io import fastpar
-
-        self.host_s = 0.0
-        self.wire_s = 0.0
-        self.fetch_s = 0.0
-        self._mods = (scan_mod, planner_mod, fastpar)
-        self._orig = (scan_mod.ParquetScanExec._upload,
-                      planner_mod.to_arrow, fastpar.read_file)
-
-        taps = self
-
-        def upload(inner_self, tables):
-            t0 = time.perf_counter()
-            try:
-                return taps._orig[0](inner_self, tables)
-            finally:
-                taps.wire_s += time.perf_counter() - t0
-
-        def fetch(b):
-            t0 = time.perf_counter()
-            try:
-                return to_arrow(b)
-            finally:
-                taps.fetch_s += time.perf_counter() - t0
-
-        def read_file(*a, **k):
-            t0 = time.perf_counter()
-            try:
-                return taps._orig[2](*a, **k)
-            finally:
-                taps.host_s += time.perf_counter() - t0
-
-        scan_mod.ParquetScanExec._upload = upload
-        planner_mod.to_arrow = fetch
-        fastpar.read_file = read_file
-
-    def restore(self):
-        scan_mod, planner_mod, fastpar = self._mods
-        scan_mod.ParquetScanExec._upload = self._orig[0]
-        planner_mod.to_arrow = self._orig[1]
-        fastpar.read_file = self._orig[2]
-
-
 def _stage_breakdown(df, prefix: str) -> dict:
-    """One instrumented collect: where does an iteration of this query
-    go?  host_decode / wire_upload / final_fetch accumulate wall time
-    in the tapped stages; `other` is the residual — with the software
-    pipeline on, stages OVERLAP, so the residual approximates the
-    non-overlapped compute+dispatch and the four fields can sum past
-    the total.  The final-fetch figure inlines the wait for any device
-    execution still in flight (dispatch is async) — if the residual is
-    dominated by fetch at near-zero decode/wire time, the bottleneck is
-    the link, not the engine."""
-    taps = _StageTaps()
+    """One traced collect: where does an iteration of this query go?
+    host_decode / wire_upload / final_fetch are the seconds inside the
+    engine's own spans (`scan.decode.file`, `wire.encode` +
+    `wire.put`, `query.fetch.batch`: docs/observability.md), summed
+    over the threads that recorded them; `other` is the residual —
+    with the software pipeline on, stages OVERLAP and decode runs on a
+    pool, so the residual approximates the non-overlapped
+    compute+dispatch and the four fields can sum past the total.  The
+    final-fetch figure inlines the wait for any device execution still
+    in flight (dispatch is async) — if the residual is dominated by
+    fetch at near-zero decode/wire time, the bottleneck is the link,
+    not the engine."""
+    from spark_rapids_tpu import trace
+
+    was_on = trace.is_enabled()
+    trace.enable()
     try:
-        t0 = time.perf_counter()
+        t0_ns = time.perf_counter_ns()
         df.collect(engine="tpu")
-        total = time.perf_counter() - t0
+        total = (time.perf_counter_ns() - t0_ns) / 1e9
+        spans = [e for e in trace.snapshot() if e.ts_ns >= t0_ns]
     finally:
-        taps.restore()
+        if not was_on:
+            trace.disable()
+
+    def inside(*names: str) -> float:
+        return sum(e.dur_ns for e in spans if e.name in names) / 1e9
+
+    host_s = inside("scan.decode.file")
+    wire_s = inside("wire.encode", "wire.put")
+    fetch_s = inside("query.fetch.batch")
     return {
-        f"{prefix}_stage_host_decode_s": round(taps.host_s, 4),
-        f"{prefix}_stage_wire_upload_s": round(taps.wire_s, 4),
-        f"{prefix}_stage_final_fetch_s": round(taps.fetch_s, 4),
+        f"{prefix}_stage_host_decode_s": round(host_s, 4),
+        f"{prefix}_stage_wire_upload_s": round(wire_s, 4),
+        f"{prefix}_stage_final_fetch_s": round(fetch_s, 4),
         f"{prefix}_stage_other_s": round(
-            max(0.0, total - taps.host_s - taps.wire_s - taps.fetch_s),
-            4),
+            max(0.0, total - host_s - wire_s - fetch_s), 4),
     }
 
 

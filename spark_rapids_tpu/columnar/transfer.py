@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 from typing import Optional, Sequence
 
 import jax
@@ -46,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 import pyarrow as pa
 
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.column import (
     Column,
@@ -81,7 +83,12 @@ def upload_components(comps):
     spills every unpinned store buffer and re-uploads once — the
     upload is restartable by construction (host components are still
     in hand).  A second failure propagates to the batch
-    split-and-retry ladder / task retry."""
+    split-and-retry ladder / task retry.
+
+    The `wire.put` span times the host's call of ``jax.device_put``
+    (and a retry's spill, when there is one).  The call may return
+    before the link is done with the bytes: the span is what the
+    uploading thread pays, not the transfer's own duration."""
     from spark_rapids_tpu.execs.retry import absorb_once
     from spark_rapids_tpu.robustness import faults as _faults
 
@@ -89,7 +96,6 @@ def upload_components(comps):
         _faults.fault_point("transfer.upload", n_comps=len(comps))
         return jax.device_put(comps)
 
-    out = absorb_once(attempt, action="upload_retry")
     # count HOST array leaves only (tree_leaves: nested column pytrees
     # from the arrow.py fallback path count too): device-resident
     # components handed back through here (decode_now re-running a
@@ -98,6 +104,8 @@ def upload_components(comps):
     host_bytes = sum(
         int(a.nbytes) for a in jax.tree_util.tree_leaves(comps)
         if isinstance(a, np.ndarray))
+    with _trace.span("wire.put", bytes=host_bytes, comps=len(comps)):
+        out = absorb_once(attempt, action="upload_retry")
     if host_bytes:
         with _upload_lock:
             _UPLOAD_STATS["batches"] += 1
@@ -296,7 +304,6 @@ class _Comps:
     def add_wire(self, a: np.ndarray):
         a = np.ascontiguousarray(a)
         if self.wire_cfg is not None:
-            from spark_rapids_tpu import trace as _trace
             from spark_rapids_tpu.columnar import compression as WC
 
             with _trace.span("wire.compress", nbytes=a.nbytes,
@@ -320,7 +327,23 @@ def encode_for_device(arrays: Sequence[pa.Array], schema: T.Schema,
 
     Returns None when a column type has no wire encoding yet (decimal,
     list) — callers fall back to the per-component padded upload path.
+    Traced as `wire.encode` on the encoding thread; its `wire_bytes`
+    are what `upload_components` will count for these components.
     """
+    if not _trace.TRACER.enabled:
+        return _encode_components(arrays, schema, n)
+    t0 = time.perf_counter_ns()
+    enc = _encode_components(arrays, schema, n)
+    _trace.record_complete(
+        "wire.encode", t0, time.perf_counter_ns() - t0, rows=n,
+        columns=len(schema.fields),
+        host_bytes=sum(a.nbytes for a in arrays),
+        wire_bytes=sum(int(c.nbytes) for c in enc[0]) if enc else 0)
+    return enc
+
+
+def _encode_components(arrays: Sequence[pa.Array], schema: T.Schema,
+                       n: int) -> Optional[tuple[list, tuple]]:
     for f in schema.fields:
         if isinstance(f.dtype, (T.DecimalType, T.ListType,
                                 T.StructType, T.MapType)):
@@ -726,7 +749,6 @@ def decode_on_device(comps: list, plan: tuple, schema: T.Schema,
     wire-form batch) must not count it twice — every encoded batch
     contributes exactly one decompress per codec use, whether its
     decode runs here eagerly or fused inside a consumer program."""
-    from spark_rapids_tpu import trace as _trace
     from spark_rapids_tpu.execs.jit_cache import cached_jit
 
     # the compiled decode ignores dict_n (it is applied by _wrap_cols

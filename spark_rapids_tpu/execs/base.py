@@ -166,7 +166,11 @@ class _MetricReaper:
         # leaves, and one dead leaf must not drop the whole sample
         from spark_rapids_tpu.trace.ledger import derive_sentinels
 
-        sentinels = derive_sentinels(observed)
+        # one eager slice per output leaf, dispatched by the operator's
+        # own thread after its `exec.<op>` span has closed: tens of ms
+        # for a wide batch, so the timeline names it
+        with _trace.span("exec.sentinels", metric=metric.name):
+            sentinels = derive_sentinels(observed)
         # no live device leaves (host-only output, or every leaf
         # already consumed): the worker records the elapsed wall with
         # no readiness wait — the timer still ticks, like the
@@ -567,13 +571,17 @@ class FusableExec(TpuExec):
                     batch = f(batch)
                 return batch
 
-        if all(k is not None for k in keys):
-            from spark_rapids_tpu.execs.jit_cache import cached_jit
+        from spark_rapids_tpu.execs.jit_cache import (
+            cached_jit,
+            named_program,
+        )
 
+        if all(k is not None for k in keys):
             jitted = cached_jit(("fused", tuple(keys), ansi),
                                 lambda: pipeline, op=self.name)
         else:
-            jitted = jax.jit(pipeline)
+            jitted = jax.jit(named_program(pipeline, self.name,
+                                           "unkeyed"))
         self._fused = (jitted, node, aware, ansi, len(chain))
         return self._fused
 
@@ -610,19 +618,21 @@ class FusableExec(TpuExec):
                 batch = f(batch)
             return batch
 
+        from spark_rapids_tpu.execs.jit_cache import (
+            cached_jit,
+            donation_enabled,
+            named_program,
+        )
+
         donated = False
         if all(k is not None for k in keys):
-            from spark_rapids_tpu.execs.jit_cache import (
-                cached_jit,
-                donation_enabled,
-            )
-
             donated = donation_enabled()
             jitted = cached_jit(("fusedenc", tuple(keys), ansi),
                                 lambda: pipeline, op=self.name,
                                 donate=(0,))
         else:
-            jitted = jax.jit(pipeline)
+            jitted = jax.jit(named_program(pipeline, self.name,
+                                           "unkeyed"))
         self._fused_enc = (jitted, donated, len(chain))
         return self._fused_enc
 

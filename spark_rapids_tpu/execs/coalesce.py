@@ -32,6 +32,7 @@ import queue
 import threading
 from typing import Iterator, Optional
 
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, concat_batches
 from spark_rapids_tpu.config import MAX_CAPACITY, get_conf, register
@@ -252,7 +253,15 @@ class TpuCoalescePartitionsExec(TpuExec):
         next_part = iter(range(n_parts))
         part_lock = threading.Lock()
 
+        # thread-locals do not follow the work onto the task threads:
+        # the query's trace context is handed over as prefetch hands it
+        tctx = _trace.current_context()
+
         def worker() -> None:
+            with _trace.attach_context(tctx):
+                run_tasks()
+
+        def run_tasks() -> None:
             sem = TpuSemaphore.get()
             task_id = threading.get_ident()
             try:
@@ -277,8 +286,9 @@ class TpuCoalescePartitionsExec(TpuExec):
                 sem.release_if_necessary(task_id)
                 out_q.put(_DONE)
 
-        workers = [threading.Thread(target=worker, daemon=True)
-                   for _ in range(threads)]
+        workers = [threading.Thread(target=worker, daemon=True,
+                                    name=f"tpu-coalesce-task-{i}")
+                   for i in range(threads)]
         for w in workers:
             w.start()
         done = 0

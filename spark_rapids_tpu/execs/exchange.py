@@ -394,7 +394,8 @@ class TpuShuffleExchangeExec(TpuExec):
                         ("rangepid", pkey, n, batch.capacity,
                          repr(batch.schema)),
                         lambda: lambda b, bd:
-                            part.partition_ids_with_bounds(b, bd))
+                            part.partition_ids_with_bounds(b, bd),
+                        op=self.name)
                     subs = split_batch(batch, pid_fn(batch, bounds), n)
                     for rid, sub in enumerate(subs):
                         rows = sub.concrete_num_rows()
@@ -467,7 +468,9 @@ class TpuShuffleExchangeExec(TpuExec):
                     _trace.span("exchange.task", task=p):
                 fn(p)
 
-        with ThreadPoolExecutor(max_workers=threads) as pool:
+        with ThreadPoolExecutor(
+                max_workers=threads,
+                thread_name_prefix="tpu-exchange-map") as pool:
             futures = [pool.submit(run, p) for p in range(n_tasks)]
             for f in futures:
                 f.result()

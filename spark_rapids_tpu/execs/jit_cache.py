@@ -19,6 +19,8 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
+import re
 import threading
 import warnings
 from typing import Callable, Optional, Sequence
@@ -255,6 +257,32 @@ def serialize_sharded(fn: Callable) -> Callable:
     return _SerializedDispatch(fn)
 
 
+_NOT_IN_A_NAME = re.compile(r"[^A-Za-z0-9_]")
+
+
+def named_program(fn: Callable, op: Optional[str], tag: str) -> Callable:
+    """`fn` under the name `tpu__<op>__<tag>`, for `jax.jit` to call:
+    JAX names the XLA module after the function (`jit_<name>`), so a
+    profiler capture then says which exec's program ran, and whatever
+    lacks the `jit_tpu__` prefix was dispatched outside `cached_jit`.
+    `op` is the owning exec's name (`none` where the site has none),
+    `tag` the key's leading string (`trace.ledger.key_tag`) or
+    `unkeyed`; both are cut to `[A-Za-z0-9_]`.
+
+    Always a thin wrapper, never a rename: partials, bound methods and
+    callable objects take no `__name__`, JAX reads the name only when
+    it traces, and one function may serve several keys.  The wrapper
+    runs at trace time alone; a compiled dispatch never enters it."""
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    program.__name__ = program.__qualname__ = "tpu__%s__%s" % (
+        _NOT_IN_A_NAME.sub("_", op or "none"),
+        _NOT_IN_A_NAME.sub("_", tag))
+    return program
+
+
 def cached_jit(key: tuple, make_fn: Callable[[], Callable],
                op: Optional[str] = None,
                donate: "int | Sequence[int] | None" = None,
@@ -360,6 +388,9 @@ def cached_jit(key: tuple, make_fn: Callable[[], Callable],
                     if mesh_serving_enabled() else None
             else:
                 store = _persist.active()
+            def make_named() -> Callable:
+                return named_program(make_fn(), op, _ledger.key_tag(key))
+
             restored = None
             conf_fp = ""
             if store is not None:
@@ -367,14 +398,14 @@ def cached_jit(key: tuple, make_fn: Callable[[], Callable],
                 exported = store.load_programs(key, conf_fp)
                 if exported:
                     restored = _persist.RestoredProgram(
-                        key, exported, make_fn, jit_kwargs, store,
+                        key, exported, make_named, jit_kwargs, store,
                         conf_fp)
             if restored is not None:
                 fn = _ledger.LEDGER.wrap(
                     key, restored, op=op, donated=bool(donate),
                     meta={**(meta or {}), "persist_restored": True})
             else:
-                jitted = jax.jit(make_fn(), **jit_kwargs)
+                jitted = jax.jit(make_named(), **jit_kwargs)
                 if store is not None:
                     jitted = _persist.AutoSave(key, jitted, store,
                                                conf_fp)

@@ -9,11 +9,13 @@ arrow seam; device-side Parquet decode (Pallas) is a later optimization.
 
 from __future__ import annotations
 
+import time
 from typing import Iterator, Optional, Sequence
 
 import pyarrow as pa
 
 from spark_rapids_tpu import config as _config
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.arrow import from_arrow, schema_to_arrow
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
@@ -169,6 +171,16 @@ def _scan_batch_rows(schema: T.Schema) -> int:
     # one compiled program shape for every full batch
     by_bytes = 1 << (by_bytes.bit_length() - 1)
     return int(max(1, min(rows_cap, by_bytes, conf.get(MAX_CAPACITY))))
+
+
+def _record_decode(t0_ns: int, fi: int, path: str, tables) -> None:
+    """Close a `scan.decode.file` span opened at `t0_ns` on the thread
+    that decoded: `tables` is what the decoder handed back (Tables or
+    RecordBatches), before partition columns and the host prefilter."""
+    _trace.record_complete(
+        "scan.decode.file", t0_ns, time.perf_counter_ns() - t0_ns,
+        file=fi, path=path, rows=sum(t.num_rows for t in tables),
+        bytes=sum(t.nbytes for t in tables))
 
 
 def _prefetched(gen, stage: str = "scan.decode",
@@ -381,9 +393,15 @@ class ParquetScanExec(TpuExec):
             if not keep_rgs:
                 return
 
+        # `scan.decode.file` spans close before every yield: one left
+        # open across it would charge the consumer's time to the decoder
+        tracing = _trace.TRACER.enabled
+        t0 = time.perf_counter_ns() if tracing else 0
         fast = self._try_fast_tables(f, fi, keep_rgs, conjuncts)
         if fast is not None:
             tables, fast_rf_complete = fast
+            if tracing:
+                _record_decode(t0, fi, "fast", tables)
             for tbl in tables:
                 for f2 in self.partition_fields:
                     tbl = tbl.append_column(
@@ -404,6 +422,9 @@ class ParquetScanExec(TpuExec):
             # the Arrow C++ pool)
             tbl = f.read_row_groups(keep_rgs, columns=self.columns,
                                     use_threads=True)
+            if tracing:
+                # from t0: a fast attempt that gave up is decode time too
+                _record_decode(t0, fi, "pyarrow", (tbl,))
             for f2 in self.partition_fields:
                 tbl = tbl.append_column(
                     f2.name,
@@ -414,12 +435,16 @@ class ParquetScanExec(TpuExec):
                                  columns=self.columns,
                                  row_groups=keep_rgs,
                                  use_threads=True):
+            if tracing:
+                # one span for each `next()` of the reader
+                _record_decode(t0, fi, "pyarrow", (rb,))
             tbl = pa.Table.from_batches([rb])
             for f2 in self.partition_fields:
                 tbl = tbl.append_column(
                     f2.name,
                     self._host_partition_array(fi, f2, rb.num_rows))
             yield self._host_prefilter(tbl)
+            t0 = time.perf_counter_ns() if tracing else 0
 
     def _try_fast_tables(self, f, fi: int, keep_rgs,
                          conjuncts) -> Optional[tuple]:
@@ -574,7 +599,6 @@ class ParquetScanExec(TpuExec):
         rfs = [(n, rf) for n, rf in rfs if n in names]
         if not rfs:
             return tbl
-        from spark_rapids_tpu import trace as _trace
         from spark_rapids_tpu.io.pa_filter import (
             runtime_filter_column_mask,
         )
@@ -758,11 +782,18 @@ class ParquetScanExec(TpuExec):
             # file k's tables are being consumed, order preserved
             from concurrent.futures import ThreadPoolExecutor
 
-            def decode(fi):
-                return list(_counted(self._file_tables(fi,
-                                                       conjuncts)))
+            # thread-locals do not follow the work onto the pool: hand
+            # it the query's trace context as prefetch hands it to us
+            tctx = _trace.current_context()
 
-            with ThreadPoolExecutor(max_workers=threads) as pool:
+            def decode(fi):
+                with _trace.attach_context(tctx):
+                    return list(_counted(self._file_tables(fi,
+                                                           conjuncts)))
+
+            with ThreadPoolExecutor(
+                    max_workers=threads,
+                    thread_name_prefix="tpu-scan-decode") as pool:
                 pending = []
                 it = iter(files)
                 for fi in it:
