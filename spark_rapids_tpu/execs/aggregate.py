@@ -220,9 +220,20 @@ class TpuHashAggregateExec(TpuExec):
         filter chain — masked rows never existed, but no compaction
         kernels are paid for them."""
         from spark_rapids_tpu.columnar.column import MIN_CAPACITY
+        from spark_rapids_tpu.execs.jit_cache import expr_key
 
         ctx = EvalContext.for_batch(batch)
-        cols = [e.eval(ctx) for e in self.input_exprs]
+        # equal input expressions (q1's sum and avg of one column)
+        # evaluate once and share their arrays, so the coded group-by
+        # packs one matrix column for them (eval reads nothing that
+        # expr_key leaves out)
+        evaluated: dict = {}
+        cols = []
+        for e in self.input_exprs:
+            k = expr_key(e)
+            if k not in evaluated:
+                evaluated[k] = e.eval(ctx)
+            cols.append(evaluated[k])
         # Spark inserts NormalizeNaNAndZero under grouping keys (the
         # analyzer's NormalizeFloatingNumbers rule): -0.0 groups AS 0.0
         # and every NaN as the one canonical NaN — normalize here so

@@ -1,27 +1,47 @@
-"""Sort-based segmented group-by aggregation.
+"""Group-by aggregation: three paths to one answer.
 
 TPU counterpart of cudf's `Table.groupBy(...).aggregate(...)` as used by
 GpuHashAggregateExec (ref: sql-plugin/.../aggregate.scala:240,366).  cudf
-uses a device hash table; the XLA-idiomatic design is sort-based:
+uses a device hash table; here every path is a program of static shape
+whose output is prefix-compact with `num_groups` live rows (a traced
+scalar).  `groupby_aggregate` picks, at trace time and from what the
+batch shows:
 
-    sort rows by key -> mark segment starts -> segment_{sum,min,max}
+1. **Coded, masked** (`_coded_groupby`, `_segment_sums(masked=True)`):
+   every key carries the wire's dictionary sidecar, so a row's combined
+   code IS its dense group id in a static domain of K segments, and
+   K x m (m matrix columns) is at most `MAX_MASKED_CELLS`.  The sums are
+   compare + select + reduce, no scatter: 5.6 ms for q1's update of
+   2^20 rows (K 81, m 11) on a TPU v5e.
+2. **Coded, scatter** (`_segment_sums(masked=False)`): the same dense
+   ids, K up to `MAX_CODED_DOMAIN`, through one `jax.ops.segment_sum`.
+   A scatter-add is serial in its rows on the TPU: 95-98 ms for 2^20
+   rows whatever K and m are (91 ns a row; it was q1's whole cost).
+3. **Sort** (the rest of `groupby_aggregate`): any other keys, floats
+   and merged partials among them (a partial carries no codes):
 
-which is one fused program of static shape: the output batch has the same
-capacity as the input with `num_groups` live rows (traced scalar).
-Aggregations are expressed as (update, merge) pairs the way Spark
-aggregate modes are (Partial -> PartialMerge/Final), so multi-batch and
-post-shuffle merging reuse the same kernels on the partial-result columns.
+       sort rows by key -> mark segment starts -> segment_{sum,min,max}
+
+   with output capacity equal to the input's; its per-spec `_eval_agg`
+   still scatters (q3's aggregate; ROADMAP S2).
+
+The times are the chip's (PERF.md section 6, PR 26).  Aggregations are
+expressed as (update, merge) pairs the way Spark aggregate modes are
+(Partial -> PartialMerge/Final), so multi-batch and post-shuffle merging
+reuse the same kernels on the partial-result columns.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import (
@@ -91,6 +111,46 @@ def agg_output_dtype(spec: AggSpec, value_dtype: Optional[T.DataType]
 #: e.g. a (store x item) TPC-DS grouping of ~18K combined domain.
 MAX_CODED_DOMAIN = 1 << 17
 
+#: most segments x matrix columns (K x m) the coded path reduces as a
+#: masked sum; above it the same matrix goes through `segment_sum`.  A
+#: scatter-add on the TPU costs 91 ns a row whatever K and m are (serial
+#: in its rows: 95-98 ms for 2^20 DOUBLE rows at K 9..4,225 and m 11 or
+#: 30, 80 ms for int64), a masked sum about 7 us per K x m for the same
+#: rows (6.3 ms at K 81, m 11; 82 ms at K 1,089), so they cross near
+#: K x m = 14,000 (DOUBLE) and 12,000 (int64); 2^13 keeps the masked
+#: form at three quarters of the scatter's time or less (TPU v5e,
+#: `scripts/sweep_coded_reduce.py`; PERF.md section 6, PR 26).
+MAX_MASKED_CELLS = 1 << 13
+
+#: rows of one masked partial sum: the row axis is folded to
+#: (cap / lanes, lanes) and reduced over the major axis first, so the
+#: inner loop is lane-wise adds with no cross-lane reduce.  q1's update
+#: at 2^20 rows: 8.0 ms at 128 and 256, 6.2 at 1,024, 5.6 at 4,096,
+#: 7.2 at 16,384 (same sweep)
+_MASKED_LANES = 4096
+
+
+def _segment_sums(cols: list, seg: jax.Array, K: int,
+                  masked: bool) -> jax.Array:
+    """Per-segment sums of same-dtype `(cap,)` columns, as `(K, m)`.
+    Rows whose `seg` is K (dead or filtered) land in no segment.  Both
+    forms accumulate in the columns' own dtype (DOUBLE or int64, which
+    wraps); they differ only in the order the terms are added."""
+    if not masked:
+        with jax.named_scope("groupby.coded.scatter"):
+            return jax.ops.segment_sum(jnp.stack(cols, axis=1), seg,
+                                       num_segments=K)
+    with jax.named_scope("groupby.coded.masked"):
+        cap = seg.shape[0]
+        lanes = math.gcd(cap, _MASKED_LANES)
+        M = jnp.stack(cols, axis=0).reshape(len(cols), cap // lanes, lanes)
+        hit = seg.reshape(1, cap // lanes, lanes) \
+            == jnp.arange(K, dtype=jnp.int32)[:, None, None]
+        # (K, m, cap/lanes, lanes) is never materialised: XLA fuses the
+        # select into the reduce (compare + select + reduce, no scatter)
+        part = jnp.sum(jnp.where(hit[:, None], M[None], 0), axis=2)
+        return jnp.sum(part, axis=2)
+
 
 def _coded_key_domains(key_cols: Sequence[AnyColumn]) -> Optional[list[int]]:
     """Per-key dictionary sizes when EVERY key column carries the wire
@@ -133,17 +193,17 @@ def _coded_groupby(batch: ColumnarBatch, key_ordinals: Sequence[int],
     """Sort-free group-by over dictionary codes (the analog of cudf's
     hash groupby for low-cardinality keys, ref: aggregate.scala:240-430):
     each row's combined code IS its dense group id, so the whole
-    aggregation is segment reductions over a static code domain — no
+    aggregation is segment reductions over a static code domain: no
     O(n log n) lexsort of the key bytes.
 
-    Kernel-budget design (built to keep the LAUNCH COUNT per batch
-    low; what a launch costs on the chip is not measured yet, ROADMAP
-    S2): every sum/count-family
-    aggregate packs into ONE (rows, m) matrix reduced by a single N-D
-    segment_sum; compaction is a cumsum + one gather (no scatters);
-    only min/max/first/last fall back to per-spec segment ops.  Output
-    is compact (capacity = padded domain size), orders of magnitude
-    below the input bucket."""
+    Every sum/count-family aggregate packs into ONE (rows, m) DOUBLE
+    matrix (integer sums into a second, int64 one), a column per
+    distinct operand, reduced by `_segment_sums`: a masked sum while
+    K x m <= `MAX_MASKED_CELLS`, else `segment_sum` (the module header
+    has what each costs on the chip).  Compaction is a cumsum + one
+    gather; only min/max/first/last fall back to per-spec segment ops.
+    Output is compact (capacity = padded domain size), orders of
+    magnitude below the input bucket."""
     from spark_rapids_tpu.columnar.column import MIN_CAPACITY
 
     cap = batch.capacity
@@ -164,42 +224,58 @@ def _coded_groupby(batch: ColumnarBatch, key_ordinals: Sequence[int],
 
     # pack the sum/count family into one f64 matrix (and one i64 matrix
     # for integer-typed sums, whose wrap-on-overflow semantics f64
-    # cannot reproduce); slot 0 = live-ones: count_star AND occupancy
+    # cannot reproduce); slot 0 = live-ones: count_star AND occupancy.
+    # One column per distinct operand: specs that read the same traced
+    # arrays (q1's sum and avg of one input, a count beside a sum) share
+    # it, keyed by the identity of the input's data and validity.
     f64_cols: list = [jnp.where(live, 1.0, 0.0)]
     i64_cols: list = []
+    seen: dict = {}
+
+    def column_of(cols: list, key: tuple, make) -> int:
+        if key not in seen:
+            cols.append(make())
+            seen[key] = len(cols) - 1
+        return seen[key]
+
     slots: list = []  # per spec: ("f64"/"i64", value_slot, nvalid_slot)
     for spec in aggs:
         if spec.op == "count_star":
             slots.append(("star",))
             continue
         vcol = batch.columns[spec.ordinal]
+        if spec.op != "count" and not (spec.op == "sum"
+                                       and isinstance(vcol, Column)):
+            slots.append(("segop",))
+            continue
         valid = vcol.validity & live
+        nv = column_of(f64_cols, ("nv", id(vcol.validity)),
+                       lambda: valid.astype(jnp.float64))
         if spec.op == "count":
-            f64_cols.append(valid.astype(jnp.float64))
-            slots.append(("count", len(f64_cols) - 1))
+            slots.append(("count", nv))
             continue
-        if spec.op == "sum" and isinstance(vcol, Column):
-            out_dtype = agg_output_dtype(spec, vcol.dtype)
-            phys = np.dtype(T.to_numpy_dtype(out_dtype))
-            f64_cols.append(valid.astype(jnp.float64))
-            nv = len(f64_cols) - 1
-            if phys.kind == "f":
-                f64_cols.append(jnp.where(
-                    valid, vcol.data.astype(jnp.float64), 0.0))
-                slots.append(("f64", len(f64_cols) - 1, nv, out_dtype))
-            else:
-                i64_cols.append(jnp.where(
-                    valid, vcol.data.astype(jnp.int64),
-                    jnp.asarray(0, jnp.int64)))
-                slots.append(("i64", len(i64_cols) - 1, nv, out_dtype))
-            continue
-        slots.append(("segop",))
+        out_dtype = agg_output_dtype(spec, vcol.dtype)
+        operand = (id(vcol.data), id(vcol.validity))
+        if np.dtype(T.to_numpy_dtype(out_dtype)).kind == "f":
+            vs = column_of(f64_cols, ("f64",) + operand, lambda: jnp.where(
+                valid, vcol.data.astype(jnp.float64), 0.0))
+            slots.append(("f64", vs, nv, out_dtype))
+        else:
+            vs = column_of(i64_cols, ("i64",) + operand, lambda: jnp.where(
+                valid, vcol.data.astype(jnp.int64),
+                jnp.asarray(0, jnp.int64)))
+            slots.append(("i64", vs, nv, out_dtype))
 
-    S = jax.ops.segment_sum(jnp.stack(f64_cols, axis=1), seg,
-                            num_segments=K)
-    Si = (jax.ops.segment_sum(jnp.stack(i64_cols, axis=1), seg,
-                              num_segments=K)
-          if i64_cols else None)
+    # one choice per program, from what the trace sees: masked work
+    # grows with K x m, the scatter's with neither
+    m = len(f64_cols) + len(i64_cols)
+    masked = K * m <= MAX_MASKED_CELLS
+    if _trace.TRACER.enabled:
+        _trace.event("groupby.coded_reduce",
+                     kind="masked" if masked else "scatter",
+                     K=K, m=m, cap=cap)
+    S = _segment_sums(f64_cols, seg, K, masked)
+    Si = _segment_sums(i64_cols, seg, K, masked) if i64_cols else None
 
     occ = S[:, 0] > 0.0
     ranks = jnp.cumsum(occ.astype(jnp.int32))

@@ -555,4 +555,6 @@ def test_groupby_on_dict_column_differential():
     })
     s = TpuSession()
     df = s.create_dataframe(t).group_by("k").agg((sum_("v"), "sv"))
-    assert_tpu_cpu_equal(df)
+    # the masked reduction adds the terms in another order than the CPU
+    # engine: equal to nine decimals, not to the last bit
+    assert_tpu_cpu_equal(df, approx_float=True)

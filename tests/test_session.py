@@ -204,7 +204,17 @@ def test_tpch_q1_shape(spark):
                 (avg("l_discount"), "avg_disc"),
                 (count_star(), "count_order"))
            .order_by("l_returnflag", "l_linestatus"))
-    assert_tpu_cpu_equal(q, ignore_order=False, approx_float=True)
+    # the coded group-by adds each sum's terms in another order than the
+    # CPU engine; sums near 3e7 then differ past the harness's nine
+    # decimals, so hold the doubles to a relative bound instead
+    tpu, cpu = q.collect(engine="tpu"), q.collect(engine="cpu")
+    assert tpu.schema.names == cpu.schema.names
+    for name in tpu.schema.names:
+        got, want = tpu[name].to_pylist(), cpu[name].to_pylist()
+        if pa.types.is_floating(tpu[name].type):
+            np.testing.assert_allclose(got, want, rtol=1e-12, atol=0)
+        else:
+            assert got == want, name
 
 
 def test_to_device_arrays_zero_copy_into_jax():
