@@ -7,6 +7,11 @@ DataFrames and `collect()`), its counters (`stage_snapshot`,
 tracer's spans and `compile_cache_dir()`.  `require_devices`,
 `CompileCounters` and `off_device` are copied from `chip_smoke.py`
 (PR 21), which stays the program's.
+
+A cell of one chip runs a plain session.  A cell of more than one runs
+under the collective shuffle over its first `chips` devices
+(`TpuSession.enable_collective_shuffle`, the switch a user turns): the
+cell's chip count decides it and no key of a file does.
 """
 
 import dataclasses
@@ -103,6 +108,32 @@ def off_device(explain_text: str) -> list:
     return off if plan else [explain_text]
 
 
+def lacking(root, operators) -> list:
+    """Those of the named operators that the executed plan does not
+    hold.  `root` is the history event's snapshot of the operator tree
+    that ran (`desc`, `children`); an operator's description starts
+    with its name."""
+    held, todo = set(), [root]
+    while todo:
+        node = todo.pop()
+        held.add(node.desc.split(" ", 1)[0])
+        todo += node.children
+    return [op for op in operators if op not in held]
+
+
+def stated_conf(config: dict) -> dict:
+    """The keys a configuration's file sets over the shipped default
+    conf (`conf`), each of which its `assumed.conf` has to account for
+    by name: what a cut made necessary, never a tuning knob."""
+    said = config.get("assumed", {}).get("conf", "")
+    conf = config.get("conf", {})
+    for key in conf:
+        if key not in said:
+            raise Refused(f"the configuration sets {key} and its "
+                          "assumed.conf does not say why")
+    return conf
+
+
 def _delta(after: dict, before: dict) -> dict:
     return {k: after[k] - before.get(k, 0) for k in after}
 
@@ -125,7 +156,10 @@ class Collect:
     round: int
     wall_s: float
     result: object  # a pyarrow Table, dropped once it is checked
-    failure: str = None
+    failure: str = None  # how the answer differs from the expected one
+    plan_fault: str = None  # a degrade, an operator off the device or
+    # one that the step names and the plan lacks
+    gap: float = None  # check.compare's widest gap of a double
 
 
 @dataclasses.dataclass
@@ -139,24 +173,26 @@ class Round:
 
 
 class Runner:
-    """A session under the cell's configuration, and rounds over it."""
+    """A session under the cell's configuration, and rounds over it.
+    Where the cell has more than one chip the session runs under the
+    collective shuffle over a mesh of that many devices, until
+    `close()`."""
 
     def __init__(self, cell: spec.Cell, data, devs: list, trace: bool):
         from spark_rapids_tpu import native
         from spark_rapids_tpu.session import TpuSession
 
-        if cell.chips != 1:
-            raise Refused(
-                f"the cell asks for {cell.chips} chips and this harness "
-                "drives one: the mesh path comes with the PR that lists "
-                "and measures a four-chip cell (PERF.md section 7)")
-        self.cell, self.data, self.devs = cell, data, devs[:1]
+        self.cell, self.data, self.devs = cell, data, devs[:cell.chips]
         self.compiles = CompileCounters()
         if native.load() is None:
             raise Refused("the native host codec is not loaded (no g++?): "
                           "scans would decode through the slow path")
-        # the shipped default conf: no configuration sets a key yet
         self.session = TpuSession()
+        for key, value in stated_conf(cell.config).items():
+            self.session.conf.set(key, value)
+        if cell.chips > 1:
+            # make_mesh takes the first `chips` of jax.devices()
+            self.session.enable_collective_shuffle(cell.chips)
         if trace:
             from spark_rapids_tpu.trace import TRACE_ENABLED
 
@@ -204,7 +240,7 @@ class Runner:
             if not reads <= filled:
                 self._build(step).collect()
                 filled |= reads
-        degraded = self._degrades()
+        degraded = self._degrades(self._new_events())
         if degraded:
             raise Refused(f"while filling the cache: {degraded}")
         stats = get_store().spill_stats()
@@ -277,15 +313,22 @@ class Runner:
             wall = time.perf_counter() - t0
         return Collect(step.query, index, wall, out)
 
-    def _degrades(self) -> list:
-        """What the session's history says of the collects since the
-        last look: one that the CPU engine answered, or a plan with an
-        operator off the device."""
+    def _new_events(self) -> list:
+        """The session history's events since the last look, in the
+        order of their collects."""
+        new = [ev for ev in self.session.history.events
+               if ev.query_id > self._seen_queries]
+        new.sort(key=lambda ev: ev.query_id)
+        if new:
+            self._seen_queries = new[-1].query_id
+        return new
+
+    def _degrades(self, events: list) -> list:
+        """What the history's events say of their collects: one that
+        the CPU engine answered, or a plan with an operator off the
+        device."""
         why = []
-        for ev in self.session.history.events:
-            if ev.query_id <= self._seen_queries:
-                continue
-            self._seen_queries = ev.query_id
+        for ev in events:
             if "[degraded to CPU engine" in ev.explain:
                 why.append(f"query {ev.query_id} degraded to the CPU engine")
             why += [f"query {ev.query_id} off the device: {ln}"
@@ -293,15 +336,30 @@ class Runner:
         return why
 
     def _note_degrades(self, done: Round) -> None:
-        """Outside the timing.  The history does not say which collect
-        of the round an event belongs to, so a degrade fails them all."""
-        why = self._degrades()
+        """Outside the timing.  A degrade fails every collect of the
+        round.  A round's events stand in the history in the order of
+        its collects, so where a step names operators (`plan_has`) its
+        own event's plan is held to them and its own collect fails."""
+        events = self._new_events()
+        why = self._degrades(events)
         fallbacks = done.counters["retry.cpu_fallbacks"]
         if fallbacks:
             why.append(f"{fallbacks} CPU fallbacks counted by retry_stats")
+        if any(step.plan_has for step in self.cell.round):
+            if len(events) != len(done.collects):
+                why.append(f"{len(events)} events in the history for "
+                           f"{len(done.collects)} collects: no plan can "
+                           "be held to its plan_has")
+            else:
+                for c, step, ev in zip(done.collects, self.cell.round,
+                                       events):
+                    lacks = lacking(ev.root, step.plan_has)
+                    if lacks:
+                        c.plan_fault = (f"the plan of query {ev.query_id} "
+                                        f"lacks {', '.join(lacks)}")
         if why:
             for c in done.collects:
-                c.failure = c.failure or "; ".join(why)
+                c.plan_fault = c.plan_fault or "; ".join(why)
 
     def check(self, done: Round) -> None:
         """Each answer against the plain reference's; the result is
@@ -309,16 +367,21 @@ class Runner:
         for c, step, want in zip(done.collects, self.cell.round,
                                  self.data.expected):
             ordered = spec.module("queries", step.query).ORDERED
-            c.failure = c.failure or check.difference(c.result, want,
-                                                      ordered)
+            c.failure, c.gap = check.compare(c.result, want, ordered)
             c.result = None
 
     def memory_peak_bytes(self) -> int:
         """`peak_bytes_in_use` on the fullest chip; 0 where the backend
         reports none (a rehearsal on the CPU)."""
-        stats = [d.memory_stats() for d in self.devs]
-        return max((s or {}).get("peak_bytes_in_use", 0) for s in stats)
+        return max(self.memory_peaks())
+
+    def memory_peaks(self) -> list:
+        """`peak_bytes_in_use` of each of the cell's chips."""
+        return [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                for d in self.devs]
 
     def close(self) -> None:
         for frame in self._cached.values():
             frame.unpersist()
+        if self.cell.chips > 1:
+            self.session.disable_collective_shuffle()

@@ -3,7 +3,7 @@
 import dataclasses
 import statistics
 
-from benchmarks.harness import spec, trace_reduce
+from benchmarks.harness import check, spec, trace_reduce
 from benchmarks.harness.peaks import DEVICE_PEAKS
 
 
@@ -44,14 +44,44 @@ class Run:
         cell's chips."""
         if self.trace is None or not self.trace.chips:
             return None
-        return statistics.fmean(trace_reduce.chip_busy_s(self.trace, c)
-                                for c in self.trace.chips)
+        return statistics.fmean(trace_reduce.chips_busy_s(self.trace))
 
     def window_s(self):
         if self.trace is None:
             return None
         lo, hi = trace_reduce.window(self.trace)
         return (hi - lo) / 1e9
+
+
+def compared(collects: list) -> dict:
+    """What decided `correct`, each number beside its limit, over every
+    collect of the run (warm-up included): the widest gap of a double
+    from the plain reference's (`check.compare`), the collects whose
+    answer differs from it at all, and those whose plan degraded, left
+    the device or lacks an operator its step names."""
+    gaps = [c.gap for c in collects if c.gap is not None]
+    return {
+        # 1e300 stands for a NaN or an infinity, which JSON has not
+        "double_rel_gap": {"value": min(max(gaps, default=0.0), 1e300),
+                           "limit": check.REL_TOL},
+        "answers_differing": {
+            "value": sum(1 for c in collects if c.failure), "limit": 0},
+        "plans_at_fault": {
+            "value": sum(1 for c in collects if c.plan_fault), "limit": 0},
+    }
+
+
+def chips_at_work(run: Run, chips: int) -> list:
+    """Busy seconds of each chip in the traced window, or ValueError
+    where the trace holds fewer chips than the cell's mesh or one of
+    them ran nothing: everything on the first chip is no measurement
+    of several."""
+    busy = trace_reduce.chips_busy_s(run.trace)
+    if len(busy) < chips or min(busy[:chips]) <= 0:
+        raise ValueError(
+            f"the cell's mesh has {chips} chips and the trace shows device "
+            f"work on {sum(b > 0 for b in busy)}: busy seconds {busy}")
+    return busy
 
 
 def end_to_end(run: Run) -> dict:

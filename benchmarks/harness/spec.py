@@ -60,6 +60,8 @@ class Step:
 
     query: str
     tables: tuple  # (role, Table) pairs
+    #: operators the plan of this step's collect has to hold
+    plan_has: tuple = ()
 
     def table(self, role: str) -> Table:
         return dict(self.tables)[role]
@@ -127,7 +129,8 @@ def load_cell(name: str, rehearse: bool = False) -> Cell:
     steps = tuple(
         Step(s["query"], tuple(
             (role, _table(config, table, rehearse))
-            for role, table in sorted(s["tables"].items())))
+            for role, table in sorted(s["tables"].items())),
+            tuple(s.get("plan_has", ())))
         for s in traffic["round"])
     return Cell(
         name=name, chips=entry["chips"], config=config, traffic=traffic,

@@ -27,6 +27,7 @@ import numpy as np
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
+ASYNC_LINE = "Async XLA Ops"
 MODULES_LINE = "XLA Modules"
 #: what the benchmark's own annotations start with
 ANNOTATION_PREFIX = "bench."
@@ -40,6 +41,11 @@ class Chip:
     op_names: list
     modules: np.ndarray  # (m, 2)
     module_names: list
+    #: the `Async XLA Ops` line, which no busy time reads: a collective
+    #: may stand there alone
+    async_ops: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros((0, 2)))
+    async_names: list = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -71,8 +77,9 @@ def load(path: str) -> Trace:
             lines = {ln.name: ln for ln in plane.lines}
             op_names, ops = _line_events(lines.get(OPS_LINE))
             mod_names, mods = _line_events(lines.get(MODULES_LINE))
+            async_names, async_ops = _line_events(lines.get(ASYNC_LINE))
             chips.append(Chip(int(found.group(1)), ops, op_names,
-                              mods, mod_names))
+                              mods, mod_names, async_ops, async_names))
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
                 for ev in line.events:
@@ -126,6 +133,11 @@ def chip_busy_s(trace: Trace, chip: Chip) -> float:
     lo, hi = window(trace)
     spans = chip.ops if len(chip.ops) else chip.modules
     return busy_ns(spans, lo, hi) / 1e9
+
+
+def chips_busy_s(trace: Trace) -> list:
+    """`chip_busy_s` of every chip of the trace, by index."""
+    return [chip_busy_s(trace, c) for c in trace.chips]
 
 
 def top_modules(trace: Trace, chip: Chip, n: int = 10) -> list:

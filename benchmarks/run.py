@@ -12,7 +12,9 @@ program into the process; (5) run rounds for `--seconds`: none starts
 after the time is up, the one in flight finishes and counts; (6) check
 every answer, reduce, print.  The last line of standard output is the
 contract's object; the record of the run is the line before it and a
-file under `benchmarks/out/<cell>/`.
+file under `benchmarks/out/<cell>/`.  What decided `correct` stands
+last in that object, under `compared`, each number beside its limit,
+and again as the last lines of standard error.
 
 `--trace 1` turns the engine's tracer on (the one conf key the
 benchmark sets, in that run alone) and takes a `jax.profiler` trace
@@ -36,6 +38,7 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
+import sys  # noqa: E402
 import tempfile  # noqa: E402
 
 from benchmarks.harness import datagen, spec  # noqa: E402
@@ -122,10 +125,12 @@ def measure(cell: spec.Cell, data: datagen.Data, args, out_dir: str):
                          device["kind"], runner.memory_peak_bytes())
         device["memory_peak_bytes"] = run.memory_peak_bytes
         collects = [c for r in warmup + rounds for c in r.collects]
-        failures = [f"{c.query} round {c.round}: {c.failure}"
-                    for c in collects if c.failure]
+        failures = [f"{c.query} round {c.round}: "
+                    f"{c.failure or c.plan_fault}"
+                    for c in collects if c.failure or c.plan_fault]
         line = {"correct": not failures, "attempted": len(collects),
                 "failed": len(failures), "device": device}
+        record["chip_peak_bytes"] = runner.memory_peaks()
         if args.rehearse:
             line["rehearsal"] = True
         if args.trace:
@@ -134,6 +139,12 @@ def measure(cell: spec.Cell, data: datagen.Data, args, out_dir: str):
                          if lo <= s.ts_ns <= hi]
             record["trace_file"] = _read_trace(run, trace_dir, marker_ns)
             if run.trace is not None and run.trace.chips:
+                if cell.chips > 1:
+                    try:
+                        record["chip_busy_s"] = reduce.chips_at_work(
+                            run, cell.chips)
+                    except ValueError as e:
+                        raise engine.Refused(str(e))
                 device["busy_s"] = run.busy_s()
                 device["window_s"] = run.window_s()
                 line["breakdown"] = reduce.breakdown(run, marker_ns)
@@ -145,6 +156,8 @@ def measure(cell: spec.Cell, data: datagen.Data, args, out_dir: str):
         else:
             line["metrics"] = _metrics_line(cell.end_to_end,
                                             reduce.end_to_end(run))
+        # what decided `correct`, last in the line and on standard error
+        line["compared"] = reduce.compared(collects)
         record.update(
             setup_s=setup_s, failures=failures[:20],
             warmup=[_round_record(r) for r in warmup],
@@ -227,6 +240,9 @@ def main(argv=None) -> None:
         json.dump(record, f, indent=1)
     print(json.dumps({"record": record}), flush=True)
     print(json.dumps(line), flush=True)
+    for name, c in line["compared"].items():
+        print(f"[bench] compared {name}: {c['value']!r}, limit "
+              f"{c['limit']!r}", file=sys.stderr, flush=True)
 
 
 if __name__ == "__main__":
