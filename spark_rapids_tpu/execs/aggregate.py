@@ -23,6 +23,7 @@ from typing import Iterator, Optional, Sequence
 
 import jax.numpy as jnp
 
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, concat_batches
 from spark_rapids_tpu.columnar.column import pad_capacity
@@ -38,6 +39,7 @@ from spark_rapids_tpu.exprs.base import (
 from spark_rapids_tpu.ops.groupby import (
     AggSpec,
     groupby_aggregate,
+    noting_paths,
     reduce_aggregate,
 )
 from spark_rapids_tpu.trace import ledger as _ledger
@@ -57,6 +59,23 @@ _FUSED_DRAIN_CAP = 1 << 18
 #: MAX_CODED_DOMAIN).  Module-level so tests can force the sizing path
 #: on small data.
 _DEFER_SYNC_CAP = 1 << 18
+
+
+#: program key -> the group-by path its trace took (`sort`, `masked`,
+#: `scatter`; `none` for a grand aggregate), for the `agg.*` spans of
+#: every later run of that program in this process
+_PATHS: dict = {}
+
+
+def _noting_path(key: tuple, fn):
+    """`fn`, remembering under `key` which group-by path it takes
+    when it is traced.  The wrapper runs at trace time alone."""
+    def traced(*args):
+        with noting_paths() as paths:
+            out = fn(*args)
+        _PATHS[key] = "+".join(paths) or "none"
+        return out
+    return traced
 
 
 def _as_device_rows(batch):
@@ -270,6 +289,39 @@ class TpuHashAggregateExec(TpuExec):
         return groupby_aggregate(partial, list(range(self.n_keys)),
                                  self.merge_specs, self.partial_schema)
 
+    def _merge_traced(self, partials: ColumnarBatch) -> ColumnarBatch:
+        """The merge program over the concatenated partials, under an
+        `agg.merge` span that says how many partial rows went in."""
+        rows = partials.num_rows
+        with _trace.span("agg.merge", capacity=partials.capacity,
+                         rows=rows if isinstance(rows, int) else None,
+                         path=_PATHS.get(self._path_keys[1])):
+            return self._jit_merge(_as_device_rows(partials))
+
+    def _tick_absorbed(self, batch) -> None:
+        """Output rows of the absorbed execs whose count the input's
+        fixes (a projection's, an expand's fan-out of it), deferred as
+        every row metric is: their own `execute()` never runs.  Stops
+        at the first exec that drops rows."""
+        chain = self._absorbed_chain()
+        rows = getattr(batch, "num_rows", None)
+        if chain is None or rows is None:
+            return
+        from spark_rapids_tpu.execs.base import (
+            NUM_OUTPUT_BATCHES,
+            NUM_OUTPUT_ROWS,
+        )
+        from spark_rapids_tpu.execs.basic import TpuProjectExec
+
+        times = 1
+        for e in chain[0]:
+            fanout = getattr(e, "fanout", None)
+            if fanout is None and not isinstance(e, TpuProjectExec):
+                return
+            times *= fanout or 1
+            e.metrics[NUM_OUTPUT_ROWS].add_lazy(rows, times)
+            e.metrics[NUM_OUTPUT_BATCHES].add(1)
+
     def _drain_final_fused(self, pending, rows_hint: int):
         """Final drain as ONE program: concat (traced stack+compact) +
         merge + finalize, mode-dependent.  Saves 2-3 program executions
@@ -478,8 +530,10 @@ class TpuHashAggregateExec(TpuExec):
                             b = st(b)
                     return self._update_batch(b, mask)
 
-                upd = cached_jit(key + ("absorb", ckeys, "update"),
-                                 lambda: update_full, op=self.name)
+                upd_key = key + ("absorb", ckeys, "update")
+                upd = cached_jit(upd_key,
+                                 lambda: _noting_path(upd_key, update_full),
+                                 op=self.name)
                 # the donated twin: same traced program, wire
                 # components donate_argnums'd so XLA reuses their HBM
                 # for the partial columns.  A SEPARATE cached program
@@ -492,12 +546,15 @@ class TpuHashAggregateExec(TpuExec):
                 )
 
                 upd_d = cached_jit(
-                    key + ("absorb", ckeys, "update"),
-                    lambda: update_full, op=self.name,
+                    upd_key, lambda: _noting_path(upd_key, update_full),
+                    op=self.name,
                     donate=(0,)) if donation_enabled() else None
+                self._path_keys = (upd_key, key + ("merge",))
                 self._jits = (
                     upd, upd_d,
-                    cached_jit(key + ("merge",), lambda: self._merge_batch,
+                    cached_jit(key + ("merge",),
+                               lambda: _noting_path(key + ("merge",),
+                                                    self._merge_batch),
                                op=self.name),
                     cached_jit(key + ("final",),
                                lambda: self._finalize_batch,
@@ -673,9 +730,13 @@ class TpuHashAggregateExec(TpuExec):
             donation is on: run_consuming marks the batch consumed
             and memoizes the output, so a ladder re-run of this unit
             resumes instead of re-executing over donated buffers."""
-            with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
-                if self.mode == "final":
-                    return batch  # already partial layout
+            if self.mode == "final":
+                return batch  # already partial layout
+            self._tick_absorbed(batch)
+            with _trace.span("agg.update",
+                             capacity=getattr(batch, "capacity", None),
+                             path=_PATHS.get(self._path_keys[0])), \
+                    MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
                 enc = isinstance(batch, EncodedBatch)
                 if enc and self._jit_update_donated is not None:
                     # a retry-ladder re-run of a consumed batch
@@ -708,7 +769,7 @@ class TpuHashAggregateExec(TpuExec):
             def att():
                 if "b" not in state:
                     state["b"] = drain_pending(commit=False)
-                return self._jit_merge(_as_device_rows(state["b"]))
+                return self._merge_traced(state["b"])
 
             try:
                 merged = R.run_with_oom_retry(att, desc="agg.merge")
@@ -883,12 +944,24 @@ class TpuHashAggregateExec(TpuExec):
                     state["b"] = drain_pending(commit=False)
                 m = state["b"]
                 if not single or self.mode == "final":
-                    m = self._jit_merge(_as_device_rows(m))
+                    m = self._merge_traced(m)
                 if self.mode == "partial":
                     return m
                 return self._jit_finalize(_as_device_rows(m))
 
             out = R.run_with_oom_retry(final_att, desc="agg.drain")
             finish_drain()
+            # the drained partials' concat: this frame stays suspended
+            # while the operators above work on `out`
+            state.clear()
+            if not isinstance(out.num_rows, int) \
+                    and out.capacity > _DEFER_SYNC_CAP:
+                # a merge's output keeps the capacity of the partials
+                # that went in, and every operator above pays by
+                # capacity: count the groups (one readback, as every
+                # large partial pays) and size the batch to them
+                n = P.device_read_int(out.num_rows, tag="agg.size")
+                out = dataclasses.replace(out, num_rows=n) \
+                    .shrink_to_capacity(pad_capacity(n))
             t.observe(out)
         yield self._count_output(out)

@@ -55,13 +55,14 @@ class TpuMetric:
         with self._lock:
             self._value += v
 
-    def add_lazy(self, v) -> None:
-        """Add a host int now or a device scalar at read time."""
+    def add_lazy(self, v, times: int = 1) -> None:
+        """Add a host int now or a device scalar at read time, `times`
+        times over (an expand's fan-out of its input's count)."""
         if isinstance(v, int):
-            self.add(v)
+            self.add(v * times)
             return
         with self._lock:
-            self._pending.append(v)
+            self._pending.extend([v] * times)
             if len(self._pending) < self._FLUSH_AT:
                 return
             pending, self._pending = self._pending, []

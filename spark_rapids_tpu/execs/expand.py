@@ -34,6 +34,11 @@ class TpuExpandExec(FusableExec):
     def schema(self) -> T.Schema:
         return self._schema
 
+    @property
+    def fanout(self) -> int:
+        """Output rows per input row."""
+        return len(self.projections)
+
     def node_desc(self) -> str:
         return f"TpuExpandExec [{len(self.projections)} projections]"
 

@@ -37,6 +37,7 @@ from typing import Iterator, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, concat_batches
 from spark_rapids_tpu.columnar.column import pad_capacity
@@ -160,7 +161,10 @@ class _HashJoinBase(TpuExec):
         finally:
             for h in handles:
                 h.close()
-        self.metrics["buildRows"].add(b.concrete_num_rows())
+        rows = b.concrete_num_rows()
+        self.metrics["buildRows"].add(rows)
+        _trace.event("join.build", op=self.name, rows=rows,
+                     capacity=b.capacity, batches=len(collected))
         return b
 
     def _empty_build(self) -> ColumnarBatch:
@@ -376,7 +380,13 @@ class _HashJoinBase(TpuExec):
                 if n_total <= cap:
                     self.metrics["specHits"].add(1)
                     SP.record_hit("join.probe", cap, n_total)
-                    yield self._count_output(o)
+                    # the chunk was sized by a prediction with room to
+                    # spare; now that the count is here, a bucket or
+                    # more too large is cut to the rows' own, because
+                    # every operator above pays by capacity (q67: an
+                    # Expand of nine times it)
+                    yield self._count_output(
+                        o.shrink_to_capacity(pad_capacity(n_total)))
                     return
                 # undershoot: the speculated chunk covers [0, cap);
                 # continuation chunks pick up from there — expand_pairs

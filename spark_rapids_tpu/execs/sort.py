@@ -49,6 +49,13 @@ SORT_SINGLE_BATCH_ROWS = register(
     "Row threshold above which a global sort switches from one-device-"
     "batch sorting to the out-of-core sample-split sort (the "
     "OutOfCoreSort mode analog, ref: GpuSortExec.scala:38-40).")
+#: a global sort counts an input batch of more than this capacity
+#: before it augments it, and sizes it to its rows: one readback in an
+#: operator that waits for all of its input anyway, against a sort
+#: paid by capacity.  The aggregate's `_DEFER_SYNC_CAP`, for the same
+#: reason.
+_COUNT_ABOVE_CAPACITY = 1 << 18
+
 SORT_SAMPLE_PER_BATCH = register(
     "spark.rapids.tpu.sql.sort.samplesPerBatch", 128,
     "Rows sampled from each input batch to estimate range-bucket bounds "
@@ -104,6 +111,14 @@ class TpuSortExec(_SortMixin):
     independently (the SortEachBatch mode used below partial
     aggregations).  `global_sort=False` is the legacy spelling of
     scope='batch'."""
+
+    #: a global sort counts an input batch of more than
+    #: `_COUNT_ABOVE_CAPACITY` and sizes it to its rows BEFORE it
+    #: augments and sorts it (see `ingest`).  Without that a sort above
+    #: a selective filter runs at the filter's input capacity: q67's
+    #: last sort was a 5.55 GB program for 1,100 rows, more than the
+    #: chip had left.  A caller that cannot run without it may ask.
+    sizes_counted_input = True
 
     def __init__(self, keys: Sequence[SortKey], child: TpuExec,
                  global_sort: bool = True, scope: Optional[str] = None):
@@ -296,6 +311,16 @@ class TpuSortExec(_SortMixin):
                 d0, t0, rows0 = list(deferred), total, list(rows)
                 try:
                     if depth == 0:
+                        if not isinstance(b.num_rows, int) \
+                                and b.capacity > _COUNT_ABOVE_CAPACITY:
+                            # count it first and size it to its rows:
+                            # augment, sort and gather are paid by
+                            # capacity, and a filter's output keeps its
+                            # input's (q67: 1,100 live rows in a bucket
+                            # of 2^21)
+                            n = b.concrete_num_rows()
+                            b = _dc.replace(b, num_rows=n) \
+                                .shrink_to_capacity(pad_capacity(n))
                         aug = jit_aug(b.with_device_num_rows())
                     else:
                         aug = b  # recursive input: already augmented

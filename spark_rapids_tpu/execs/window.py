@@ -225,23 +225,29 @@ class TpuWindowExec(TpuExec):
                 repr(self._schema))
 
     def _window_source(self, source) -> Iterator[ColumnarBatch]:
+        from spark_rapids_tpu import trace as _trace
         from spark_rapids_tpu.execs.jit_cache import cached_jit
         from spark_rapids_tpu.memory import SpillPriorities, get_store
 
         store = get_store()
         handles = []
-        try:
-            for b in source:
-                handles.append(store.register(
-                    b, SpillPriorities.COALESCE_PENDING))
-            if not handles:
-                return
-            batches = [h.get() for h in handles]
-            big = batches[0] if len(batches) == 1 else \
-                concat_batches(batches)
-        finally:
-            for h in handles:
-                h.close()
+        # the child drained to one batch: this operator's wait for
+        # those below it, then the concat
+        with _trace.span("window.collect") as collected:
+            try:
+                for b in source:
+                    handles.append(store.register(
+                        b, SpillPriorities.COALESCE_PENDING))
+                if not handles:
+                    return
+                batches = [h.get() for h in handles]
+                big = batches[0] if len(batches) == 1 else \
+                    concat_batches(batches)
+            finally:
+                for h in handles:
+                    h.close()
+            collected.note(batches=len(batches), capacity=big.capacity,
+                           rows=_host_rows(big))
         # partitioned check first: the unpartitioned path must not pay
         # a sizing round trip just to test emptiness (the window program
         # handles zero live rows; empty SOURCES returned above)
@@ -249,8 +255,13 @@ class TpuWindowExec(TpuExec):
             return  # empty reduce partition
         fn = cached_jit(self._cache_key(), lambda: self._window_batch,
                         op=self.name)
-        with MetricTimer(self.metrics[TOTAL_TIME], op=self.name):
-            out = fn(big.with_device_num_rows())
+        with _trace.span("window.partition", capacity=big.capacity,
+                         rows=_host_rows(big)):
+            with MetricTimer(self.metrics[TOTAL_TIME], op=self.name):
+                out = fn(big.with_device_num_rows())
+        # this frame stays suspended while the operators above work on
+        # `out`: it must not hold the partition's input beside it
+        del batches, big
         yield self._count_output(out)
 
     def execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
@@ -269,6 +280,12 @@ class TpuWindowExec(TpuExec):
             return
         for p in range(self.num_partitions):
             yield from self.execute_partition(p)
+
+
+def _host_rows(batch: ColumnarBatch):
+    """The batch's row count where the host holds it, else None: a
+    span never pays a readback for its attributes."""
+    return batch.num_rows if isinstance(batch.num_rows, int) else None
 
 
 def expr_key_fn(we: WindowExpression) -> tuple:

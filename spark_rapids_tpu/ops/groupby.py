@@ -22,8 +22,10 @@ batch shows:
 
        sort rows by key -> mark segment starts -> segment_{sum,min,max}
 
-   with output capacity equal to the input's; its per-spec `_eval_agg`
-   still scatters (q3's aggregate; ROADMAP S2).
+   with output capacity equal to the input's.  The keys of the groups
+   are gathered from each segment's first row (one more stable pass
+   finds them); the per-spec `_eval_agg` still scatters (q3's and
+   q67's aggregates; ROADMAP S2).
 
 The times are the chip's (PERF.md section 6, PR 26).  Aggregations are
 expressed as (update, merge) pairs the way Spark aggregate modes are
@@ -33,8 +35,10 @@ reuse the same kernels on the partial-result columns.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
+import threading
 from typing import Optional, Sequence
 
 import jax
@@ -50,7 +54,33 @@ from spark_rapids_tpu.columnar.column import (
     StringColumn,
     pad_capacity,
 )
-from spark_rapids_tpu.ops.sort import SortOrder, sort_permutation
+from spark_rapids_tpu.ops.sort import (
+    SortOrder,
+    sort_permutation,
+    stable_argsort,
+)
+
+_TRACING = threading.local()
+
+
+@contextlib.contextmanager
+def noting_paths():
+    """Collects, while a program is traced on this thread, the path
+    each `groupby_aggregate` in it takes (`masked`, `scatter`, `sort`):
+    the choice is made from static shapes, once a trace, so whoever
+    compiles the program keeps it for the spans of its later runs."""
+    prev = getattr(_TRACING, "paths", None)
+    _TRACING.paths = paths = []
+    try:
+        yield paths
+    finally:
+        _TRACING.paths = prev
+
+
+def _note_path(path: str) -> None:
+    paths = getattr(_TRACING, "paths", None)
+    if paths is not None:
+        paths.append(path)
 
 
 def _keys_equal_adjacent(col: AnyColumn) -> jax.Array:
@@ -270,6 +300,7 @@ def _coded_groupby(batch: ColumnarBatch, key_ordinals: Sequence[int],
     # grows with K x m, the scatter's with neither
     m = len(f64_cols) + len(i64_cols)
     masked = K * m <= MAX_MASKED_CELLS
+    _note_path("masked" if masked else "scatter")
     if _trace.TRACER.enabled:
         _trace.event("groupby.coded_reduce",
                      kind="masked" if masked else "scatter",
@@ -361,6 +392,7 @@ def groupby_aggregate(batch: ColumnarBatch, key_ordinals: Sequence[int],
     if ks is not None:
         return _coded_groupby(batch, key_ordinals, ks, aggs, out_schema,
                               live_mask)
+    _note_path("sort")
     cap = batch.capacity
     live = batch.row_mask()
     if live_mask is not None:
@@ -382,24 +414,20 @@ def groupby_aggregate(batch: ColumnarBatch, key_ordinals: Sequence[int],
     num_groups = jnp.sum(is_start.astype(jnp.int32))
 
     out_cols: list[AnyColumn] = []
-    # keys: value at each segment start, scattered to [0, num_groups)
-    start_dest = jnp.where(is_start, seg_id, cap)
+    # keys: the value at each segment's first row.  The g-th group's
+    # first row is the g-th row that starts a segment: one more stable
+    # pass puts those rows first, in order, and each key array is then
+    # ONE gather.  (A scatter to [0, num_groups) is the same answer at
+    # 91 ns a row and array on the TPU, where a gather takes a tenth:
+    # q67's update writes 23 key arrays of 4.7M rows.)
+    first_rows = stable_argsort(~is_start)
     group_live = idx < num_groups
     for kc in key_cols:
-        if isinstance(kc, StringColumn):
-            chars = jnp.zeros_like(kc.chars).at[start_dest].set(
-                kc.chars, mode="drop")
-            lengths = jnp.zeros_like(kc.lengths).at[start_dest].set(
-                kc.lengths, mode="drop")
-            valid = jnp.zeros_like(kc.validity).at[start_dest].set(
-                kc.validity, mode="drop") & group_live
-            out_cols.append(StringColumn(chars, lengths, valid))
-        else:
-            data = jnp.zeros_like(kc.data).at[start_dest].set(
-                kc.data, mode="drop")
-            valid = jnp.zeros_like(kc.validity).at[start_dest].set(
-                kc.validity, mode="drop") & group_live
-            out_cols.append(Column(data, valid, kc.dtype))
+        g = kc.gather(first_rows, group_live)
+        # plain columns, as a partial carries no dictionary sidecar
+        out_cols.append(StringColumn(g.chars, g.lengths, g.validity)
+                        if isinstance(kc, StringColumn)
+                        else Column(g.data, g.validity, kc.dtype))
 
     for spec in aggs:
         out_cols.append(_eval_agg(spec, sorted_batch, seg_id, live_sorted,

@@ -20,6 +20,7 @@ import itertools
 import time
 from typing import Iterator, Optional, Sequence
 
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu.config import register
 from spark_rapids_tpu.execs.base import TpuExec
 
@@ -97,6 +98,27 @@ def _snap(node: TpuExec) -> NodeSnapshot:
         node.node_desc(),
         {name: m.value for name, m in node.metrics.items()},
         [_snap(c) for c in node.children])
+
+
+def _trace_operators(root: NodeSnapshot, qid: int, at_ns: int) -> None:
+    """One `query.operator` instant an operator of the plan that ran,
+    stamped at the query's end: its settled counts (rows and batches
+    out, `rows_in` the rows its children put out, every other metric
+    that moved) on the tracer's timeline, where a reader of spans
+    finds them; the counts were deferred, so no operator paid a
+    readback for them."""
+    todo = [root]
+    while todo:
+        node = todo.pop()
+        todo += node.children
+        moved = {k: v for k, v in node.metrics.items()
+                 if isinstance(v, (int, float)) and v}
+        _trace.TRACER.record(
+            "query.operator", at_ns, 0,
+            {"query_id": qid, "op": node.desc.split(" ", 1)[0],
+             "desc": node.desc[:160],
+             "rows_in": sum(c.metrics.get("numOutputRows", 0)
+                            for c in node.children), **moved}, ph="i")
 
 
 def snapshot_delta(after: NodeSnapshot,
@@ -201,6 +223,8 @@ class QueryHistory:
                             wall_s, ts, start_ts=start_ts,
                             end_ts=end_ts, start_ns=start_ns,
                             end_ns=end_ns, conf_hash=conf_hash)
+            if _trace.TRACER.enabled:
+                _trace_operators(root, qid, end_ns)
             with self._mu:
                 self._events.append(ev)
                 if len(self._events) > self.capacity:

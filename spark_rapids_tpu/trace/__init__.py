@@ -327,6 +327,9 @@ class _NoopSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def note(self, **attrs: Any) -> None:
+        pass
+
 
 _NOOP = _NoopSpan()
 
@@ -341,6 +344,10 @@ class _Span:
     def __enter__(self) -> "_Span":
         self.t0 = time.perf_counter_ns()
         return self
+
+    def note(self, **attrs: Any) -> None:
+        """Attributes that are known only once the region has run."""
+        self.attrs.update(attrs)
 
     def __exit__(self, *exc) -> bool:
         # a clear() between enter and exit discards this span: record()
