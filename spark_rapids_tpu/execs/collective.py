@@ -23,9 +23,9 @@ mesh with NamedSharding end-to-end — rounds are a lax.scan INSIDE the
 compiled program (bucketed by .spmd.bucketRounds), inputs arrive as
 global sharded arrays, and the per-round host syncs
 (concrete_num_rows, shrink) of the legacy host-loop driver are
-deferred to ONE stage-exit counts fetch.  spmd.enabled=false keeps
-the legacy per-round host loop (one dispatch + 2n syncs per round) —
-the digest-comparison baseline for the SPMD path."""
+deferred to one counts fetch a program boundary.  spmd.enabled=false
+keeps the legacy per-round host loop (one dispatch + 2n syncs per
+round) — the digest-comparison baseline for the SPMD path."""
 
 from __future__ import annotations
 
@@ -35,6 +35,7 @@ from typing import Iterator, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch, concat_batches
 from spark_rapids_tpu.columnar.column import pad_capacity
@@ -241,11 +242,15 @@ class _CollectiveBase(TpuExec):
 class TpuCollectiveHashAggregateExec(_CollectiveBase):
     """Grouped aggregation as fused SPMD programs over the active mesh.
 
-    Per round: map-side update aggregation, hash all_to_all on the
-    group keys, and reduce-side merge run as ONE program; per-shard
-    round results park on device, and a final per-shard local program
-    (merge + finalize, no collectives) folds the rounds — same keys
-    always land on the same shard, so the cross-round merge is local."""
+    Per round: map-side update aggregation, then hash all_to_all on
+    the group keys and reduce-side merge.  The SPMD stage runs the
+    update as its own program, counts the partial rows and runs the
+    exchange + merge program at THEIR capacity (`_materialize_spmd`);
+    the host-loop driver keeps all three fused in one step at the
+    input round's capacity.  Per-shard round results park on device,
+    and a final per-shard local program (merge + finalize, no
+    collectives) folds the rounds — same keys always land on the same
+    shard, so the cross-round merge is local."""
 
     def __init__(self, groups: Sequence[Expression],
                  aggs: Sequence[NamedAgg], child: TpuExec, mesh,
@@ -276,7 +281,8 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
 
     def additional_metrics(self):
         return [("collectiveRows", "MODERATE"),
-                ("collectiveRounds", "MODERATE")]
+                ("collectiveRounds", "MODERATE"),
+                ("collectivePartialRows", "MODERATE")]
 
     # -- fused phases ----------------------------------------------------- #
 
@@ -300,13 +306,19 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
         return self._materialize_host_loop()
 
     def _materialize_spmd(self) -> list[list[ColumnarBatch]]:
-        """The aggregation stage as O(1) partitioned programs: one
-        exchange-scan program per round bucket (map-side update ->
-        in-program hash all_to_all -> reduce-side merge, all rounds
-        folded into a lax.scan), ONE mid-stage counts fetch + shrink,
-        then one tail program (cross-round merge + finalize) at tight
-        capacity — same keys always land on the same shard, so the
-        cross-round fold is shard-local."""
+        """The aggregation stage as O(1) partitioned programs.  Per
+        round bucket: an update program (map-side partial aggregation,
+        rounds folded into a lax.scan, no collective), ONE counts
+        fetch + shrink that cuts every (round, shard) partial to its
+        counted rows, then an exchange program (in-program hash
+        all_to_all -> reduce-side merge, the same scan) at THAT
+        capacity — the shuffle carries groups, not the input round's
+        padding.  After the last bucket: one more counts fetch +
+        shrink and one tail program (cross-round merge + finalize) at
+        tight capacity — same keys always land on the same shard, so
+        the cross-round fold is shard-local.  A group-by whose
+        partials are as many as its rows counts its way back to the
+        input's bucket, so one path serves both."""
         from spark_rapids_tpu.parallel import spmd as S
         from spark_rapids_tpu.parallel.exchange import exchange_shard
 
@@ -315,9 +327,12 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
         akey = self._agg._cache_key()
         ko = list(range(self._agg.n_keys))
 
-        def xchg_body(b: ColumnarBatch) -> ColumnarBatch:
-            return self._merge(
-                exchange_shard(self._pre(b), ko, n, DATA_AXIS))
+        def xchg_body(partial: ColumnarBatch) -> ColumnarBatch:
+            return self._merge(exchange_shard(partial, ko, n, DATA_AXIS))
+
+        def capacity(rounds) -> int:
+            # what shard_stack_rounds unifies the grid to
+            return max(b.capacity for shards in rounds for b in shards)
 
         with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
             shrunk: list[list[ColumnarBatch]] = []  # rounds[r][d]
@@ -325,12 +340,25 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
 
             def flush(bucket):
                 bucket = S.pad_rounds_pow2(bucket, child.schema, n)
-                xs = S.shard_stack_rounds(bucket, self.mesh)
+                input_cap = capacity(bucket)
+                update = S.make_update_scan_stage(
+                    self.mesh, akey, self._pre, len(bucket),
+                    op=self.name, donate=True)
+                partials = update(S.shard_stack_rounds(bucket, self.mesh))
+                counts = S.stage_counts(partials)
+                sized = S.shrink_rounds(partials, counts, mesh=self.mesh)
+                self.metrics["collectivePartialRows"].add(
+                    int(counts.sum()))
                 prog = S.make_exchange_scan_stage(
                     self.mesh, akey, xchg_body, len(bucket),
                     op=self.name, donate=True)
-                shrunk.extend(S.shrink_rounds(prog(xs),
-                                              mesh=self.mesh))
+                with _trace.span("collective.agg.exchange",
+                                 input_capacity=input_cap,
+                                 capacity=capacity(sized),
+                                 partial_rows=int(counts.max()),
+                                 rounds=len(bucket)):
+                    merged = prog(S.shard_stack_rounds(sized, self.mesh))
+                shrunk.extend(S.shrink_rounds(merged, mesh=self.mesh))
 
             for shards in self._shard_rounds(child):
                 bucket.append(shards)

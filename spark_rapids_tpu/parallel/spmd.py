@@ -22,9 +22,11 @@ active mesh with `NamedSharding` end-to-end —
   ``all_to_all`` body of parallel/exchange.py runs inside a
   ``lax.scan`` over the rounds axis — R exchange rounds compile once
   and dispatch once, instead of R host dispatches;
-- per-round host syncs are DEFERRED to stage exit: one
-  ``stage_counts`` fetch of the output row-count array replaces the
-  per-round per-shard `concrete_num_rows` + shrink choreography.
+- per-round host syncs are DEFERRED to program boundaries: one
+  ``stage_counts`` fetch of a program's output row-count array
+  replaces the per-round per-shard `concrete_num_rows` + shrink
+  choreography (the aggregate stage fetches twice a bucket: its
+  update program's counts size the exchange, docs/spmd.md).
 
 Programs compile through execs/jit_cache.cached_jit with the sharding
 spec pair folded into the structural key (plus parallel.mesh.mesh_key,
@@ -383,17 +385,11 @@ def _stage_jit(key: tuple, make_fn, mesh, op, in_shardings,
         meta={"devices": n, "rounds": n_rounds})
 
 
-def make_exchange_scan_stage(mesh, key: tuple, body: Callable,
-                             n_rounds: int, op: Optional[str] = None,
-                             donate: bool = False):
-    """The EXCHANGE program of a stage: lax.scan over the rounds axis
-    applying `body` (per-shard round batch -> per-shard batch; the
-    in-program all_to_all — exchange_shard / route_shard — lives
-    inside `body`, as do any fused map/reduce phases).  Emits the
-    round-stacked per-shard outputs at the worst-case n x cap receive
-    capacity; the host shrinks them ONCE at stage exit
-    (`shrink_rounds`) before the tail program, so the tail's work is
-    proportional to live rows, not padding."""
+def _rounds_scan_stage(tag: str, mesh, key: tuple, body: Callable,
+                       n_rounds: int, op: Optional[str],
+                       donate: bool):
+    """A program that scans `body` (per-shard round batch -> per-shard
+    batch) over the rounds axis: round-stacked in, round-stacked out."""
     axis = DATA_AXIS
 
     def make():
@@ -407,9 +403,39 @@ def make_exchange_scan_stage(mesh, key: tuple, body: Callable,
                           P(None, axis))
 
     return _stage_jit(
-        ("spmdxchg", key, n_rounds), make, mesh, op,
+        (tag, key, n_rounds), make, mesh, op,
         (rounds_sharding(mesh),), rounds_sharding(mesh),
         (0,) if donate else None, n_rounds)
+
+
+def make_update_scan_stage(mesh, key: tuple, body: Callable,
+                           n_rounds: int, op: Optional[str] = None,
+                           donate: bool = False):
+    """The MAP-SIDE program of the aggregate stage: lax.scan over the
+    rounds axis applying `body` (the partial-aggregate update) per
+    shard, NO collective.  Emits the round-stacked partials at the
+    input's capacity with their (R, n) row counts; the host fetches
+    those counts once and cuts every partial to its counted rows
+    (`shrink_rounds`) BEFORE the exchange program, so the all_to_all
+    and the reduce-side merge are sized to the groups the shuffle
+    carries and not to the input round's padding."""
+    return _rounds_scan_stage("spmdupdate", mesh, key, body, n_rounds,
+                              op, donate)
+
+
+def make_exchange_scan_stage(mesh, key: tuple, body: Callable,
+                             n_rounds: int, op: Optional[str] = None,
+                             donate: bool = False):
+    """The EXCHANGE program of a stage: lax.scan over the rounds axis
+    applying `body` (per-shard round batch -> per-shard batch; the
+    in-program all_to_all — exchange_shard / route_shard — lives
+    inside `body`, as do any fused map/reduce phases).  Emits the
+    round-stacked per-shard outputs at the worst-case n x cap receive
+    capacity; the host shrinks them ONCE at stage exit
+    (`shrink_rounds`) before the tail program, so the tail's work is
+    proportional to live rows, not padding."""
+    return _rounds_scan_stage("spmdxchg", mesh, key, body, n_rounds,
+                              op, donate)
 
 
 def make_stage_tail(mesh, key: tuple, fn: Callable, n_rounds: int,

@@ -249,7 +249,10 @@ def test_choose_bounds_dynamic_matches_static():
 def _canon(table: pa.Table) -> list:
     d = table.to_pydict()
     cols = sorted(d)
-    return sorted(zip(*[d[c] for c in cols])) if cols else []
+    # NULL keys sort last instead of failing the comparison
+    return sorted(zip(*[d[c] for c in cols]),
+                  key=lambda row: tuple((x is None, x) for x in row)
+                  ) if cols else []
 
 
 def _assert_same_result(session, make_df, conf):
@@ -356,33 +359,62 @@ def _collective_programs(snap: dict) -> dict:
             if v["tag"].startswith("spmd")}
 
 
+def _budget_table(distinct: bool = False) -> pa.Table:
+    rng = np.random.default_rng(23)
+    k = rng.permutation(8192) if distinct else rng.integers(0, 64, 8192)
+    return pa.table({"k": k.astype(np.int64),
+                     "v": rng.integers(0, 100, 8192).astype(np.int64)})
+
+
+def _budget_query(session, conf, t: pa.Table):
+    """The dispatch-budget query: a round closes when one shard hits
+    the budget; with least-loaded filling that is ~8 shards x 128 rows
+    = 1024 rows per round -> 8192 rows = ~8 rounds of input, one full
+    bucket (and the trailing round's bucket, if any)."""
+    conf.set(ROUND_KEY, 128)
+    conf.set(BATCH_KEY, 64)
+    conf.set(BUCKET_KEY, 8)
+    return (session.create_dataframe(t).group_by(col("k"))
+            .agg((sum_(col("v")), "s")))
+
+
+def _agg_node(exec_):
+    return next(nd for nd in exec_._walk()
+                if type(nd).__name__ == "TpuCollectiveHashAggregateExec")
+
+
+def _traced_collect(exec_):
+    """collect_exec under the tracer: (answer, the stage's
+    `collective.agg.exchange` span attrs, one dict a bucket)."""
+    from spark_rapids_tpu import trace
+    from spark_rapids_tpu.plan.planner import collect_exec
+
+    trace.enable()
+    trace.clear()
+    try:
+        got = collect_exec(exec_)
+        spans = [e.attrs for e in trace.snapshot()
+                 if e.name == "collective.agg.exchange"]
+    finally:
+        trace.disable()
+        trace.clear()
+    return got, spans
+
+
 def test_spmd_stage_dispatch_budget(collective_session, conf_sandbox):
     """Many exchange rounds, O(1) program dispatches: with the round
-    budget forced tiny (16 rounds' worth of input), the warm agg stage
-    still executes as at most bucket-chain + fold programs — the
-    rounds run as an in-program scan, not a Python loop of dispatches
-    — and the ledger attributes the partitioned programs with their
-    mesh width and in-program round counts."""
+    budget forced tiny (8+ rounds' worth of input), the warm agg stage
+    still executes as two programs a bucket (update, exchange) plus one
+    fold — the rounds run as an in-program scan, not a Python loop of
+    dispatches — and the ledger attributes the partitioned programs
+    with their mesh width and in-program round counts."""
     from spark_rapids_tpu.plan.planner import collect_exec, plan_query
     from spark_rapids_tpu.trace import ledger
 
-    rng = np.random.default_rng(23)
-    t = pa.table({"k": rng.integers(0, 64, 8192).astype(np.int64),
-                  "v": rng.integers(0, 100, 8192).astype(np.int64)})
-    # a round closes when one shard hits the budget; with least-loaded
-    # filling that is ~8 shards x 128 rows = 1024 rows per round ->
-    # 8192 rows = ~8 rounds of input in one bucket
-    conf_sandbox.set(ROUND_KEY, 128)
-    conf_sandbox.set(BATCH_KEY, 64)
-    conf_sandbox.set(BUCKET_KEY, 8)
-    df = (collective_session.create_dataframe(t).group_by(col("k"))
-          .agg((sum_(col("v")), "s")))
+    t = _budget_table()
+    df = _budget_query(collective_session, conf_sandbox, t)
     exec_, _ = plan_query(df._plan, collective_session.conf)
     assert "stage=spmd" in exec_.tree_string()
-    rounds_seen = sum(
-        node.metrics["collectiveRounds"].value
-        for node in exec_._walk()
-        if "collectiveRounds" in node.metrics)
 
     ledger.enable()
     ledger.reset_stats()
@@ -391,9 +423,14 @@ def test_spmd_stage_dispatch_budget(collective_session, conf_sandbox):
         ledger.LEDGER.flush(timeout=10.0)
         snap = _collective_programs(ledger.snapshot())
         dispatches = sum(p["dispatches"] for p in snap.values())
-        # stage budget: bucketed scan programs + one fold — never one
-        # dispatch per round
-        assert 1 <= dispatches <= 4, snap
+        rounds = _agg_node(exec_).metrics["collectiveRounds"].value
+        buckets = -(-rounds // 8)
+        assert rounds >= 8, rounds
+        # stage budget: an update and an exchange program a bucket and
+        # one fold — never one dispatch per round
+        assert dispatches == 2 * buckets + 1, snap
+        assert {p["tag"] for p in snap.values()} == {
+            "spmdupdate", "spmdxchg", "spmdtail"}, snap
         assert all(p["devices"] == N_DEV for p in snap.values()), snap
         scan_rounds = max(p["rounds"] for p in snap.values())
         assert scan_rounds >= 8, snap  # rounds folded INTO a program
@@ -402,6 +439,90 @@ def test_spmd_stage_dispatch_budget(collective_session, conf_sandbox):
         ledger.reset_stats()
     want = t.group_by("k").aggregate([("v", "sum")])
     assert _canon(got) == _canon(want)
+
+
+def test_spmd_agg_exchange_sized_to_counted_partials(collective_session,
+                                                     conf_sandbox):
+    """The exchange program of the aggregate stage runs at the capacity
+    of the largest COUNTED partial, not at the input round's: the
+    `collective.agg.exchange` span says what it was sized to, and
+    `collectivePartialRows` is the partial rows the shuffle carried —
+    the distinct keys of every (round, shard) input, summed."""
+    from spark_rapids_tpu.columnar.column import pad_capacity
+    from spark_rapids_tpu.plan.planner import plan_query
+
+    t = _budget_table()
+    df = _budget_query(collective_session, conf_sandbox, t)
+    # what the map side must count, from the same round staging
+    twin, _ = plan_query(df._plan, collective_session.conf)
+    node = _agg_node(twin)
+    per_shard = [len(set(b.to_pydict()["k"]))
+                 for shards in node._shard_rounds(node.children[0])
+                 for b in shards]
+
+    exec_, _ = plan_query(df._plan, collective_session.conf)
+    got, spans = _traced_collect(exec_)
+    assert spans and sum(s["rounds"] for s in spans) >= 8, spans
+    for s in spans:
+        assert s["capacity"] == pad_capacity(s["partial_rows"]), s
+        assert s["capacity"] < s["input_capacity"], s
+    assert max(s["partial_rows"] for s in spans) == max(per_shard)
+    metrics = _agg_node(exec_).metrics
+    assert metrics["collectivePartialRows"].value == sum(per_shard)
+    want = t.group_by("k").aggregate([("v", "sum")])
+    assert _canon(got) == _canon(want)
+
+
+def test_spmd_agg_exchange_stands_aside_for_distinct_keys(
+        collective_session, conf_sandbox):
+    """Every key distinct: the partials are as many as the rows, the
+    counted capacity IS the input's bucket and the exchange runs at
+    the size it always did — no shape test, no knob, same answer."""
+    from spark_rapids_tpu.plan.planner import plan_query
+
+    t = _budget_table(distinct=True)
+    df = _budget_query(collective_session, conf_sandbox, t)
+    exec_, _ = plan_query(df._plan, collective_session.conf)
+    got, spans = _traced_collect(exec_)
+    assert spans
+    full = [s for s in spans if s["rounds"] >= 8]
+    assert full, spans
+    for s in full:
+        assert s["capacity"] == s["input_capacity"], s
+    metrics = _agg_node(exec_).metrics
+    assert metrics["collectivePartialRows"].value == t.num_rows
+    want = t.group_by("k").aggregate([("v", "sum")])
+    assert _canon(got) == _canon(want)
+
+
+def test_spmd_agg_string_keys_with_nulls_digest(collective_session,
+                                                conf_sandbox):
+    """String group keys with NULLs, two rounds a bucket, double sums
+    (whose digest moves with the ORDER of a sum's terms): the counted
+    exchange keeps sender order and the merge sorts by key, so SPMD
+    and host-loop answers are identical to the bit."""
+    rng = np.random.default_rng(31)
+    words = np.array(["", "a", "bb", "Ünï", "delta-long-key", "zz"])
+    k1 = [None if x == 6 else str(words[x])
+          for x in rng.integers(0, 7, 2000)]
+    k2 = [None if x == 3 else "NYR"[x]
+          for x in rng.integers(0, 4, 2000)]
+    t = pa.table({"k1": pa.array(k1, pa.string()),
+                  "k2": pa.array(k2, pa.string()),
+                  "v": rng.random(2000) * 1e6})
+    conf_sandbox.set(ROUND_KEY, 64)
+    conf_sandbox.set(BATCH_KEY, 64)
+    conf_sandbox.set(BUCKET_KEY, 2)
+
+    def q(s):
+        return (s.create_dataframe(t).group_by(col("k1"), col("k2"))
+                .agg((sum_(col("v")), "s"), (count(col("v")), "c")))
+
+    # columns sorted by name: (c, k1, k2, s); doubles compared exactly
+    rows = _assert_same_result(collective_session, q, conf_sandbox)
+    groups = {(a, b) for a, b in zip(k1, k2)}
+    assert {(r[1], r[2]) for r in rows} == groups
+    assert sum(r[0] for r in rows) == 2000
 
 
 def test_spmd_explain_shows_stage_decision(collective_session,
