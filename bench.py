@@ -95,8 +95,8 @@ the codec + OOC machinery under real pressure.
 ROADMAP #3): the collective tier's agg/join/sort phases on the virtual
 N-device CPU mesh — per-phase wall, exchange rounds, partitioned
 program counts, ledger dispatches/device time, per-device wall — plus
-the milestone comparison: single-device vs host-loop vs SPMD
-whole-stage walls, bit-identical canonical digests, and
+the milestone comparison: single-device vs SPMD whole-stage walls,
+bit-identical canonical digests, and
 `speedup_vs_single_device`.  Known-noise XLA:CPU AOT stderr is
 filtered out of the captured `tail`, so MULTICHIP_r*.json carries only
 signal.
@@ -1870,8 +1870,6 @@ def _bench_mesh_serving(n_devices: int, n_sessions: int) -> dict:
     def _conf(transport: str, mesh_serving: bool) -> TpuConf:
         return TpuConf({
             SHUFFLE_TRANSPORT.key: transport,
-            "spark.rapids.tpu.shuffle.collective.spmd.enabled":
-                transport == "collective",
             "spark.rapids.tpu.shuffle.collective.roundRows":
                 max(1024, rows // (n_devices * 4)),
             "spark.rapids.tpu.sql.batchSizeRows":

@@ -16,16 +16,13 @@ most that many rows per shard — so a skewed or large child never forces
 one stop-the-world host gather (the streaming discipline of the
 reference's shuffle writer).
 
-STAGE EXECUTION (docs/spmd.md): with
-spark.rapids.tpu.shuffle.collective.spmd.enabled (the default), a
-whole query stage lowers to O(1) partitioned pjit programs over the
-mesh with NamedSharding end-to-end — rounds are a lax.scan INSIDE the
-compiled program (bucketed by .spmd.bucketRounds), inputs arrive as
-global sharded arrays, and the per-round host syncs
-(concrete_num_rows, shrink) of the legacy host-loop driver are
-deferred to one counts fetch a program boundary.  spmd.enabled=false
-keeps the legacy per-round host loop (one dispatch + 2n syncs per
-round) — the digest-comparison baseline for the SPMD path."""
+STAGE EXECUTION (docs/spmd.md): a whole query stage lowers to O(1)
+partitioned pjit programs over the mesh with NamedSharding end-to-end
+— rounds are a lax.scan INSIDE the compiled program (bucketed by
+.spmd.bucketRounds), inputs arrive as global sharded arrays, and the
+host syncs are one counts fetch a program boundary.  Each exec has ONE
+driver (`_materialize`), and every program it dispatches compiles
+through parallel/spmd.py's stage builders, i.e. through cached_jit."""
 
 from __future__ import annotations
 
@@ -55,20 +52,6 @@ COLLECTIVE_ROUND_ROWS = register(
     "(the batch-at-a-time discipline of the reference's shuffle "
     "writer, GpuShuffleExchangeExec.scala:167-270).")
 
-SPMD_STAGE = register(
-    "spark.rapids.tpu.shuffle.collective.spmd.enabled", True,
-    "Lower each collective query stage (exchange + its fused "
-    "agg/join/sort work) to O(1) partitioned pjit programs over the "
-    "active mesh with NamedSharding end-to-end: exchange rounds run "
-    "as a lax.scan INSIDE the compiled program, inputs arrive as "
-    "global sharded arrays, and per-round host syncs are deferred to "
-    "one stage-exit counts fetch (docs/spmd.md).  Off: the legacy "
-    "host-loop driver — one program dispatch plus per-shard "
-    "concrete_num_rows/shrink syncs per round — kept as the "
-    "bit-identical digest baseline.  The planner reads this at plan "
-    "time (collective.stage_config), so the stage shape is part of "
-    "the plan, not a collect-time surprise.")
-
 SPMD_BUCKET_ROUNDS = register(
     "spark.rapids.tpu.shuffle.collective.spmd.bucketRounds", 8,
     "Maximum exchange rounds folded into ONE partitioned stage "
@@ -79,26 +62,20 @@ SPMD_BUCKET_ROUNDS = register(
     "shard; round counts inside a bucket pad to a power of two so the "
     "scan length — part of the compiled program's key — takes a "
     "handful of values instead of one executable per data-dependent "
-    "round count (docs/spmd.md).",
+    "round count (docs/spmd.md).  The planner reads this at plan "
+    "time (collective.stage_bucket_rounds), so the stage shape is "
+    "part of the plan, not a collect-time surprise.",
     check=lambda v: v >= 1)
 
 
-def stage_config(conf=None) -> tuple[bool, int]:
-    """(spmd_enabled, bucket_rounds) — THE planner seam deciding how
-    collective stage boundaries compile.  Read at plan time and pinned
-    into the exec (and therefore into explain()/the event log's plan
-    report), so a conf flip after planning cannot silently change an
+def stage_bucket_rounds(conf=None) -> int:
+    """spmd.bucketRounds — THE planner seam deciding how collective
+    stage boundaries compile.  Read at plan time and pinned into the
+    exec (and therefore into explain()/the event log's plan report),
+    so a conf flip after planning cannot silently change an
     already-planned stage's execution shape."""
     conf = conf or get_conf()
-    return bool(conf.get(SPMD_STAGE)), int(conf.get(SPMD_BUCKET_ROUNDS))
-
-
-def _unify_shards(shards: list[ColumnarBatch]) -> list[ColumnarBatch]:
-    """Pad shard batches to one capacity/width profile for stacking
-    (shared with the SPMD global-array assembly in parallel/spmd.py)."""
-    from spark_rapids_tpu.parallel.spmd import unify_batches
-
-    return unify_batches(shards)
+    return int(conf.get(SPMD_BUCKET_ROUNDS))
 
 
 def _fold_groups(groups: list[list[ColumnarBatch]],
@@ -125,19 +102,16 @@ class _CollectiveBase(TpuExec):
 
     mesh = None  # set by subclass __init__
 
-    def _init_stage(self, spmd: Optional[bool],
-                    bucket_rounds: Optional[int]) -> None:
+    def _init_stage(self, bucket_rounds: Optional[int]) -> None:
         """Pin the stage execution shape at construction (= plan)
-        time; the planner passes stage_config() through so the
+        time; the planner passes stage_bucket_rounds() through so the
         decision is part of the plan."""
-        conf_spmd, conf_bucket = stage_config()
-        self.spmd_stage = conf_spmd if spmd is None else bool(spmd)
-        self.bucket_rounds = max(1, conf_bucket if bucket_rounds is None
-                                 else int(bucket_rounds))
+        self.bucket_rounds = max(
+            1, stage_bucket_rounds() if bucket_rounds is None
+            else int(bucket_rounds))
 
     def _stage_desc(self) -> str:
-        return (f"stage=spmd(bucket={self.bucket_rounds})"
-                if self.spmd_stage else "stage=host-loop")
+        return f"stage=spmd(bucket={self.bucket_rounds})"
 
     @property
     def num_partitions(self) -> int:
@@ -171,23 +145,6 @@ class _CollectiveBase(TpuExec):
             if "collectiveRounds" in self.metrics:
                 self.metrics["collectiveRounds"].add(1)
             yield _fold_groups(per_shard, child.schema)
-
-    def _exchange_rounds(self, child: TpuExec, step, *extras,
-                         out_schema: Optional[T.Schema] = None
-                         ) -> list[ColumnarBatch]:
-        """Stream the child through `step` round by round, parking each
-        round's per-shard outputs shrunk on device; returns one folded
-        batch per shard.  `out_schema` is the STEP's output schema
-        (defaults to the child's — right for pure routing steps)."""
-        from spark_rapids_tpu.parallel.exchange import unstack_batch
-
-        n = self.num_partitions
-        parts: list[list[ColumnarBatch]] = [[] for _ in range(n)]
-        for shards in self._shard_rounds(child):
-            out = step(self._stack(shards), *extras)
-            for i, b in enumerate(unstack_batch(out)):
-                parts[i].append(self._shrunk(b))
-        return _fold_groups(parts, out_schema or child.schema)
 
     # -- per-partition serving ----------------------------------------- #
 
@@ -226,47 +183,30 @@ class _CollectiveBase(TpuExec):
         for p in range(self.num_partitions):
             yield from self.execute_partition(p)
 
-    def _stack(self, shards: list[ColumnarBatch]):
-        from spark_rapids_tpu.parallel.exchange import stack_batches
-
-        return stack_batches(_unify_shards(shards))
-
-    @staticmethod
-    def _shrunk(batch: ColumnarBatch) -> ColumnarBatch:
-        """Shrink a per-shard program output (capacity n_dest * cap) to
-        its live prefix so parked rounds don't hold inflated buffers."""
-        rows = batch.concrete_num_rows()
-        return batch.shrink_to_capacity(pad_capacity(rows))
-
 
 class TpuCollectiveHashAggregateExec(_CollectiveBase):
     """Grouped aggregation as fused SPMD programs over the active mesh.
 
     Per round: map-side update aggregation, then hash all_to_all on
-    the group keys and reduce-side merge.  The SPMD stage runs the
-    update as its own program, counts the partial rows and runs the
-    exchange + merge program at THEIR capacity (`_materialize_spmd`);
-    the host-loop driver keeps all three fused in one step at the
-    input round's capacity.  Per-shard round results park on device,
-    and a final per-shard local program (merge + finalize, no
-    collectives) folds the rounds — same keys always land on the same
-    shard, so the cross-round merge is local."""
+    the group keys and reduce-side merge.  The stage runs the update
+    as its own program, counts the partial rows and runs the exchange
+    + merge program at THEIR capacity (`_materialize`).  Per-shard
+    round results park on device, and a final per-shard local program
+    (merge + finalize, no collectives) folds the rounds — same keys
+    always land on the same shard, so the cross-round merge is local."""
 
     def __init__(self, groups: Sequence[Expression],
                  aggs: Sequence[NamedAgg], child: TpuExec, mesh,
-                 spmd: Optional[bool] = None,
                  bucket_rounds: Optional[int] = None):
         super().__init__(child)
         self.mesh = mesh
-        self._init_stage(spmd, bucket_rounds)
+        self._init_stage(bucket_rounds)
         # the partial-mode exec carries every traceable phase we fuse
         self._agg = TpuHashAggregateExec(groups, aggs, child,
                                          mode="partial")
         self._schema = T.Schema(
             list(self._agg.partial_schema.fields[: self._agg.n_keys])
             + [na.output_field() for na in self._agg.aggs])
-        self._step = None
-        self._final_step = None
 
     @property
     def schema(self) -> T.Schema:
@@ -301,11 +241,6 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
     # -- driver ----------------------------------------------------------- #
 
     def _materialize(self) -> list[list[ColumnarBatch]]:
-        if self.spmd_stage:
-            return self._materialize_spmd()
-        return self._materialize_host_loop()
-
-    def _materialize_spmd(self) -> list[list[ColumnarBatch]]:
         """The aggregation stage as O(1) partitioned programs.  Per
         round bucket: an update program (map-side partial aggregation,
         rounds folded into a lax.scan, no collective), ONE counts
@@ -382,30 +317,6 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
             out.append([b])
         return out
 
-    def _materialize_host_loop(self) -> list[list[ColumnarBatch]]:
-        from spark_rapids_tpu.parallel.exchange import (
-            make_hash_exchange_step,
-            make_local_step,
-            unstack_batch,
-        )
-
-        if self._step is None:
-            self._step = make_hash_exchange_step(
-                self.mesh, list(range(self._agg.n_keys)),
-                pre=self._pre, post=self._merge)
-            self._final_step = make_local_step(self.mesh,
-                                               self._finalize)
-        with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
-            merged = self._exchange_rounds(
-                self.children[0], self._step,
-                out_schema=self._agg.partial_schema)
-            final = t.observe(self._final_step(self._stack(merged)))
-        out = []
-        for b in unstack_batch(final):
-            self.metrics["collectiveRows"].add(b.concrete_num_rows())
-            out.append([b])
-        return out
-
 
 class TpuCollectiveHashJoinExec(_CollectiveBase):
     """Shuffled equi-join as fused SPMD programs (the collective analog
@@ -420,14 +331,13 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
 
     def __init__(self, left_keys, right_keys, join_type: str,
                  left: TpuExec, right: TpuExec, mesh,
-                 spmd: Optional[bool] = None,
                  bucket_rounds: Optional[int] = None):
         from spark_rapids_tpu.execs.join import _nullable_fields
 
         assert join_type in self.SUPPORTED_TYPES, join_type
         super().__init__(left, right)
         self.mesh = mesh
-        self._init_stage(spmd, bucket_rounds)
+        self._init_stage(bucket_rounds)
         self.left_keys = list(left_keys)
         self.right_keys = list(right_keys)
         self.join_type = join_type
@@ -437,8 +347,6 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
             rf = _nullable_fields(right.schema) \
                 if join_type == "left_outer" else list(right.schema.fields)
             self._schema = T.Schema(list(left.schema.fields) + rf)
-        self._build_step = None
-        self._join_steps: dict[int, object] = {}
 
     @property
     def schema(self) -> T.Schema:
@@ -471,14 +379,6 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
         return partition_ids([k.eval(sctx) for k in self.left_keys],
                              stream.capacity, self.num_partitions)
 
-    def _join_shard(self, stream: ColumnarBatch, build: ColumnarBatch,
-                    out_cap: int):
-        from spark_rapids_tpu.parallel.exchange import route_shard
-
-        routed = route_shard(stream, self._route_stream(stream),
-                             self.num_partitions, DATA_AXIS)
-        return self._join_local(routed, build, out_cap)
-
     def _join_local(self, routed: ColumnarBatch, build: ColumnarBatch,
                     out_cap: int):
         from spark_rapids_tpu.ops.join import (
@@ -507,31 +407,7 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
                             self._schema, stream_first=True)
         return out, total
 
-    def _join_step(self, out_cap: int):
-        from spark_rapids_tpu.parallel.exchange import make_join_step
-
-        step = self._join_steps.get(out_cap)
-        if step is None:
-            step = self._join_steps[out_cap] = make_join_step(
-                self.mesh,
-                lambda s, b: self._join_shard(s, b, out_cap))
-        return step
-
     # -- driver ------------------------------------------------------------ #
-
-    def _collect_build(self) -> ColumnarBatch:
-        """Exchange the build side by right-key hash, in rounds;
-        returns the stacked per-shard build batch."""
-        from spark_rapids_tpu.parallel.exchange import make_route_step
-
-        if self._build_step is None:
-            self._build_step = make_route_step(
-                self.mesh, lambda b: self._route_build(b))
-        merged = self._exchange_rounds(self.children[1],
-                                       self._build_step)
-        for b in merged:
-            self.metrics["buildRows"].add(b.concrete_num_rows())
-        return self._stack(merged)
 
     def _join_key(self) -> tuple:
         from spark_rapids_tpu.execs.jit_cache import exprs_key
@@ -540,11 +416,6 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
                 exprs_key(self.right_keys), repr(self._schema))
 
     def _materialize(self) -> list[list[ColumnarBatch]]:
-        if self.spmd_stage:
-            return self._materialize_spmd()
-        return self._materialize_host_loop()
-
-    def _materialize_spmd(self) -> list[list[ColumnarBatch]]:
         """The join stage as O(1) partitioned programs per side: the
         build side runs one exchange-scan program (route by right-key
         hash, all rounds in one lax.scan) + mid-stage shrink + one
@@ -652,65 +523,29 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
                      for _ in range(n)]])
         return chunks
 
-    def _materialize_host_loop(self) -> list[list[ColumnarBatch]]:
-        from spark_rapids_tpu.parallel import spmd as S
-        from spark_rapids_tpu.parallel.exchange import unstack_batch
-
-        chunks: list[list[ColumnarBatch]] = [
-            [] for _ in range(self.num_partitions)]
-        with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
-            build_stacked = self._collect_build()
-            build_rows = int(S.fetch(build_stacked.num_rows).max())
-            for shards in self._shard_rounds(self.children[0]):
-                n = self.num_partitions
-                cap_round = max(s.capacity for s in shards)
-                stacked = self._stack(shards)
-                # initial output guess: a shard can receive up to the
-                # whole round (n * cap_round); matches usually stay
-                # near stream row counts
-                cap_guess = 64 if self.join_type in (
-                    "left_semi", "left_anti") else pad_capacity(
-                        max(cap_round * n, build_rows, 64))
-                while True:
-                    step = self._join_step(cap_guess)
-                    out, totals = step(stacked, build_stacked)
-                    if self.join_type in ("left_semi", "left_anti"):
-                        break
-                    worst = int(S.fetch(totals).max())
-                    if worst <= cap_guess:
-                        break
-                    # JoinGatherer-style re-bucket: recompile at the
-                    # capacity the data actually needs
-                    cap_guess = pad_capacity(worst)
-                out = t.observe(out)
-                for i, b in enumerate(unstack_batch(out)):
-                    if b.concrete_num_rows():
-                        chunks[i].append(self._shrunk(b))
-        return chunks
-
 
 class TpuCollectiveSortExec(_CollectiveBase):
     """Distributed ORDER BY as fused SPMD programs (the collective
     analog of range-exchange + per-partition sort; ref:
     GpuRangePartitioner sketch/determineBounds + GpuSortExec).
 
-    Pass 1 streams the child into parked device rounds while sampling
-    sort keys; bounds come from the pooled sample; pass 2 routes every
-    round through a range-bisect all_to_all (bounds ride as a
-    REPLICATED program argument, so one compiled program serves every
-    bounds value); each shard then sorts locally — shard index order
-    IS the total order."""
+    The route program samples sort keys over every parked round, pools
+    the samples with an all_gather, derives range bounds in-program and
+    routes every round through a range-bisect all_to_all; each shard
+    then sorts locally — shard index order IS the total order.  (Under
+    mesh serving a long input takes two bucketed passes instead, the
+    bounds riding as a REPLICATED program argument so one compiled
+    program serves every bounds value: `_spmd_sort_bucketed`.)"""
 
     SAMPLE_PER_SHARD = 256
 
     def __init__(self, keys, child: TpuExec, mesh,
-                 spmd: Optional[bool] = None,
                  bucket_rounds: Optional[int] = None):
         super().__init__(child)
         from spark_rapids_tpu.ops.partition import RangePartitioning
 
         self.mesh = mesh
-        self._init_stage(spmd, bucket_rounds)
+        self._init_stage(bucket_rounds)
         self.keys = list(keys)
         n = int(mesh.shape[DATA_AXIS])
         self._part = RangePartitioning(self.keys, n).bind(child.schema)
@@ -730,16 +565,6 @@ class TpuCollectiveSortExec(_CollectiveBase):
     def additional_metrics(self):
         return [("collectiveRounds", "MODERATE")]
 
-    @staticmethod
-    def _sample_k(rows: int) -> int:
-        """Per-batch sample count ~ proportional to rows (one per 64,
-        power-of-two bucketed for compile-cache stability, capped) —
-        equal per-batch counts would let a 10-row tail batch weigh as
-        much as a million-row one when choosing bounds (the weighting
-        concern behind GpuRangePartitioner's size-scaled sketch)."""
-        k = max(16, min(256, rows // 64))
-        return 1 << (k - 1).bit_length()
-
     def _sort_key(self) -> tuple:
         from spark_rapids_tpu.execs.jit_cache import exprs_key
 
@@ -748,11 +573,6 @@ class TpuCollectiveSortExec(_CollectiveBase):
                       for k in self._part.keys))
 
     def _materialize(self) -> list[list[ColumnarBatch]]:
-        if self.spmd_stage:
-            return self._materialize_spmd()
-        return self._materialize_host_loop()
-
-    def _materialize_spmd(self) -> list[list[ColumnarBatch]]:
         """The distributed ORDER BY as TWO partitioned programs: the
         route program (in-program sampling at host-chosen fractional
         positions — no per-batch row-count sync — all_gather-pooled
@@ -760,9 +580,8 @@ class TpuCollectiveSortExec(_CollectiveBase):
         scanned rounds axis), ONE mid-stage counts fetch + shrink,
         then the tail program sorting each shard at tight capacity —
         shard index order IS the total order.  The sort stage ignores
-        bucketRounds: bounds must see every round's sample, and the
-        host-loop path also parked all rounds before routing, so the
-        resident footprint is unchanged."""
+        bucketRounds: bounds must see every round's sample, so every
+        round is resident while the route program runs."""
         from spark_rapids_tpu.ops.sort import sort_permutation
         from spark_rapids_tpu.parallel import spmd as S
 
@@ -871,77 +690,3 @@ class TpuCollectiveSortExec(_CollectiveBase):
                                  donate=True)
         return t.observe(tail(xs2))
 
-    def _materialize_host_loop(self) -> list[list[ColumnarBatch]]:
-        import numpy as np
-
-        from spark_rapids_tpu.execs.jit_cache import cached_jit, exprs_key
-        from spark_rapids_tpu.ops.range_partition import choose_bounds
-        from spark_rapids_tpu.parallel.exchange import (
-            make_local_step,
-            make_route_step,
-            unstack_batch,
-        )
-
-        part = self._part
-        n = self.num_partitions
-        pkey = (exprs_key([k.expr for k in part.keys]),
-                tuple((k.descending, k.nulls_last) for k in part.keys))
-        rng = np.random.default_rng(0x52414E47)
-
-        # pass 1: park rounds + sample keys per shard (sample size
-        # scales with batch rows — see _sample_k)
-        rounds: list[list[ColumnarBatch]] = []
-        samples: list[ColumnarBatch] = []
-        with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
-            for shards in self._shard_rounds(self.children[0]):
-                rounds.append(shards)
-                for s in shards:
-                    rows = s.concrete_num_rows()
-                    if not rows:
-                        continue
-                    n_sample = self._sample_k(rows)
-                    jit_sample = cached_jit(
-                        ("csortsample", pkey, s.capacity, n_sample,
-                         repr(s.schema)),
-                        op=self.name,
-                        make_fn=lambda: lambda b, p: part.key_batch(
-                            b).gather(p, p.shape[0]))
-                    pos = jnp.asarray(
-                        rng.integers(0, rows, n_sample).astype(np.int32))
-                    samples.append(jit_sample(s, pos))
-            if not samples:
-                return [[ColumnarBatch.empty(self.schema)]
-                        for _ in range(n)]
-            n_live = sum(s.num_rows for s in samples)
-            jit_bounds = cached_jit(
-                ("csortbounds", pkey, n_live, n,
-                 tuple(s.capacity for s in samples)),
-                op=self.name,
-                make_fn=lambda: lambda ss: choose_bounds(
-                    concat_batches(ss), part.key_orders(), n, n_live))
-            bounds = jit_bounds(samples)
-
-            # pass 2: range-routed all_to_all per round, then local sort
-            route = make_route_step(
-                self.mesh,
-                lambda b, bd: part.partition_ids_with_bounds(b, bd),
-                n_extra=1)
-            parts: list[list[ColumnarBatch]] = [[] for _ in range(n)]
-            for shards in rounds:
-                out = route(self._stack(shards), bounds)
-                for i, b in enumerate(unstack_batch(out)):
-                    parts[i].append(self._shrunk(b))
-            merged = _fold_groups(parts, self.schema)
-
-            def local_sort_fn(b: ColumnarBatch) -> ColumnarBatch:
-                # sort by the evaluated key batch (works for arbitrary
-                # key expressions, not just column refs)
-                from spark_rapids_tpu.ops.sort import sort_permutation
-
-                perm = sort_permutation(part.key_batch(b),
-                                        part.key_orders())
-                return b.gather(perm, b.num_rows)
-
-            local_sort = make_local_step(self.mesh, local_sort_fn)
-            final = t.observe(local_sort(self._stack(merged)))
-            return [[b] for b in unstack_batch(final)]

@@ -212,11 +212,13 @@ class _CompileLatch:
 #: Pod-scale serving's concurrent sessions are exactly this shape
 #: (docs/pod_serving.md).  Holding the lock across the (async) call
 #: makes every device see the same program order — sufficient, IF
-#: every multi-device launch goes through the gate: the eager side
-#: doors (a sharded array's `__getitem__`, an eager `jnp.max` on a
-#: sharded leaf) are closed in exchange.take_piece and the stage-exit
-#: device_get fetches.  Single-threaded/mesh-off callers never
-#: contend, and program-to-program pipelining is untouched.
+#: every multi-device launch goes through the gate: cached_jit is the
+#: only place one is compiled (parallel/spmd.py's stage builders all
+#: end in it), and the eager side doors (a sharded array's
+#: `__getitem__`, an eager `jnp.max` on a sharded leaf) are closed in
+#: exchange.take_piece and the stage-exit device_get fetches.
+#: Single-threaded/mesh-off callers never contend, and
+#: program-to-program pipelining is untouched.
 _SHARDED_DISPATCH_LOCK = threading.RLock()
 
 
@@ -246,15 +248,6 @@ class _SerializedDispatch:
     def __call__(self, *args, **kwargs):
         with _SHARDED_DISPATCH_LOCK:
             return self._fn(*args, **kwargs)
-
-
-def serialize_sharded(fn: Callable) -> Callable:
-    """Route a multi-device program compiled OUTSIDE cached_jit (the
-    shard_map step builders in parallel/exchange.py) through the same
-    process-wide collective dispatch gate — every rendezvous-bearing
-    program in the process must share ONE gate or the pool-starvation
-    deadlock above comes back through the unguarded door."""
-    return _SerializedDispatch(fn)
 
 
 _NOT_IN_A_NAME = re.compile(r"[^A-Za-z0-9_]")

@@ -765,8 +765,8 @@ def _is_wire_module(path: str) -> bool:
 _STEP_SYNC_ATTRS = {"concrete_num_rows", "block_until_ready", "item",
                     "tolist"}
 #: builder-function name prefixes whose NESTED defs are traced step
-#: bodies (make_hash_exchange_step's shard_fn, make_agg_stage's
-#: shard_fn/body, ...)
+#: bodies (make_exchange_scan_stage's shard_fn/step, make_stage_tail's
+#: shard_fn, ...)
 _STEP_BUILDER_PREFIXES = ("make_", "spmd_")
 
 
@@ -791,8 +791,9 @@ class _CollectiveStepSyncChecker(ast.NodeVisitor):
     - any function passed by name to ``shard_map``/``_shard_map``;
     - in execs/collective.py: methods handed to a builder as a bound
       reference or called from a lambda passed to a builder
-      (``make_route_step(mesh, lambda b: self._route_build(b))``
-      makes ``_route_build`` a traced body).
+      (``make_join_scan_stage(mesh, key, lambda s, b:
+      self._join_local(s, b, cap), n)`` makes ``_join_local`` a traced
+      body).
 
     The host DRIVER code in the same modules (round staging,
     stage-exit counts fetches) legitimately syncs and is out of

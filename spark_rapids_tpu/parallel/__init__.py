@@ -11,11 +11,6 @@ from spark_rapids_tpu.parallel.mesh import (  # noqa: F401
     make_mesh,
     mesh_key,
 )
-from spark_rapids_tpu.parallel.exchange import (  # noqa: F401
-    make_hash_exchange_step,
-    stack_batches,
-    unstack_batch,
-)
 from spark_rapids_tpu.parallel.pipeline import (  # noqa: F401
     device_read,
     device_read_int,

@@ -1,17 +1,18 @@
 """SPMD whole-stage execution: one pjit program per query stage.
 
-The collective tier's original driver (execs/collective.py) ran a HOST
-LOOP per exchange round: stack per-shard batches on the default device,
-dispatch one shard_map step, unstack, host-sync every shard's row count,
-shrink, fold.  Per round that is one program dispatch plus 2n host
-round-trips — the dispatch-soup anti-pattern the DeviceLedger exists to
-expose, and the opposite of how pjit/GSPMD programs are meant to run
-(SNIPPETS [1][2]: partitioned compilation with `PartitionSpec` +
-donation; [3]: mesh/`NamedSharding` helpers).
+Driving an exchange from the host round by round (stack per-shard
+batches on the default device, dispatch one shard_map step, unstack,
+host-sync every shard's row count, shrink, fold) costs one program
+dispatch plus 2n host round-trips a round — the dispatch-soup
+anti-pattern the DeviceLedger exists to expose, and the opposite of how
+pjit/GSPMD programs are meant to run (SNIPPETS [1][2]: partitioned
+compilation with `PartitionSpec` + donation; [3]: mesh/`NamedSharding`
+helpers).
 
-This module is the replacement: a query stage (exchange + its fused
-agg/join/sort work) lowers to a SINGLE partitioned XLA program over the
-active mesh with `NamedSharding` end-to-end —
+Here a query stage (exchange + its fused agg/join/sort work) lowers to
+O(1) partitioned XLA programs over the active mesh with
+`NamedSharding` end-to-end, and these builders are the only place the
+collective tier (execs/collective.py) compiles a program —
 
 - inputs arrive as GLOBAL sharded arrays: per-shard round batches are
   assembled with `jax.make_array_from_single_device_arrays` under
@@ -83,7 +84,7 @@ def stage_sharding(mesh) -> NamedSharding:
 
 
 # ------------------------------------------------------------------ #
-# Capacity unification (shared with the host-loop fallback path)
+# Capacity unification
 # ------------------------------------------------------------------ #
 
 
@@ -242,9 +243,9 @@ def sample_fracs(mesh, n_rounds: int, k: int,
 
 def stage_counts(batch: ColumnarBatch) -> np.ndarray:
     """THE stage-exit sync: fetch the output row-count array (shape
-    (n,) or (R, n)) in one device_get.  Everything the host loop used
-    to learn per round (`concrete_num_rows` per shard, shrink sizes)
-    comes out of this single fetch."""
+    (n,) or (R, n)) in one device_get.  Everything the host needs per
+    round (live rows per shard, shrink sizes) comes out of this single
+    fetch."""
     return np.asarray(jax.device_get(batch.num_rows))
 
 
@@ -565,9 +566,9 @@ def make_bounds_route_stage(mesh, key: tuple, part, n_rounds: int,
                             donate: bool = False):
     """Pass 2 of the bucketed distributed ORDER BY: scan one bucket's
     rounds through the range-routed all_to_all, with the bounds batch
-    riding as a REPLICATED program argument (the make_route_step
-    idiom) — one compiled program serves every bounds value, so the
-    bucket count never mints executables."""
+    riding as a REPLICATED program argument — one compiled program
+    serves every bounds value, so the bucket count never mints
+    executables."""
     n = int(mesh.shape[DATA_AXIS])
     axis = DATA_AXIS
 
@@ -606,9 +607,8 @@ def make_sort_route_stage(mesh, key: tuple, part, n_rounds: int,
     Emits the round-stacked routed rounds; after the mid-stage shrink
     the tail program (`make_stage_tail` with the local sort) sorts
     each shard at tight capacity — shard index order IS the total
-    order.  The host-loop path needed a per-batch `concrete_num_rows`
-    sync just to SIZE its samples; here the row counts never leave
-    the device."""
+    order.  Samples are sized in-program, so no row count leaves the
+    device to size them."""
     from spark_rapids_tpu.ops.range_partition import (
         choose_bounds_dynamic,
     )

@@ -936,7 +936,7 @@ def _plan_join(p: L.Join, kids: list[TpuExec]) -> TpuExec:
     if key_dtypes_match and p.condition is None:
         from spark_rapids_tpu.execs.collective import (
             TpuCollectiveHashJoinExec,
-            stage_config,
+            stage_bucket_rounds,
         )
         from spark_rapids_tpu.shuffle.transport import get_transport
 
@@ -945,12 +945,12 @@ def _plan_join(p: L.Join, kids: list[TpuExec]) -> TpuExec:
                 and jt in TpuCollectiveHashJoinExec.SUPPORTED_TYPES
                 and transport.supports_schema(kids[0].schema)
                 and transport.supports_schema(kids[1].schema)):
-            # stage boundary decided HERE at plan time: SPMD
-            # whole-stage vs legacy host-loop, pinned into the exec
-            spmd, bucket = stage_config(conf)
+            # stage boundary decided HERE at plan time and pinned
+            # into the exec
             return TpuCollectiveHashJoinExec(
                 p.left_keys, p.right_keys, jt, kids[0], kids[1],
-                transport.mesh, spmd=spmd, bucket_rounds=bucket)
+                transport.mesh,
+                bucket_rounds=stage_bucket_rounds(conf))
     if key_dtypes_match and (kids[0].num_partitions > 1
                              or kids[1].num_partitions > 1):
         # EnsureRequirements: a child already hash-partitioned on these
@@ -1043,14 +1043,13 @@ def _plan_sort(p: L.Sort, child_exec: TpuExec) -> TpuExec:
             and transport.supports_schema(child_exec.schema):
         from spark_rapids_tpu.execs.collective import (
             TpuCollectiveSortExec,
-            stage_config,
+            stage_bucket_rounds,
         )
 
         # stage boundary decided at plan time (docs/spmd.md)
-        spmd, bucket = stage_config(conf)
-        return TpuCollectiveSortExec(p.keys, child_exec,
-                                     transport.mesh, spmd=spmd,
-                                     bucket_rounds=bucket)
+        return TpuCollectiveSortExec(
+            p.keys, child_exec, transport.mesh,
+            bucket_rounds=stage_bucket_rounds(conf))
     if child_exec.num_partitions > 1 and conf.get(RANGE_SORT):
         n = conf.get(SHUFFLE_PARTITIONS)
         ex = TpuShuffleExchangeExec(
@@ -1113,14 +1112,13 @@ def _plan_aggregate(p: L.Aggregate, child_exec: TpuExec) -> TpuExec:
                 and transport.supports_schema(child_exec.schema):
             from spark_rapids_tpu.execs.collective import (
                 TpuCollectiveHashAggregateExec,
-                stage_config,
+                stage_bucket_rounds,
             )
 
             # stage boundary decided at plan time (docs/spmd.md)
-            spmd, bucket = stage_config()
             return TpuCollectiveHashAggregateExec(
                 p.groups, p.aggs, child_exec, transport.mesh,
-                spmd=spmd, bucket_rounds=bucket)
+                bucket_rounds=stage_bucket_rounds())
     if child_exec.num_partitions <= 1:
         return TpuHashAggregateExec(p.groups, p.aggs, child_exec)
     partial = TpuHashAggregateExec(p.groups, p.aggs, child_exec,

@@ -1369,7 +1369,6 @@ def run_mesh_serving_smoke() -> dict:
         over = dict(base)
         over.update({
             SHUFFLE_TRANSPORT.key: "collective",
-            "spark.rapids.tpu.shuffle.collective.spmd.enabled": True,
             "spark.rapids.tpu.shuffle.collective.roundRows": 512,
             "spark.rapids.tpu.sql.batchSizeRows": 512,
             "spark.rapids.tpu.serving.mesh.enabled": True,

@@ -2,9 +2,10 @@
 
 BENCH_r06 ran q3 at 0.117x vs CPU where the round before it ran
 0.248x (neither record names its device).  The layers that
-landed between the rounds (fusion + buffer donation in PR11, SPMD
-stage execution in PR14) each ship a kill switch, so the regression is
-bisectable by CONF, not by checkout: every arm below re-runs the exact
+landed between the rounds (fusion + buffer donation in PR11) each
+ship a kill switch, so the regression is bisectable by CONF, not by
+checkout (the SPMD stage execution of PR14 had one too, until PR 30
+deleted the path it fell back to): every arm below re-runs the exact
 bench.py q3 shape (same fixture generator, same timed-iteration
 protocol, wire compression + device ledger + event log on, matching
 the committed rounds) in a FRESH subprocess (no shared jit cache —
@@ -36,7 +37,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 
 _DONATE = "spark.rapids.tpu.sql.fusion.donation.enabled"
 _FUSION = "spark.rapids.tpu.sql.fusion.enabled"
-_SPMD = "spark.rapids.tpu.shuffle.collective.spmd.enabled"
 _SPEC = "spark.rapids.tpu.sql.speculation.enabled"
 _RF = "spark.rapids.tpu.sql.runtimeFilter.enabled"
 _COALESCE = "spark.rapids.tpu.sql.coalesce.enabled"
@@ -48,7 +48,6 @@ ARMS = [
     ("no_donation", {_DONATE: False}),
     ("no_fusion", {_DONATE: True, _FUSION: False}),
     ("no_fusion_no_donation", {_DONATE: False, _FUSION: False}),
-    ("no_spmd", {_DONATE: True, _SPMD: False}),
     ("no_speculation", {_DONATE: True, _SPEC: False}),
     ("no_runtime_filter", {_DONATE: True, _RF: False}),
     ("r07_coalesce", {_DONATE: True, _COALESCE: True}),

@@ -8,14 +8,27 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.exprs.hashing import partition_ids
 from spark_rapids_tpu.ops.groupby import AggSpec, groupby_aggregate
-from spark_rapids_tpu.parallel import (
-    make_hash_exchange_step,
-    make_mesh,
-    stack_batches,
-    unstack_batch,
-)
+from spark_rapids_tpu.parallel import make_mesh
+from spark_rapids_tpu.parallel import spmd as S
+from spark_rapids_tpu.parallel.exchange import exchange_shard
+from spark_rapids_tpu.parallel.mesh import DATA_AXIS
 
 N_DEV = 8
+
+
+def exchange_one_round(mesh, shards, tag, pre=None, post=None):
+    """`exchange_shard` on key 0 (with `pre`/`post` fused in the body)
+    as a one-round exchange stage program; the shards' outputs."""
+
+    def body(b):
+        b = pre(b) if pre else b
+        b = exchange_shard(b, [0], N_DEV, DATA_AXIS)
+        return post(b) if post else b
+
+    step = S.make_exchange_scan_stage(mesh, ("test_exchange", tag),
+                                      body, 1)
+    out = step(S.shard_stack_rounds([shards], mesh))
+    return S.shrink_rounds(out)[0]
 
 
 def make_shards(schema, n_rows_per_shard, seed=0):
@@ -34,9 +47,7 @@ def test_exchange_routes_rows_to_hash_owner():
     mesh = make_mesh(N_DEV)
     schema = T.Schema([T.Field("k", T.LONG), T.Field("v", T.LONG)])
     shards = make_shards(schema, 20)
-    step = make_hash_exchange_step(mesh, key_ordinals=[0])
-    out = step(stack_batches(shards))
-    outs = unstack_batch(out)
+    outs = exchange_one_round(mesh, shards, "route")
 
     # every input row lands on exactly the device that owns its hash bucket
     all_in = []
@@ -71,8 +82,7 @@ def test_exchange_with_fused_partial_and_merge_agg():
     def post(b):
         return groupby_aggregate(b, [0], [AggSpec("sum", 1)], partial_schema)
 
-    step = make_hash_exchange_step(mesh, key_ordinals=[0], pre=pre, post=post)
-    outs = unstack_batch(step(stack_batches(shards)))
+    outs = exchange_one_round(mesh, shards, "fused", pre=pre, post=post)
 
     got = {}
     for o in outs:

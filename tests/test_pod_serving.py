@@ -47,7 +47,6 @@ from spark_rapids_tpu.session import TpuSession, col, count_star, sum_
 from spark_rapids_tpu.shuffle.transport import SHUFFLE_TRANSPORT
 
 MESH_ENABLED = "spark.rapids.tpu.serving.mesh.enabled"
-SPMD_ENABLED = "spark.rapids.tpu.shuffle.collective.spmd.enabled"
 ROUND_ROWS = "spark.rapids.tpu.shuffle.collective.roundRows"
 
 
@@ -105,7 +104,6 @@ def _mesh_conf(rows: int, mesh_serving: bool = True) -> TpuConf:
     over = dict(get_conf()._values)
     over.update({
         SHUFFLE_TRANSPORT.key: "collective",
-        SPMD_ENABLED: True,
         ROUND_ROWS: max(256, rows // 8),
         "spark.rapids.tpu.sql.batchSizeRows": max(256, rows // 8),
         "spark.rapids.tpu.sql.autoBroadcastJoinThresholdBytes": -1,

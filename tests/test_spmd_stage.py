@@ -2,9 +2,13 @@
 stage lowers to O(1) partitioned pjit programs over the 8-virtual-device
 mesh — global sharded inputs (NamedSharding end-to-end), exchange rounds
 as an in-program lax.scan, host syncs deferred to stage exit — with
-results bit-identical to the legacy host-loop driver, plus the
-`_CollectiveBase._shard_rounds` round-staging contracts the stage input
-rides on."""
+results identical to the one-device answer of the same session
+(`shuffle.transport = local`), plus the `_CollectiveBase._shard_rounds`
+round-staging contracts the stage input rides on."""
+
+import ast
+import inspect
+import pathlib
 
 import numpy as np
 import pyarrow as pa
@@ -20,7 +24,7 @@ from spark_rapids_tpu.session import TpuSession, col, count, sum_
 N_DEV = 8
 
 ROUND_KEY = "spark.rapids.tpu.shuffle.collective.roundRows"
-SPMD_KEY = "spark.rapids.tpu.shuffle.collective.spmd.enabled"
+TRANSPORT_KEY = "spark.rapids.tpu.shuffle.transport"
 BUCKET_KEY = "spark.rapids.tpu.shuffle.collective.spmd.bucketRounds"
 BATCH_KEY = "spark.rapids.tpu.sql.batchSizeRows"
 
@@ -37,7 +41,7 @@ def collective_session():
 def conf_sandbox():
     """Snapshot/restore the confs these tests tweak."""
     conf = get_conf()
-    keys = (ROUND_KEY, SPMD_KEY, BUCKET_KEY, BATCH_KEY,
+    keys = (ROUND_KEY, BUCKET_KEY, BATCH_KEY,
             "spark.rapids.tpu.sql.autoBroadcastJoinThresholdBytes")
     old = {k: conf.get(k) for k in keys}
     yield conf
@@ -82,7 +86,7 @@ def _collective_base(mesh):
     child = _FakeChild(schema, [])
     exec_ = _CollectiveBase(child)
     exec_.mesh = mesh
-    exec_._init_stage(None, None)
+    exec_._init_stage(None)
     return exec_
 
 
@@ -242,7 +246,7 @@ def test_choose_bounds_dynamic_matches_static():
 
 
 # ------------------------------------------------------------------ #
-# Whole-stage digest identity: SPMD on vs host loop off
+# Whole-stage answers: the collective stage vs one device
 # ------------------------------------------------------------------ #
 
 
@@ -255,18 +259,35 @@ def _canon(table: pa.Table) -> list:
                   ) if cols else []
 
 
+def _collect_both(session, make_df, conf):
+    """(the collective answer, the one-device answer) of one query in
+    one session: the second collect plans under `shuffle.transport =
+    local`, so no collective exec and no mesh program is in it."""
+    from spark_rapids_tpu.plan.planner import plan_query
+
+    def run(collective: bool) -> pa.Table:
+        df = make_df(session)
+        plan = plan_query(df._plan, session.conf)[0].tree_string()
+        assert ("TpuCollective" in plan) == collective, plan
+        return df.collect(engine="tpu")
+
+    mesh_answer = run(True)
+    conf.set(TRANSPORT_KEY, "local")
+    try:
+        return mesh_answer, run(False)
+    finally:
+        conf.set(TRANSPORT_KEY, "collective")
+
+
 def _assert_same_result(session, make_df, conf):
-    conf.set(SPMD_KEY, True)
-    on = _canon(make_df(session).collect(engine="tpu"))
-    conf.set(SPMD_KEY, False)
-    off = _canon(make_df(session).collect(engine="tpu"))
-    conf.set(SPMD_KEY, True)
-    assert on == off
-    return on
+    mesh_answer, single = map(_canon, _collect_both(session, make_df,
+                                                    conf))
+    assert mesh_answer == single
+    return mesh_answer
 
 
-def test_spmd_agg_digest_identical_to_host_loop(collective_session,
-                                                conf_sandbox):
+def test_spmd_agg_identical_to_one_device(collective_session,
+                                          conf_sandbox):
     rng = np.random.default_rng(11)
     t = pa.table({"k": rng.integers(0, 40, 3000).astype(np.int64),
                   "v": rng.integers(0, 100, 3000).astype(np.int64)})
@@ -287,14 +308,15 @@ def test_spmd_agg_digest_identical_to_host_loop(collective_session,
 
 @pytest.mark.parametrize("how", [
     "inner",
-    # the other types compile their own program pairs on BOTH paths —
-    # covered, but in the slow tier to keep tier-1's wall bounded
+    # the other types compile their own programs on the mesh and on
+    # one device — covered, but in the slow tier to keep tier-1's
+    # wall bounded
     pytest.param("left_anti", marks=pytest.mark.slow),
     pytest.param("left_outer", marks=pytest.mark.slow),
     pytest.param("left_semi", marks=pytest.mark.slow),
 ])
-def test_spmd_join_digest_identical_to_host_loop(collective_session,
-                                                 conf_sandbox, how):
+def test_spmd_join_identical_to_one_device(collective_session,
+                                           conf_sandbox, how):
     rng = np.random.default_rng(13)
     lt = pa.table({"k": rng.integers(0, 30, 1200).astype(np.int64),
                    "lv": rng.integers(0, 9, 1200).astype(np.int64)})
@@ -312,23 +334,28 @@ def test_spmd_join_digest_identical_to_host_loop(collective_session,
     _assert_same_result(collective_session, q, conf_sandbox)
 
 
-def test_spmd_sort_digest_identical_to_host_loop(collective_session,
-                                                 conf_sandbox):
+def test_spmd_sort_identical_to_one_device(collective_session,
+                                           conf_sandbox):
     rng = np.random.default_rng(17)
     t = pa.table({"k": rng.integers(0, 10_000, 2500).astype(np.int64),
                   "v": np.arange(2500, dtype=np.int64)})
     conf_sandbox.set(ROUND_KEY, 300)
     conf_sandbox.set(BATCH_KEY, 128)
 
-    def run(spmd):
-        conf_sandbox.set(SPMD_KEY, spmd)
-        df = collective_session.create_dataframe(t).order_by(col("k"))
-        d = df.collect(engine="tpu").to_pydict()
+    # k repeats, so v decides between equal keys: the total order
+    # (k, v) is unique, whichever shard and round a row went through
+    def q(s):
+        return s.create_dataframe(t).order_by(col("k"), col("v"))
+
+    def rows(table):
+        d = table.to_pydict()
         return list(zip(d["k"], d["v"]))
 
-    on, off = run(True), run(False)
-    assert [k for k, _ in on] == sorted(t.column("k").to_pylist())
-    assert on == off  # identical TOTAL order, not just sorted keys
+    mesh_answer, single = map(rows, _collect_both(
+        collective_session, q, conf_sandbox))
+    assert mesh_answer == rows(t.sort_by([("k", "ascending"),
+                                          ("v", "ascending")]))
+    assert mesh_answer == single
 
 
 def test_spmd_empty_input_stages(collective_session, conf_sandbox):
@@ -499,8 +526,10 @@ def test_spmd_agg_string_keys_with_nulls_digest(collective_session,
                                                 conf_sandbox):
     """String group keys with NULLs, two rounds a bucket, double sums
     (whose digest moves with the ORDER of a sum's terms): the counted
-    exchange keeps sender order and the merge sorts by key, so SPMD
-    and host-loop answers are identical to the bit."""
+    exchange keeps sender order and the merge sorts by key, so two
+    collective collects are identical to the bit; one device adds the
+    same terms in another order, so its sums agree to 1e-12 relative
+    and its keys and counts exactly."""
     rng = np.random.default_rng(31)
     words = np.array(["", "a", "bb", "Ünï", "delta-long-key", "zz"])
     k1 = [None if x == 6 else str(words[x])
@@ -518,8 +547,13 @@ def test_spmd_agg_string_keys_with_nulls_digest(collective_session,
         return (s.create_dataframe(t).group_by(col("k1"), col("k2"))
                 .agg((sum_(col("v")), "s"), (count(col("v")), "c")))
 
-    # columns sorted by name: (c, k1, k2, s); doubles compared exactly
-    rows = _assert_same_result(collective_session, q, conf_sandbox)
+    # columns sorted by name: (c, k1, k2, s)
+    rows, single = map(_canon, _collect_both(
+        collective_session, q, conf_sandbox))
+    assert rows == _canon(q(collective_session).collect(engine="tpu"))
+    assert [r[:3] for r in rows] == [r[:3] for r in single]
+    np.testing.assert_allclose([r[3] for r in rows],
+                               [r[3] for r in single], rtol=1e-12, atol=0)
     groups = {(a, b) for a, b in zip(k1, k2)}
     assert {(r[1], r[2]) for r in rows} == groups
     assert sum(r[0] for r in rows) == 2000
@@ -535,13 +569,60 @@ def test_spmd_explain_shows_stage_decision(collective_session,
                   "v": pa.array([3, 4], pa.int64())})
     df = (collective_session.create_dataframe(t).group_by(col("k"))
           .agg((sum_(col("v")), "s")))
-    conf_sandbox.set(SPMD_KEY, False)
-    exec_, _ = plan_query(df._plan, collective_session.conf)
-    assert "stage=host-loop" in exec_.tree_string()
-    conf_sandbox.set(SPMD_KEY, True)
     conf_sandbox.set(BUCKET_KEY, 4)
     exec_, _ = plan_query(df._plan, collective_session.conf)
     assert "stage=spmd(bucket=4)" in exec_.tree_string()
     # conf flips AFTER planning do not change the planned stage shape
-    conf_sandbox.set(SPMD_KEY, False)
+    conf_sandbox.set(BUCKET_KEY, 2)
     assert "stage=spmd(bucket=4)" in exec_.tree_string()
+    assert _agg_node(exec_).bucket_rounds == 4
+
+
+# ------------------------------------------------------------------ #
+# One executor, one compile door
+# ------------------------------------------------------------------ #
+
+
+def test_mesh_programs_compile_through_cached_jit_only():
+    """No module of the collective tier calls `jax.jit` itself: every
+    mesh program goes through `spmd._stage_jit` -> `cached_jit`, which
+    gives it its `jit_tpu__<op>__<tag>` name, its cache key, its ledger
+    entry and the one collective dispatch gate."""
+    import spark_rapids_tpu
+
+    root = pathlib.Path(spark_rapids_tpu.__file__).parent
+    paths = sorted((root / "parallel").glob("*.py")) + [
+        root / "execs" / "collective.py"]
+    assert len(paths) > 5, paths
+    direct = []
+    for path in paths:
+        tree = ast.parse(path.read_text(), str(path))
+        jit_names = {a.asname or a.name for n in ast.walk(tree)
+                     if isinstance(n, ast.ImportFrom) and n.module == "jax"
+                     for a in n.names if a.name in ("jit", "pjit")}
+        for n in ast.walk(tree):
+            if isinstance(n, ast.Attribute) and n.attr in ("jit", "pjit") \
+                    and isinstance(n.value, ast.Name) \
+                    and n.value.id == "jax":
+                direct.append(f"{path.name}:{n.lineno}")
+            elif isinstance(n, ast.Name) and n.id in jit_names:
+                direct.append(f"{path.name}:{n.lineno}")
+    assert not direct, direct
+
+
+def test_collective_stage_has_one_executor_and_no_switch():
+    """The per-round host loop and the key that chose it are gone: the
+    key is in no documented conf, and the three execs take no `spmd`
+    argument (what is left to pin at plan time is `bucket_rounds`)."""
+    from spark_rapids_tpu.execs import collective as C
+    from spark_rapids_tpu.tools.gen_docs import configs_md
+
+    doc = configs_md()
+    assert BUCKET_KEY.replace("bucketRounds", "enabled") not in doc
+    assert BUCKET_KEY in doc and ROUND_KEY in doc
+    for cls in (C.TpuCollectiveHashAggregateExec,
+                C.TpuCollectiveHashJoinExec, C.TpuCollectiveSortExec):
+        params = inspect.signature(cls.__init__).parameters
+        assert "spmd" not in params, cls.__name__
+        assert "bucket_rounds" in params, cls.__name__
+        assert not hasattr(cls, "_materialize_spmd"), cls.__name__
