@@ -19,6 +19,7 @@ streaming design."""
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Iterator, Optional, Sequence
 
 import jax.numpy as jnp
@@ -38,9 +39,12 @@ from spark_rapids_tpu.exprs.base import (
 )
 from spark_rapids_tpu.ops.groupby import (
     AggSpec,
+    RollupLevels,
     groupby_aggregate,
     noting_paths,
     reduce_aggregate,
+    rollup_sort,
+    rollup_write,
 )
 from spark_rapids_tpu.trace import ledger as _ledger
 
@@ -62,8 +66,8 @@ _DEFER_SYNC_CAP = 1 << 18
 
 
 #: program key -> the group-by path its trace took (`sort`, `masked`,
-#: `scatter`; `none` for a grand aggregate), for the `agg.*` spans of
-#: every later run of that program in this process
+#: `scatter`, `rollup`; `none` for a grand aggregate), for the `agg.*`
+#: spans of every later run of that program in this process
 _PATHS: dict = {}
 
 
@@ -86,6 +90,27 @@ def _as_device_rows(batch):
     if _ledger.LEDGER.enabled and type(batch.num_rows) is int:
         _ledger.note_occupancy(batch.num_rows, batch.capacity)
     return batch.with_device_num_rows()
+
+
+def _ordinals_read(e: Expression, into: set) -> None:
+    """The ordinals of the bound references in `e`'s tree."""
+    if isinstance(e, BoundReference):
+        into.add(e.ordinal)
+    for c in e.children:
+        _ordinals_read(c, into)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Rollup:
+    """An absorbed grouping-set Expand taken as the levels of one sort:
+    the update's inputs bound to the Expand's CHILD, the literal's
+    column left out of them (`exprs`, `input_schema`, `specs`)."""
+
+    expand: TpuExec
+    shape: RollupLevels
+    exprs: list
+    input_schema: T.Schema
+    specs: list
 
 
 class TpuHashAggregateExec(TpuExec):
@@ -180,6 +205,9 @@ class TpuHashAggregateExec(TpuExec):
         self._jit_merge = None
         self._jit_finalize = None
         self._jits = None
+        #: how the absorbed Expand is taken as the levels of one sort
+        #: (`_rollup_of`), settled with `_jits`; None: as it stands
+        self._rollup: Optional[_Rollup] = None
         self._jit_lock = threading.Lock()
 
     def _cache_key(self) -> tuple:
@@ -232,13 +260,9 @@ class TpuHashAggregateExec(TpuExec):
             io += n_in
         return specs
 
-    def _update_batch(self, batch: ColumnarBatch,
-                      live_mask=None) -> ColumnarBatch:
-        """Project inputs then run the update aggregation (traceable).
-        `live_mask` carries fused WHERE predicates from an absorbed
-        filter chain — masked rows never existed, but no compaction
-        kernels are paid for them."""
-        from spark_rapids_tpu.columnar.column import MIN_CAPACITY
+    def _project_inputs(self, batch: ColumnarBatch, exprs, n_keys: int):
+        """The update's input columns: `exprs` over `batch`, the first
+        `n_keys` of them grouping keys (traceable)."""
         from spark_rapids_tpu.execs.jit_cache import expr_key
 
         ctx = EvalContext.for_batch(batch)
@@ -248,7 +272,7 @@ class TpuHashAggregateExec(TpuExec):
         # expr_key leaves out)
         evaluated: dict = {}
         cols = []
-        for e in self.input_exprs:
+        for e in exprs:
             k = expr_key(e)
             if k not in evaluated:
                 evaluated[k] = e.eval(ctx)
@@ -259,7 +283,7 @@ class TpuHashAggregateExec(TpuExec):
         # the emitted key VALUE is canonical too, not just the grouping
         from spark_rapids_tpu.columnar.column import Column as _Col
 
-        for i in range(self.n_keys):
+        for i in range(n_keys):
             c = cols[i]
             if isinstance(c, _Col) and isinstance(
                     c.dtype, (T.FloatType, T.DoubleType)):
@@ -267,6 +291,17 @@ class TpuHashAggregateExec(TpuExec):
                               jnp.where(c.data == 0, 0.0, c.data)
                               ).astype(c.data.dtype)
                 cols[i] = _Col(d, c.validity, c.dtype)
+        return cols
+
+    def _update_batch(self, batch: ColumnarBatch,
+                      live_mask=None) -> ColumnarBatch:
+        """Project inputs then run the update aggregation (traceable).
+        `live_mask` carries fused WHERE predicates from an absorbed
+        filter chain — masked rows never existed, but no compaction
+        kernels are paid for them."""
+        from spark_rapids_tpu.columnar.column import MIN_CAPACITY
+
+        cols = self._project_inputs(batch, self.input_exprs, self.n_keys)
         proj = ColumnarBatch(cols, batch.num_rows, self.update_input_schema)
         specs = self._update_specs()
         if self.n_keys == 0:
@@ -278,6 +313,30 @@ class TpuHashAggregateExec(TpuExec):
             return out.shrink_to_capacity(MIN_CAPACITY)
         return groupby_aggregate(proj, list(range(self.n_keys)), specs,
                                  self.partial_schema, live_mask)
+
+    def _write_rollup(self, sorted_batch: ColumnarBatch, breaks,
+                      total) -> ColumnarBatch:
+        """The rollup path's second program, sized by the first one's
+        count: the groups of all levels of one input batch, as a partial
+        whose capacity is what its counted rows pad to (a level has at
+        most as many groups as rows, so uncounted it would be levels x
+        the input's capacity, nearly all of it padding).  The count is
+        the one readback a large partial's sizing pays on every path."""
+        from spark_rapids_tpu.execs.jit_cache import cached_jit
+        from spark_rapids_tpu.parallel import pipeline as P
+
+        shape, specs = self._rollup.shape, self._rollup.specs
+        schema = self.partial_schema
+        rows = P.device_read_int(total, tag="agg.size")
+        capacity = pad_capacity(rows)
+        write = cached_jit(
+            self._path_keys[0] + ("write", capacity),
+            lambda: lambda b, brk: rollup_write(b, brk, shape, specs,
+                                                schema, capacity),
+            op=self.name)
+        with _trace.span("agg.rollup.write", capacity=capacity, rows=rows):
+            out = write(sorted_batch, breaks)
+        return dataclasses.replace(out, num_rows=rows)
 
     def _merge_batch(self, partial: ColumnarBatch) -> ColumnarBatch:
         if self.n_keys == 0:
@@ -469,6 +528,66 @@ class TpuHashAggregateExec(TpuExec):
         ch = self._absorbed_chain()
         return ch[1] if ch is not None else self.children[0]
 
+    def _rollup_of(self, expand) -> Optional["_Rollup"]:
+        """How to take `expand`, the absorbed exec directly under the
+        update, as the levels of one sort (ops.groupby, the rollup
+        path), or None to run it as it stands.  Decided from the plan's
+        structure alone: the projections have a grouping-set rewrite's
+        form, the grouping keys are its NULLed columns and its literal
+        (and columns it passes whole), no aggregate reads either, and
+        the sets of kept keys are nested."""
+        form = expand.grouping_form()
+        if form is None or not all(type(g) is BoundReference
+                                   for g in self.groups):
+            return None
+        sources, kept, gid_column, gids = form
+        key_columns = [g.ordinal for g in self.groups]
+        nulled = {c for c, k in enumerate(kept)
+                  if c != gid_column and len(k) < len(gids)}
+        if len(set(key_columns)) < len(key_columns) \
+                or gid_column not in key_columns \
+                or not nulled <= set(key_columns):
+            return None
+        reads: set = set()
+        for e in self.input_exprs[self.n_keys:]:
+            _ordinals_read(e, reads)
+        if reads & (nulled | {gid_column}):
+            return None
+        # the update's inputs without the literal: the literal's column
+        # is written from the level, not sorted or gathered
+        gid_position = key_columns.index(gid_column)
+        keys = [i for i in range(self.n_keys) if i != gid_position]
+
+        def ordinal(i: int) -> int:
+            return i - (i > gid_position)
+
+        # a key that more sets keep is dropped later: major in the sort
+        chain = sorted(keys, key=lambda i: -len(kept[key_columns[i]]))
+        levels = []
+        for p, gid in enumerate(gids):
+            held = [i for i in keys if p in kept[key_columns[i]]]
+            if set(held) != set(chain[:len(held)]):
+                return None  # CUBE, unrelated sets: no one sort serves
+            levels.append((len(held), gid))
+
+        def to_child(e: Expression) -> Expression:
+            if type(e) is BoundReference:
+                return BoundReference(sources[e.ordinal], e.dtype,
+                                      e.nullable, e.name)
+            return e
+
+        fields = self.update_input_schema.fields
+        return _Rollup(
+            expand,
+            RollupLevels(tuple(ordinal(i) for i in chain), tuple(levels),
+                         tuple(ordinal(i) for i in keys), gid_position),
+            [e.transform_up(to_child)
+             for i, e in enumerate(self.input_exprs) if i != gid_position],
+            T.Schema([f for i, f in enumerate(fields)
+                      if i != gid_position]),
+            [AggSpec(s.op, ordinal(s.ordinal), s.out_dtype)
+             for s in self._update_specs()])
+
     def execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
         if self.mode == "complete":
             assert self.num_partitions == 1
@@ -499,6 +618,18 @@ class TpuHashAggregateExec(TpuExec):
                 execs = chain[0] if chain is not None else []
                 ckeys = chain[2] if chain is not None else ()
                 from spark_rapids_tpu.execs.basic import TpuFilterExec
+                from spark_rapids_tpu.execs.expand import TpuExpandExec
+
+                # a grouping-set Expand directly under the update, its
+                # sets nested: its levels come from one sort of the
+                # rows that enter it, and it expands none
+                rollup = self._rollup_of(execs[-1]) \
+                    if execs and isinstance(execs[-1], TpuExpandExec) \
+                    else None
+                if rollup is not None:
+                    execs = execs[:-1]
+                    rollup.expand.taken_as_rollup = True
+                self._rollup = rollup
 
                 # filters become row MASKS (no compaction kernels) when
                 # nothing in the chain multiplies rows — row positions
@@ -512,7 +643,7 @@ class TpuHashAggregateExec(TpuExec):
                     else:
                         stages.append(("fn", e.make_batch_fn()))
 
-                def update_full(b):
+                def chained(b):
                     from spark_rapids_tpu.columnar.transfer import (
                         EncodedBatch,
                     )
@@ -528,9 +659,20 @@ class TpuHashAggregateExec(TpuExec):
                             mask = m if mask is None else (mask & m)
                         else:
                             b = st(b)
-                    return self._update_batch(b, mask)
+                    return b, mask
 
-                upd_key = key + ("absorb", ckeys, "update")
+                def update_full(b):
+                    b, mask = chained(b)
+                    if rollup is None:
+                        return self._update_batch(b, mask)
+                    proj = ColumnarBatch(
+                        self._project_inputs(b, rollup.exprs,
+                                             self.n_keys - 1),
+                        b.num_rows, rollup.input_schema)
+                    return rollup_sort(proj, rollup.shape, mask)
+
+                upd_key = key + ("absorb", ckeys, "update") \
+                    if rollup is None else key + ("absorb", ckeys, "rollup")
                 upd = cached_jit(upd_key,
                                  lambda: _noting_path(upd_key, update_full),
                                  op=self.name)
@@ -722,6 +864,9 @@ class TpuHashAggregateExec(TpuExec):
         # one program
         chain_len = (len(_ch[0]) + 1) if _ch is not None else 1
 
+        levels = {} if self._rollup is None \
+            else {"levels": len(self._rollup.shape.levels)}
+
         def dispatch(batch):
             """Async half: the update program for batch k+1 is
             dispatched before batch k's sizing sync retires (the same
@@ -735,7 +880,8 @@ class TpuHashAggregateExec(TpuExec):
             self._tick_absorbed(batch)
             with _trace.span("agg.update",
                              capacity=getattr(batch, "capacity", None),
-                             path=_PATHS.get(self._path_keys[0])), \
+                             path=_PATHS.get(self._path_keys[0]),
+                             **levels), \
                     MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
                 enc = isinstance(batch, EncodedBatch)
                 if enc and self._jit_update_donated is not None:
@@ -824,6 +970,10 @@ class TpuHashAggregateExec(TpuExec):
 
         def retire(part):
             nonlocal pending_rows
+            if self._rollup is not None:
+                with MetricTimer(self.metrics[TOTAL_TIME],
+                                 op=self.name) as t:
+                    part = t.observe(self._write_rollup(*part))
             if (not isinstance(part.num_rows, int)
                     and part.capacity <= _DEFER_SYNC_CAP):
                 pending.append(store.register(

@@ -6,7 +6,13 @@ concatenated tables).  Here all projections evaluate inside ONE compiled
 program: results stack to (n_projections, capacity) per column and a
 vectorized gather interleaves them into a prefix-compact output of
 capacity `n_projections * capacity` with `n_projections * num_rows` live
-rows — no per-projection kernel launches, no host loop."""
+rows — no per-projection kernel launches, no host loop.
+
+Not materialised at all: under an aggregate that absorbs it, where its
+projections are a grouping-set rewrite's and the sets are nested
+(ROLLUP).  The aggregate reads `grouping_form()` and takes every
+level from one sort of the rows that enter this exec
+(`execs/aggregate.py:_rollup_of`; `ops/groupby.py`, the rollup path)."""
 
 from __future__ import annotations
 
@@ -18,7 +24,12 @@ from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import ColumnarBatch
 from spark_rapids_tpu.columnar.column import Column, StringColumn, pad_width
 from spark_rapids_tpu.execs.base import BatchFn, FusableExec, TpuExec
-from spark_rapids_tpu.exprs.base import EvalContext, Expression
+from spark_rapids_tpu.exprs.base import (
+    BoundReference,
+    EvalContext,
+    Expression,
+    Literal,
+)
 
 
 class TpuExpandExec(FusableExec):
@@ -29,6 +40,10 @@ class TpuExpandExec(FusableExec):
         super().__init__(child)
         self.projections = [list(p) for p in projections]
         self._schema = schema
+        #: set by an aggregate that absorbed this exec and takes its
+        #: nested grouping sets from one sort of the rows that enter it
+        #: (ops.groupby, the rollup path): no row is then expanded
+        self.taken_as_rollup = False
 
     @property
     def schema(self) -> T.Schema:
@@ -37,10 +52,48 @@ class TpuExpandExec(FusableExec):
     @property
     def fanout(self) -> int:
         """Output rows per input row."""
-        return len(self.projections)
+        return 1 if self.taken_as_rollup else len(self.projections)
 
     def node_desc(self) -> str:
-        return f"TpuExpandExec [{len(self.projections)} projections]"
+        how = ", taken as rollup levels of one sort" \
+            if self.taken_as_rollup else ""
+        return f"TpuExpandExec [{len(self.projections)} projections{how}]"
+
+    def grouping_form(self):
+        """`(sources, kept, gid_column, gids)` where the projections
+        have the form a grouping-set rewrite gives them, else None:
+        every output column but one is, in each projection, the same
+        child column's reference or a NULL literal (`sources[c]` the
+        child's ordinal, `kept[c]` the projections that pass it), and
+        the one left is an integer literal in every projection
+        (`gids`, at `gid_column`, where `sources` has None)."""
+        sources: list = []
+        kept: list = []
+        gid_column, gids = None, ()
+        for column in zip(*self.projections):
+            refs = {e.ordinal for e in column
+                    if type(e) is BoundReference}
+            others = [e for e in column if type(e) is not BoundReference]
+            if len(refs) == 1 and all(
+                    type(e) is Literal and e.value is None
+                    for e in others):
+                sources.append(refs.pop())
+                kept.append(frozenset(
+                    p for p, e in enumerate(column)
+                    if type(e) is BoundReference))
+            elif gid_column is None and not refs and all(
+                    type(e) is Literal and type(e.value) is int
+                    and isinstance(e.dtype, T.IntegralType)
+                    for e in others):
+                gid_column = len(sources)
+                gids = tuple(e.value for e in others)
+                sources.append(None)
+                kept.append(frozenset())
+            else:
+                return None
+        if gid_column is None:
+            return None
+        return sources, kept, gid_column, gids
 
     def fuse_key(self):
         from spark_rapids_tpu.execs.jit_cache import exprs_key

@@ -1,4 +1,4 @@
-"""Group-by aggregation: three paths to one answer.
+"""Group-by aggregation: four paths to one answer.
 
 TPU counterpart of cudf's `Table.groupBy(...).aggregate(...)` as used by
 GpuHashAggregateExec (ref: sql-plugin/.../aggregate.scala:240,366).  cudf
@@ -26,6 +26,19 @@ batch shows:
    are gathered from each segment's first row (one more stable pass
    finds them); the per-spec `_eval_agg` still scatters (q3's and
    q67's aggregates; ROADMAP S2).
+
+The fourth is not `groupby_aggregate`'s to pick: the aggregate exec
+takes it where the plan under its update is a grouping-set Expand
+whose sets are nested (ROLLUP; `execs/aggregate.py`).
+
+4. **Rollup** (`rollup_sort`, then `rollup_write`): the nested sets are
+   prefixes of one key list, so rows sorted ONCE by that list are
+   sorted for every set, and each set's segment starts are read off
+   the same sorted batch.  The Expand is never materialised: the sort,
+   the gathers and the key comparisons run at the rows that entered
+   it, not at rows x sets.  Two programs with one count between them:
+   the first sorts and counts the groups of all sets, the second
+   writes them at the capacity that count pads to.
 
 The times are the chip's (PERF.md section 6, PR 26).  Aggregations are
 expressed as (update, merge) pairs the way Spark aggregate modes are
@@ -380,6 +393,15 @@ def _coded_groupby(batch: ColumnarBatch, key_ordinals: Sequence[int],
     return ColumnarBatch(out_cols, num_groups, out_schema)
 
 
+def _plain(col: AnyColumn) -> AnyColumn:
+    """`col` without a dictionary sidecar, as a partial carries none."""
+    if isinstance(col, StringColumn):
+        return StringColumn(col.chars, col.lengths, col.validity)
+    if isinstance(col, Column):
+        return Column(col.data, col.validity, col.dtype)
+    return col
+
+
 def groupby_aggregate(batch: ColumnarBatch, key_ordinals: Sequence[int],
                       aggs: Sequence[AggSpec],
                       out_schema: T.Schema,
@@ -423,11 +445,7 @@ def groupby_aggregate(batch: ColumnarBatch, key_ordinals: Sequence[int],
     first_rows = stable_argsort(~is_start)
     group_live = idx < num_groups
     for kc in key_cols:
-        g = kc.gather(first_rows, group_live)
-        # plain columns, as a partial carries no dictionary sidecar
-        out_cols.append(StringColumn(g.chars, g.lengths, g.validity)
-                        if isinstance(kc, StringColumn)
-                        else Column(g.data, g.validity, kc.dtype))
+        out_cols.append(_plain(kc.gather(first_rows, group_live)))
 
     for spec in aggs:
         out_cols.append(_eval_agg(spec, sorted_batch, seg_id, live_sorted,
@@ -435,6 +453,121 @@ def groupby_aggregate(batch: ColumnarBatch, key_ordinals: Sequence[int],
     n_keys = len(key_cols)
     assert len(out_schema) == n_keys + len(aggs)
     return ColumnarBatch(out_cols, num_groups, out_schema)
+
+
+@dataclasses.dataclass(frozen=True)
+class RollupLevels:
+    """Nested grouping sets over one key list, as static structure.
+
+    `chain`: the batch ordinals of the grouping keys, ordered by the set
+    that drops them, the key every set keeps first.  `levels`: one
+    `(depth, gid)` a set, in the order the sets were written: the set
+    keeps `chain[:depth]`, reads NULL for `chain[depth:]`, and `gid` is
+    the literal that tells it from the others.  `key_ordinals`: the
+    same keys in the order the output lists them; `gid_position`: where
+    among them the literal's column goes."""
+
+    chain: tuple
+    levels: tuple
+    key_ordinals: tuple
+    gid_position: int
+
+
+def rollup_sort(batch: ColumnarBatch, shape: RollupLevels,
+                live_mask=None) -> tuple:
+    """First half of the rollup path: ONE sort of `batch` (the grouping
+    keys and the aggregates' inputs of the rows that would have entered
+    the Expand) serves every level.  Returns the sorted batch, live rows
+    first and counted by its `num_rows`; `breaks`, per row the position
+    in `shape.chain` of the first key that differs from the row before
+    (the chain's length where none does, -1 for the first row), so that
+    a row starts a group of a level exactly where `breaks < depth`; and
+    the groups of all levels together, one scalar, which sizes
+    `rollup_write`'s output.  Traceable."""
+    _note_path("rollup")
+    cap = batch.capacity
+    live = batch.row_mask()
+    if live_mask is not None:
+        live = live & live_mask
+    perm = sort_permutation(batch, [SortOrder(o) for o in shape.chain],
+                            live=live)
+    n_live = jnp.sum(live.astype(jnp.int32))
+    sorted_batch = ColumnarBatch(
+        [_plain(c.gather(perm)) for c in batch.columns], n_live,
+        batch.schema)
+    # one pass over the keys, minor key first, leaves the major-most
+    # key that differs
+    breaks = jnp.full((cap,), len(shape.chain), jnp.int32)
+    for pos in reversed(range(len(shape.chain))):
+        same = _keys_equal_adjacent(sorted_batch.columns[shape.chain[pos]])
+        breaks = jnp.where(same, breaks, pos)
+    breaks = jnp.where(jnp.arange(cap, dtype=jnp.int32) == 0, -1, breaks)
+    live_sorted = sorted_batch.row_mask()
+    total = sum(jnp.sum((live_sorted & (breaks < depth)).astype(jnp.int32))
+                for depth, _ in shape.levels)
+    return sorted_batch, breaks, total
+
+
+def rollup_write(sorted_batch: ColumnarBatch, breaks: jax.Array,
+                 shape: RollupLevels, aggs: Sequence[AggSpec],
+                 out_schema: T.Schema, out_capacity: int) -> ColumnarBatch:
+    """Second half: the groups of every level, written prefix-compact
+    into `out_capacity` rows (at least `rollup_sort`'s total), the
+    levels one after another.  A level's sums are `_eval_agg` over the
+    one sorted batch with its own segment ids, so two levels that hold
+    the same rows add the same terms in the same order: their DOUBLE
+    sums are bit-equal, which a rank above them relies on.  Keys past a
+    level's depth read NULL; the literal's column its `gid`.
+    Traceable."""
+    cap = sorted_batch.capacity
+    n_levels = len(shape.levels)
+    live_sorted = sorted_batch.row_mask()
+    depths = jnp.asarray([d for d, _ in shape.levels], jnp.int32)
+    starts = live_sorted[None, :] & (breaks[None, :] < depths[:, None])
+    counts = jnp.sum(starts.astype(jnp.int32), axis=1)
+    ends = jnp.cumsum(counts)
+    offsets = ends - counts
+    out_idx = jnp.arange(out_capacity, dtype=jnp.int32)
+    group_live = out_idx < ends[-1]
+    level_of = jnp.minimum(
+        jnp.sum((out_idx[:, None] >= ends[None, :]).astype(jnp.int32),
+                axis=1), n_levels - 1)
+    in_level = [group_live & (level_of == lv) for lv in range(n_levels)]
+
+    # keys: a group's are its first row's.  One stable pass a level puts
+    # the rows that start a group first, in order (a scan, so the sort
+    # inside is compiled once); every key array is then ONE gather at
+    # the output's capacity
+    first_rows = jax.lax.map(stable_argsort, ~starts)
+    src = first_rows[level_of,
+                     jnp.clip(out_idx - jnp.take(offsets, level_of),
+                              0, cap - 1)]
+    depth_of = jnp.take(depths, level_of)
+    position = {o: pos for pos, o in enumerate(shape.chain)}
+    out_cols: list[AnyColumn] = []
+    for o in shape.key_ordinals:
+        out_cols.append(_plain(sorted_batch.columns[o].gather(
+            src, group_live & (position[o] < depth_of))))
+    gid_field = out_schema.fields[shape.gid_position]
+    gids = jnp.asarray([g for _, g in shape.levels],
+                       T.to_numpy_dtype(gid_field.dtype))
+    out_cols.insert(shape.gid_position, Column(
+        jnp.take(gids, level_of), group_live, gid_field.dtype))
+
+    segs = jnp.where(live_sorted[None, :],
+                     jnp.cumsum(starts.astype(jnp.int32), axis=1) - 1
+                     + offsets[:, None], out_capacity)
+    for spec in aggs:
+        parts = [_eval_agg(spec, sorted_batch, segs[lv], live_sorted,
+                           in_level[lv], out_capacity, cap)
+                 for lv in range(n_levels)]
+        data, validity = parts[0].data, parts[0].validity
+        for lv in range(1, n_levels):
+            data = jnp.where(in_level[lv], parts[lv].data, data)
+            validity = validity | parts[lv].validity
+        out_cols.append(Column(data, validity, parts[0].dtype))
+    assert len(out_schema) == len(out_cols)
+    return ColumnarBatch(out_cols, ends[-1], out_schema)
 
 
 def _minmax_sentinel(phys, op: str):

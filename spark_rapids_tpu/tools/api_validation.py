@@ -145,7 +145,9 @@ _EXEC_MAP: dict = {
                                 "AQE coalesced partition specs"),
     "DataWritingCommandExec": ("spark_rapids_tpu.io.write",
                                "FileWriteExec", "+Parquet/Csv/Orc"),
-    "ExpandExec": ("spark_rapids_tpu.execs.expand", "TpuExpandExec", ""),
+    "ExpandExec": ("spark_rapids_tpu.execs.expand", "TpuExpandExec",
+                   "not materialised under an aggregate whose grouping "
+                   "sets are nested: docs/fusion.md"),
     "FilterExec": ("spark_rapids_tpu.execs.basic", "TpuFilterExec", ""),
     "GenerateExec": ("spark_rapids_tpu.execs.generate",
                      "TpuGenerateExec", ""),
