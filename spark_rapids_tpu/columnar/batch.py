@@ -287,17 +287,46 @@ def _shrink_col(c: AnyColumn, new_cap: int) -> AnyColumn:
                   c.dict_values, c.dict_len)
 
 
-def concat_batches(batches: Sequence[ColumnarBatch]) -> ColumnarBatch:
+def _device_of(batch: ColumnarBatch):
+    """The one device a batch was committed to, read off its first
+    column; None for a traced, host-born or partitioned batch."""
+    if not batch.columns:
+        return None
+    leaf = batch.columns[0].validity
+    if isinstance(leaf, jax.core.Tracer) or not isinstance(leaf, jax.Array) \
+            or not leaf.committed:
+        return None
+    devices = leaf.devices()
+    return next(iter(devices)) if len(devices) == 1 else None
+
+
+def concat_batches(batches: Sequence[ColumnarBatch],
+                   op: Optional[str] = None) -> ColumnarBatch:
     """Concatenate batches of one schema into a single larger batch.
 
     TPU analog of GpuCoalesceBatches' cudf Table.concatenate
     (ref: GpuCoalesceBatches.scala:340).  Row counts must be concrete
     (host-side sizing decision, like the reference's coalesce goal
     logic), but the data never leaves the device: each part is packed
-    into the output with dynamic_update_slice — no host round trip."""
+    into the output with dynamic_update_slice — no host round trip.
+
+    The parts live on one device.  Batches parked on different chips
+    of a mesh (a collective stage's shards handed to an operator that
+    has no collective lowering) are refused here, with `op`, the
+    operator that asked, and the two devices."""
     assert batches, "concat of zero batches"
-    schema = batches[0].schema
     ns = [b.concrete_num_rows() for b in batches]
+    # a part without rows is not read, wherever it lives
+    homes = [_device_of(b) for b, n in zip(batches, ns) if n]
+    first = next((d for d in homes if d is not None), None)
+    apart = next((d for d in homes if d is not None and d != first), None)
+    if apart is not None:
+        raise ValueError(
+            f"{op or 'concat_batches'}: cannot concatenate batches that "
+            f"live on different devices, {first} and {apart}: the shards "
+            "of a collective stage reach an operator that runs on one "
+            "chip and has no collective lowering")
+    schema = batches[0].schema
     total = sum(ns)
     cap = pad_capacity(total)
     out_cols: list[AnyColumn] = []

@@ -78,6 +78,17 @@ def stage_bucket_rounds(conf=None) -> int:
     return int(conf.get(SPMD_BUCKET_ROUNDS))
 
 
+def _grid_rows(rounds) -> int:
+    """Live rows of a rounds[r][d] grid whose counts the host holds
+    (`_shard_rounds` pins them)."""
+    return sum(b.concrete_num_rows() for shards in rounds for b in shards)
+
+
+def _grid_capacity(rounds) -> int:
+    """The capacity `shard_stack_rounds` unifies a grid to."""
+    return max(b.capacity for shards in rounds for b in shards)
+
+
 def _fold_groups(groups: list[list[ColumnarBatch]],
                  schema: T.Schema) -> list[ColumnarBatch]:
     """Per-shard batch lists -> one batch per shard (empty batches for
@@ -117,12 +128,17 @@ class _CollectiveBase(TpuExec):
     def num_partitions(self) -> int:
         return int(self.mesh.shape[DATA_AXIS])
 
-    def _shard_rounds(self, child: TpuExec
+    def _shard_rounds(self, child: TpuExec, size_to_rows: bool = False
                       ) -> Iterator[list[ColumnarBatch]]:
         """Drain child partitions into per-shard batch groups, yielding
         a round whenever any shard reaches the row budget.  Always
         yields at least one round (of empties) so downstream programs
-        emit schema-correct output for empty inputs."""
+        emit schema-correct output for empty inputs.  `size_to_rows`
+        cuts a large batch to the capacity its counted rows pad to, as
+        TpuSortExec does for a global sort: a filter's output keeps its
+        input's capacity, and a stage is paid by capacity."""
+        from spark_rapids_tpu.execs.sort import _COUNT_ABOVE_CAPACITY
+
         n = self.num_partitions
         budget = get_conf().get(COLLECTIVE_ROUND_ROWS)
         per_shard: list[list[ColumnarBatch]] = [[] for _ in range(n)]
@@ -132,7 +148,10 @@ class _CollectiveBase(TpuExec):
             for b in child.execute_partition(p):
                 r = b.concrete_num_rows()
                 tgt = rows.index(min(rows))  # least-loaded shard
-                per_shard[tgt].append(_dc.replace(b, num_rows=r))
+                b = _dc.replace(b, num_rows=r)
+                if size_to_rows and b.capacity > _COUNT_ABOVE_CAPACITY:
+                    b = b.shrink_to_capacity(pad_capacity(r))
+                per_shard[tgt].append(b)
                 rows[tgt] += r
                 if max(rows) >= budget:
                     if "collectiveRounds" in self.metrics:
@@ -145,6 +164,27 @@ class _CollectiveBase(TpuExec):
             if "collectiveRounds" in self.metrics:
                 self.metrics["collectiveRounds"].add(1)
             yield _fold_groups(per_shard, child.schema)
+
+    def _tick_exchange(self, xs: ColumnarBatch, slot_capacity: int,
+                       rows: Optional[int] = None) -> int:
+        """Count one exchange program's dispatch over the stacked
+        input `xs`: `collectiveBytes` is what its all_to_all was sized
+        to carry — every shard's send buffer of n slots of
+        `slot_capacity` rows, each round, at the schema's device row
+        width — and `collectiveRows` the live `rows` that crossed
+        (left out by the aggregate, whose `collectiveRows` are its
+        groups).  From shapes and counts the host already holds: no
+        readback.  Returns the row width, for the stage's span."""
+        from spark_rapids_tpu.parallel import spmd as S
+
+        n = self.num_partitions
+        width = S.row_bytes(xs)
+        n_rounds = int(xs.num_rows.shape[0])
+        self.metrics["collectiveBytes"].add(
+            n_rounds * n * n * slot_capacity * width)
+        if rows is not None:
+            self.metrics["collectiveRows"].add(rows)
+        return width
 
     # -- per-partition serving ----------------------------------------- #
 
@@ -207,6 +247,7 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
         self._schema = T.Schema(
             list(self._agg.partial_schema.fields[: self._agg.n_keys])
             + [na.output_field() for na in self._agg.aggs])
+        self._rollup = self._take_rollup()
 
     @property
     def schema(self) -> T.Schema:
@@ -221,6 +262,7 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
 
     def additional_metrics(self):
         return [("collectiveRows", "MODERATE"),
+                ("collectiveBytes", "MODERATE"),
                 ("collectiveRounds", "MODERATE"),
                 ("collectivePartialRows", "MODERATE")]
 
@@ -238,6 +280,24 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
         cols = [e.eval(ctx) for e in self._agg.final_exprs]
         return ColumnarBatch(cols, merged.num_rows, self._schema)
 
+    def _take_rollup(self):
+        """How to take the grouping-set Expand directly under this
+        stage as the levels of one sort, as the one-chip aggregate
+        does (`TpuHashAggregateExec._rollup_of`), or None: the Expand
+        then runs as it stands and its rows enter the update program.
+        Settled at plan time, from the plan's structure alone; a taken
+        Expand is told so, and its rows never exist."""
+        from spark_rapids_tpu.execs.expand import TpuExpandExec
+
+        expand = self.children[0]
+        if not isinstance(expand, TpuExpandExec) \
+                or self._agg._absorbed_chain() is None:
+            return None
+        taken = self._agg._rollup_of(expand)
+        if taken is not None:
+            expand.taken_as_rollup = True
+        return taken
+
     # -- driver ----------------------------------------------------------- #
 
     def _materialize(self) -> list[list[ColumnarBatch]]:
@@ -253,21 +313,32 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
         tight capacity — same keys always land on the same shard, so
         the cross-round fold is shard-local.  A group-by whose
         partials are as many as its rows counts its way back to the
-        input's bucket, so one path serves both."""
+        input's bucket, so one path serves both.  Over a ROLLUP's
+        Expand the map side is the rollup path's two programs
+        (`_rollup_partials`)."""
         from spark_rapids_tpu.parallel import spmd as S
         from spark_rapids_tpu.parallel.exchange import exchange_shard
 
-        child = self.children[0]
+        rollup = self._rollup
+        # a taken Expand is not run: the rows that would enter it do
+        child = self.children[0] if rollup is None \
+            else rollup.expand.children[0]
         n = self.num_partitions
         akey = self._agg._cache_key()
         ko = list(range(self._agg.n_keys))
 
-        def xchg_body(partial: ColumnarBatch) -> ColumnarBatch:
-            return self._merge(exchange_shard(partial, ko, n, DATA_AXIS))
-
-        def capacity(rounds) -> int:
-            # what shard_stack_rounds unifies the grid to
-            return max(b.capacity for shards in rounds for b in shards)
+        def counted_partials(bucket):
+            """The update program over `bucket`, its partials cut to
+            their counted rows and stacked again, and the slot the
+            exchange leaves at: their capacity."""
+            update = S.make_update_scan_stage(
+                self.mesh, akey, self._pre, len(bucket),
+                op=self.name, donate=True)
+            partials = update(S.shard_stack_rounds(bucket, self.mesh))
+            counts = S.stage_counts(partials)
+            sized = S.shrink_rounds(partials, counts, mesh=self.mesh)
+            return (S.shard_stack_rounds(sized, self.mesh), counts,
+                    _grid_capacity(sized), None)
 
         with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
             shrunk: list[list[ColumnarBatch]] = []  # rounds[r][d]
@@ -275,24 +346,33 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
 
             def flush(bucket):
                 bucket = S.pad_rounds_pow2(bucket, child.schema, n)
-                input_cap = capacity(bucket)
-                update = S.make_update_scan_stage(
-                    self.mesh, akey, self._pre, len(bucket),
-                    op=self.name, donate=True)
-                partials = update(S.shard_stack_rounds(bucket, self.mesh))
-                counts = S.stage_counts(partials)
-                sized = S.shrink_rounds(partials, counts, mesh=self.mesh)
+                input_cap = _grid_capacity(bucket)
+                if rollup is None:
+                    xs, counts, cap, slot = counted_partials(bucket)
+                    how = {}
+                else:
+                    xs, counts, cap, slot = self._rollup_partials(
+                        rollup, bucket, akey)
+                    how = {"path": "rollup", "slot_capacity": slot,
+                           "levels": len(rollup.shape.levels)}
                 self.metrics["collectivePartialRows"].add(
                     int(counts.sum()))
+
+                def xchg_body(partial: ColumnarBatch) -> ColumnarBatch:
+                    return self._merge(exchange_shard(
+                        partial, ko, n, DATA_AXIS, slot))
+
                 prog = S.make_exchange_scan_stage(
-                    self.mesh, akey, xchg_body, len(bucket),
-                    op=self.name, donate=True)
+                    self.mesh, akey if slot is None else akey + (slot,),
+                    xchg_body, len(bucket), op=self.name, donate=True)
+                width = self._tick_exchange(xs, slot or cap)
                 with _trace.span("collective.agg.exchange",
                                  input_capacity=input_cap,
-                                 capacity=capacity(sized),
+                                 capacity=cap,
                                  partial_rows=int(counts.max()),
-                                 rounds=len(bucket)):
-                    merged = prog(S.shard_stack_rounds(sized, self.mesh))
+                                 rounds=len(bucket), row_bytes=width,
+                                 **how):
+                    merged = prog(xs)
                 shrunk.extend(S.shrink_rounds(merged, mesh=self.mesh))
 
             for shards in self._shard_rounds(child):
@@ -316,6 +396,64 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
             self.metrics["collectiveRows"].add(int(counts[d]))
             out.append([b])
         return out
+
+    def _rollup_partials(self, rollup, bucket, akey: tuple):
+        """The map side of a ROLLUP, per shard and round what the
+        one-chip exec does a batch (ops.groupby, the rollup path): the
+        SORT program orders the rows that would have entered the
+        Expand once and counts the groups of all levels; the host
+        fetches those counts and the WRITE program emits the levels'
+        partials at the capacity the largest pads to, with the rows
+        each sends to each destination.  Those second counts size the
+        exchange's send slots: a shard's partials spread over n
+        destinations, so a slot holds about an n-th of them and the
+        all_to_all, the received buffer and the reduce-side merge run
+        at the rows that cross, not at n x the partials' capacity.
+        Returns the stacked partials, their (R, n) counts, their
+        capacity and the slot capacity."""
+        from spark_rapids_tpu.execs.base import (
+            NUM_OUTPUT_BATCHES,
+            NUM_OUTPUT_ROWS,
+        )
+        from spark_rapids_tpu.exprs.hashing import partition_ids
+        from spark_rapids_tpu.ops.groupby import rollup_sort, rollup_write
+        from spark_rapids_tpu.parallel import spmd as S
+        from spark_rapids_tpu.parallel.exchange import destination_counts
+
+        agg, n = self._agg, self.num_partitions
+        rkey = akey + ("rollup", rollup.expand.fuse_key())
+        ko = list(range(agg.n_keys))
+        # the absorbed Expand's own count: the rows it was handed
+        expand = rollup.expand.metrics
+        expand[NUM_OUTPUT_ROWS].add(_grid_rows(bucket))
+        expand[NUM_OUTPUT_BATCHES].add(len(bucket))
+
+        def sort_body(b: ColumnarBatch):
+            proj = ColumnarBatch(
+                agg._project_inputs(b, rollup.exprs, agg.n_keys - 1),
+                b.num_rows, rollup.input_schema)
+            return rollup_sort(proj, rollup.shape)
+
+        sort = S.make_scan_stage("spmdrollupsort", self.mesh, rkey,
+                                 sort_body, len(bucket), op=self.name,
+                                 donate=True)
+        ordered, breaks, totals = sort(
+            S.shard_stack_rounds(bucket, self.mesh))
+        counts = S.fetch(totals)
+        cap = pad_capacity(int(counts.max()))
+
+        def write_body(b: ColumnarBatch, brk):
+            part = rollup_write(b, brk, rollup.shape, rollup.specs,
+                                agg.partial_schema, cap)
+            pid = partition_ids([part.columns[o] for o in ko], cap, n)
+            return part, destination_counts(part, pid, n)
+
+        write = S.make_scan_stage("spmdrollupwrite", self.mesh,
+                                  rkey + (cap,), write_body, len(bucket),
+                                  op=self.name, donate=True, n_args=2)
+        partials, dests = write(ordered, breaks)
+        slot = pad_capacity(int(S.fetch(dests).max()))
+        return partials, counts, cap, slot
 
 
 class TpuCollectiveHashJoinExec(_CollectiveBase):
@@ -361,6 +499,8 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
 
     def additional_metrics(self):
         return [("buildRows", "MODERATE"),
+                ("collectiveRows", "MODERATE"),
+                ("collectiveBytes", "MODERATE"),
                 ("collectiveRounds", "MODERATE")]
 
     # -- fused bodies ------------------------------------------------------ #
@@ -441,15 +581,26 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
         def stream_body(b: ColumnarBatch) -> ColumnarBatch:
             return route_shard(b, self._route_stream(b), n, DATA_AXIS)
 
+        def exchanged(prog, rounds, side: str) -> ColumnarBatch:
+            """One side's rounds through its exchange program, counted
+            and under the stage's span."""
+            xs = S.shard_stack_rounds(rounds, self.mesh)
+            cap, rows = _grid_capacity(rounds), _grid_rows(rounds)
+            width = self._tick_exchange(xs, cap, rows)
+            with _trace.span("collective.join.exchange", side=side,
+                             input_capacity=cap, capacity=cap,
+                             rows=rows, rounds=len(rounds),
+                             row_bytes=width):
+                return prog(xs)
+
         with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
             build_rounds = S.pad_rounds_pow2(
                 list(self._shard_rounds(self.children[1])),
                 self.children[1].schema, n)
-            xs_b = S.shard_stack_rounds(build_rounds, self.mesh)
             bprog = S.make_exchange_scan_stage(
                 self.mesh, jkey + ("build",), build_body,
                 len(build_rounds), op=self.name, donate=True)
-            ys_b = bprog(xs_b)
+            ys_b = exchanged(bprog, build_rounds, "build")
             bcounts = S.stage_counts(ys_b)
             shrunk_b = S.shrink_rounds(ys_b, bcounts, mesh=self.mesh)
             self.metrics["buildRows"].add(int(bcounts.sum()))
@@ -465,11 +616,10 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
             def run_bucket(bucket):
                 bucket = S.pad_rounds_pow2(bucket,
                                            self.children[0].schema, n)
-                xs = S.shard_stack_rounds(bucket, self.mesh)
                 rprog = S.make_exchange_scan_stage(
                     self.mesh, jkey + ("stream",), stream_body,
                     len(bucket), op=self.name, donate=True)
-                ys = rprog(xs)
+                ys = exchanged(rprog, bucket, "stream")
                 counts2 = S.stage_counts(ys)
                 rounds2 = S.pad_rounds_pow2(
                     S.shrink_rounds(ys, counts2, mesh=self.mesh),
@@ -563,7 +713,9 @@ class TpuCollectiveSortExec(_CollectiveBase):
                 f"[{self._stage_desc()}]")
 
     def additional_metrics(self):
-        return [("collectiveRounds", "MODERATE")]
+        return [("collectiveRows", "MODERATE"),
+                ("collectiveBytes", "MODERATE"),
+                ("collectiveRounds", "MODERATE")]
 
     def _sort_key(self) -> tuple:
         from spark_rapids_tpu.execs.jit_cache import exprs_key
@@ -600,13 +752,15 @@ class TpuCollectiveSortExec(_CollectiveBase):
         from spark_rapids_tpu.serving import mesh_serving_enabled
 
         with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
-            raw = list(self._shard_rounds(child))
+            raw = list(self._shard_rounds(child, size_to_rows=True))
             if (len(raw) > self.bucket_rounds
                     and mesh_serving_enabled()):
                 out = self._spmd_sort_bucketed(raw, local_sort, t)
             else:
                 rounds = S.pad_rounds_pow2(raw, child.schema, n)
                 xs = S.shard_stack_rounds(rounds, self.mesh)
+                self._tick_exchange(xs, _grid_capacity(rounds),
+                                    _grid_rows(rounds))
                 fracs = S.sample_fracs(self.mesh, len(rounds),
                                        self.SAMPLE_PER_SHARD)
                 rprog = S.make_sort_route_stage(
@@ -678,6 +832,8 @@ class TpuCollectiveSortExec(_CollectiveBase):
         shrunk: list[list[ColumnarBatch]] = []
         for bucket in buckets:
             xs = S.shard_stack_rounds(bucket, self.mesh)
+            self._tick_exchange(xs, _grid_capacity(bucket),
+                                _grid_rows(bucket))
             rprog = S.make_bounds_route_stage(
                 self.mesh, skey, part, len(bucket), op=self.name,
                 donate=True)
@@ -690,3 +846,115 @@ class TpuCollectiveSortExec(_CollectiveBase):
                                  donate=True)
         return t.observe(tail(xs2))
 
+
+class TpuCollectiveWindowExec(_CollectiveBase):
+    """A window with partition keys as fused SPMD programs (the
+    collective analog of a hash exchange on `partition_by` under
+    TpuWindowExec; ref: GpuWindowExec's required child distribution,
+    ClusteredDistribution(partitionBy), over GpuShuffleExchangeExec).
+
+    Rows route by the hash of the partition keys through an
+    all_to_all, so a window partition is whole on the shard that owns
+    it, and every shard runs the one-chip window program
+    (`TpuWindowExec._window_batch`: one sort by partition and order
+    keys, every window column from segmented scans) over the rows it
+    received.  Only the partition keys decide the routing: a child
+    hashed on more keys than these (an aggregate on all its group
+    keys) has a partition's rows on several shards."""
+
+    def __init__(self, window_exprs, child: TpuExec, mesh,
+                 bucket_rounds: Optional[int] = None):
+        from spark_rapids_tpu.execs.window import TpuWindowExec
+
+        super().__init__(child)
+        self.mesh = mesh
+        self._init_stage(bucket_rounds)
+        # carries the traceable window program and its cache key
+        self._win = TpuWindowExec(window_exprs, child)
+        assert self._win.spec.partition_by, \
+            "a window without partition keys has one partition"
+
+    @property
+    def schema(self) -> T.Schema:
+        return self._win.schema
+
+    def node_desc(self) -> str:
+        w = self._win
+        fns = ", ".join(f"{we.fn.describe()}->{n}" for we, n in w.named)
+        return (f"TpuCollectiveWindowExec [{fns}] over "
+                f"({w.spec.describe()}) "
+                f"[all_to_all x{self.num_partitions}] "
+                f"[{self._stage_desc()}]")
+
+    def additional_metrics(self):
+        return [("collectiveRows", "MODERATE"),
+                ("collectiveBytes", "MODERATE"),
+                ("collectiveRounds", "MODERATE")]
+
+    def _route(self, batch: ColumnarBatch) -> jax.Array:
+        from spark_rapids_tpu.exprs.hashing import partition_ids
+
+        ctx = EvalContext.for_batch(batch)
+        return partition_ids(
+            [e.eval(ctx) for e in self._win.spec.partition_by],
+            batch.capacity, self.num_partitions)
+
+    def _materialize(self) -> list[list[ColumnarBatch]]:
+        """The window stage as THREE partitioned programs and one host
+        sync.  The count program hashes the partition keys of every
+        parked round and counts the rows each shard sends to each
+        destination; the host fetches those (R, n, n) counts — the
+        stage's one readback, which gives the send slots' capacity,
+        the rows every shard receives and therefore the stage's output
+        counts too.  The route program sends the rows through the
+        all_to_all at that slot capacity; the tail program runs the
+        window per shard over its received rounds at tight capacity.
+        Like the sort, the stage ignores bucketRounds: a partition's
+        rows may sit in any round, so every round is resident while
+        the route program runs."""
+        import numpy as np
+
+        from spark_rapids_tpu.parallel import spmd as S
+        from spark_rapids_tpu.parallel.exchange import (
+            destination_counts,
+            route_shard,
+        )
+
+        child = self.children[0]
+        n = self.num_partitions
+        wkey = self._win._cache_key()
+
+        with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
+            rounds = S.pad_rounds_pow2(
+                list(self._shard_rounds(child)), child.schema, n)
+            xs = S.shard_stack_rounds(rounds, self.mesh)
+            count = S.make_scan_stage(
+                "spmdroutecount", self.mesh, wkey,
+                lambda b: destination_counts(b, self._route(b), n),
+                len(rounds), op=self.name)
+            sent = S.fetch(count(xs))  # [round, source, destination]
+            slot = pad_capacity(int(sent.max()))
+            route = S.make_exchange_scan_stage(
+                self.mesh, wkey + (slot,),
+                lambda b: route_shard(b, self._route(b), n, DATA_AXIS,
+                                      slot),
+                len(rounds), op=self.name, donate=True,
+                tag="spmdwinroute")
+            rows = int(sent.sum())
+            width = self._tick_exchange(xs, slot, rows)
+            with _trace.span("collective.window.exchange",
+                             input_capacity=_grid_capacity(rounds),
+                             capacity=slot, rows=rows,
+                             rounds=len(rounds), row_bytes=width):
+                routed = route(xs)
+            received = sent.sum(axis=1).astype(np.int32)
+            rounds2 = S.pad_rounds_pow2(
+                S.shrink_rounds(routed, received, mesh=self.mesh),
+                child.schema, n)
+            tail = S.make_stage_tail(
+                self.mesh, wkey, self._win._window_batch, len(rounds2),
+                op=self.name, donate=True)
+            out = t.observe(tail(S.shard_stack_rounds(rounds2, self.mesh)))
+        # a window emits the rows it was handed
+        return [[b] for b in S.unstack_stage(
+            out, received.sum(axis=0), mesh=self.mesh)]

@@ -242,7 +242,7 @@ class TpuWindowExec(TpuExec):
                     return
                 batches = [h.get() for h in handles]
                 big = batches[0] if len(batches) == 1 else \
-                    concat_batches(batches)
+                    concat_batches(batches, op=self.name)
             finally:
                 for h in handles:
                     h.close()

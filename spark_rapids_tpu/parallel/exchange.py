@@ -27,7 +27,7 @@ round-trip between map and reduce sides.
 from __future__ import annotations
 
 from functools import partial
-from typing import Sequence
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -97,13 +97,35 @@ def _unsqueeze0(batch: ColumnarBatch) -> ColumnarBatch:
     return ColumnarBatch(cols, batch.num_rows[None], batch.schema)
 
 
+def destination_counts(batch: ColumnarBatch, pid: jax.Array,
+                       n_dest: int) -> jax.Array:
+    """Per-shard body: how many live rows of this shard's batch `pid`
+    sends to each destination, (n_dest,) int32 — what the host reads to
+    size `route_shard`'s send slots.  A compare and a sum a
+    destination, no scatter."""
+    live = batch.row_mask()
+    dests = jnp.arange(n_dest, dtype=jnp.int32)
+    return jnp.sum((live[:, None] & (pid[:, None] == dests[None, :]))
+                   .astype(jnp.int32), axis=0)
+
+
 def route_shard(batch: ColumnarBatch, pid: jax.Array,
-                n_dest: int, axis_name: str) -> ColumnarBatch:
+                n_dest: int, axis_name: str,
+                slot_capacity: Optional[int] = None) -> ColumnarBatch:
     """Per-shard body: send each live row of this shard's batch to the
     destination in `pid` via all_to_all; returns the rows this shard
-    owns afterwards (capacity = n_dest * input capacity,
-    prefix-compact).  `pid` entries for dead rows are ignored."""
+    owns afterwards (capacity = n_dest * slot capacity,
+    prefix-compact).  `pid` entries for dead rows are ignored.
+
+    A destination's send slot holds `slot_capacity` rows: the input's
+    capacity where none is given (any source may send a full round to
+    one destination), else what the host COUNTED no (source,
+    destination) pair to exceed (`destination_counts`) — the send
+    buffer, the all_to_all and everything after it are then sized to
+    the rows that cross and not to `n_dest` times the input's
+    padding."""
     cap = batch.capacity
+    slot_cap = cap if slot_capacity is None else slot_capacity
     live = batch.row_mask()
     pid = jnp.where(live, pid, jnp.int32(n_dest))  # dead rows -> dropped
 
@@ -112,14 +134,15 @@ def route_shard(batch: ColumnarBatch, pid: jax.Array,
     # rank of each row within its destination group
     first_pos = jnp.searchsorted(spid, spid, side="left")
     rank = jnp.arange(cap, dtype=jnp.int32) - first_pos.astype(jnp.int32)
-    slot = spid * cap + rank  # OOB for dead rows (spid == n_dest)
+    # OOB for dead rows (spid == n_dest)
+    slot = spid * slot_cap + rank
 
     def scatter(x, fill=0):
-        out_shape = (n_dest * cap,) + x.shape[1:]
+        out_shape = (n_dest * slot_cap,) + x.shape[1:]
         return jnp.full(out_shape, fill, x.dtype).at[slot].set(
             jnp.take(x, order, axis=0), mode="drop")
 
-    occ = jnp.zeros((n_dest * cap,), bool).at[slot].set(
+    occ = jnp.zeros((n_dest * slot_cap,), bool).at[slot].set(
         jnp.ones((cap,), bool), mode="drop")
     sent_cols: list[AnyColumn] = []
     for c in batch.columns:
@@ -144,7 +167,7 @@ def route_shard(batch: ColumnarBatch, pid: jax.Array,
     # compact occupied rows to a prefix (stable: preserves sender order)
     corder = stable_argsort(~occ)
     n_out = jnp.sum(occ).astype(jnp.int32)
-    out_live = jnp.arange(n_dest * cap, dtype=jnp.int32) < n_out
+    out_live = jnp.arange(n_dest * slot_cap, dtype=jnp.int32) < n_out
     out_cols: list[AnyColumn] = []
     for c in recv_cols:
         g = c.gather(corder)
@@ -153,8 +176,9 @@ def route_shard(batch: ColumnarBatch, pid: jax.Array,
 
 
 def exchange_shard(batch: ColumnarBatch, key_ordinals: Sequence[int],
-                   n_dest: int, axis_name: str) -> ColumnarBatch:
+                   n_dest: int, axis_name: str,
+                   slot_capacity: Optional[int] = None) -> ColumnarBatch:
     """route_shard with Spark-parity murmur3-pmod hash routing."""
     key_cols = [batch.columns[o] for o in key_ordinals]
     pid = partition_ids(key_cols, batch.capacity, n_dest)
-    return route_shard(batch, pid, n_dest, axis_name)
+    return route_shard(batch, pid, n_dest, axis_name, slot_capacity)

@@ -256,6 +256,19 @@ def fetch(arr) -> np.ndarray:
     return np.asarray(jax.device_get(arr))
 
 
+def row_bytes(batch: ColumnarBatch) -> int:
+    """Device bytes one row of a round-stacked batch takes: every
+    column's data, validity and, for strings, the padded character
+    matrix and the lengths.  Read off the arrays' shapes on the host."""
+    total = 0
+    for c in batch.columns:
+        leaves = (c.chars, c.lengths, c.validity) \
+            if isinstance(c, StringColumn) else (c.data, c.validity)
+        for leaf in leaves:
+            total += leaf.dtype.itemsize * int(np.prod(leaf.shape[3:]))
+    return total
+
+
 def _slice_shard(batch: ColumnarBatch, idx: tuple, rows: int,
                  device=None) -> ColumnarBatch:
     # take_piece, not plain getitem: the (round, shard) piece of a
@@ -426,17 +439,49 @@ def make_update_scan_stage(mesh, key: tuple, body: Callable,
 
 def make_exchange_scan_stage(mesh, key: tuple, body: Callable,
                              n_rounds: int, op: Optional[str] = None,
-                             donate: bool = False):
+                             donate: bool = False,
+                             tag: str = "spmdxchg"):
     """The EXCHANGE program of a stage: lax.scan over the rounds axis
     applying `body` (per-shard round batch -> per-shard batch; the
     in-program all_to_all — exchange_shard / route_shard — lives
     inside `body`, as do any fused map/reduce phases).  Emits the
-    round-stacked per-shard outputs at the worst-case n x cap receive
-    capacity; the host shrinks them ONCE at stage exit
+    round-stacked per-shard outputs at the receive capacity, n x the
+    send slot's (the input's capacity, or what `body` was told the
+    host counted); the host shrinks them ONCE at stage exit
     (`shrink_rounds`) before the tail program, so the tail's work is
     proportional to live rows, not padding."""
-    return _rounds_scan_stage("spmdxchg", mesh, key, body, n_rounds,
-                              op, donate)
+    return _rounds_scan_stage(tag, mesh, key, body, n_rounds, op,
+                              donate)
+
+
+def make_scan_stage(tag: str, mesh, key: tuple, body: Callable,
+                    n_rounds: int, op: Optional[str] = None,
+                    donate: bool = False, n_args: int = 1):
+    """A program that scans `body` over the rounds axis of `n_args`
+    round-stacked pytrees: `body` is handed one round's per-shard
+    pieces and returns any pytree of per-shard arrays (batches, row
+    arrays, counts), which leave round-stacked.  What the stages whose
+    programs pass more than one batch between them are built from: the
+    rollup's sort and write programs, the window's destination
+    count."""
+    axis = DATA_AXIS
+
+    def make():
+        def shard_fn(*xs):
+            def sbody(carry, x):
+                out = body(*_tree_index(x, 0))
+                return carry, jax.tree_util.tree_map(
+                    lambda leaf: jnp.asarray(leaf)[None], out)
+            _, ys = jax.lax.scan(sbody, jnp.int32(0), xs)
+            return ys
+
+        return _shard_map(shard_fn, mesh, (P(None, axis),) * n_args,
+                          P(None, axis))
+
+    return _stage_jit(
+        (tag, key, n_rounds), make, mesh, op,
+        (rounds_sharding(mesh),) * n_args, rounds_sharding(mesh),
+        tuple(range(n_args)) if donate else None, n_rounds)
 
 
 def make_stage_tail(mesh, key: tuple, fn: Callable, n_rounds: int,

@@ -624,6 +624,24 @@ def convert_meta(meta: PlanMeta) -> TpuExec:
         from spark_rapids_tpu.execs.window import TpuWindowExec
 
         part_by = p.window_exprs[0][0].spec.partition_by
+        if part_by:
+            # tier-2: the exchange on the partition keys and the window
+            # per shard as fused SPMD programs (SURVEY.md §5.8).  The
+            # partition keys alone decide: a child hashed on more keys
+            # (an aggregate on its group keys) does not satisfy it
+            from spark_rapids_tpu.shuffle.transport import get_transport
+
+            transport = get_transport()
+            if transport.kind == "collective" \
+                    and transport.supports_schema(kids[0].schema):
+                from spark_rapids_tpu.execs.collective import (
+                    TpuCollectiveWindowExec,
+                    stage_bucket_rounds,
+                )
+
+                return TpuCollectiveWindowExec(
+                    p.window_exprs, kids[0], transport.mesh,
+                    bucket_rounds=stage_bucket_rounds())
         if part_by and kids[0].num_partitions > 1:
             # out-of-core: hash exchange on the partition keys makes
             # window groups partition-local, each reduce partition

@@ -118,6 +118,15 @@ def test_q67_shape_on_collective_mesh(tmp_path):
                          col("ss_item_sk")))
         assert_tpu_cpu_equal(out, ignore_order=False,
                              approx_float=True)
+        # the window crosses the mesh in a stage of its own: no local
+        # exchange hands it batches parked on different chips
+        held, todo = set(), [session.history.events[-1].root]
+        while todo:
+            node = todo.pop()
+            held.add(node.desc.split(" ", 1)[0])
+            todo += node.children
+        assert "TpuCollectiveWindowExec" in held, held
+        assert "TpuShuffleExchangeExec" not in held, held
     finally:
         session.disable_collective_shuffle()
 
