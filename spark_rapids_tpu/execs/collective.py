@@ -186,6 +186,53 @@ class _CollectiveBase(TpuExec):
             self.metrics["collectiveRows"].add(rows)
         return width
 
+    def _route_counted(self, rounds, key: tuple, route, span: str,
+                       tag: str = "spmdxchg", **attrs):
+        """A rounds[r][d] grid through an exchange whose send slots
+        are sized by COUNTED rows.  The count program hashes every
+        stacked round (`route`: per-shard batch -> partition ids) and
+        counts the rows each shard sends each destination; the host
+        fetches those (R, n, n) counts — the exchange's one readback —
+        and the route program sends the rows through the all_to_all at
+        `pad_capacity` of the largest (source, destination) count,
+        under `span`.  Nothing is guessed, so nothing overflows: rows
+        that all hash to one destination count a slot of the input's
+        capacity.  The same counts say what every shard received, so
+        the mid-stage shrink needs no fetch of its own: returns the
+        received rows as a rounds[r][d] grid at tight capacity (padded
+        to a power of two of rounds, ready to stack again) and their
+        (R, n) counts."""
+        import numpy as np
+
+        from spark_rapids_tpu.parallel import spmd as S
+        from spark_rapids_tpu.parallel.exchange import (
+            destination_counts,
+            route_shard,
+        )
+
+        n = self.num_partitions
+        xs = S.shard_stack_rounds(rounds, self.mesh)
+        count = S.make_scan_stage(
+            "spmdroutecount", self.mesh, key,
+            lambda b: destination_counts(b, route(b), n),
+            len(rounds), op=self.name)
+        sent = S.fetch(count(xs))  # [round, source, destination]
+        slot = pad_capacity(int(sent.max()))
+        prog = S.make_exchange_scan_stage(
+            self.mesh, key + (slot,),
+            lambda b: route_shard(b, route(b), n, DATA_AXIS, slot),
+            len(rounds), op=self.name, donate=True, tag=tag)
+        rows = int(sent.sum())
+        width = self._tick_exchange(xs, slot, rows)
+        with _trace.span(span, input_capacity=_grid_capacity(rounds),
+                         capacity=slot, rows=rows, rounds=len(rounds),
+                         row_bytes=width, **attrs):
+            routed = prog(xs)
+        received = sent.sum(axis=1).astype(np.int32)
+        return S.pad_rounds_pow2(
+            S.shrink_rounds(routed, received, mesh=self.mesh),
+            routed.schema, n), received
+
     # -- per-partition serving ----------------------------------------- #
 
     def _materialize(self) -> list[list[ColumnarBatch]]:
@@ -460,10 +507,13 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
     """Shuffled equi-join as fused SPMD programs (the collective analog
     of TpuShuffledHashJoinExec; ref: GpuShuffledHashJoinBase over
     GpuShuffleExchangeExec).  The build (right) side exchanges once by
-    right-key hash; each stream round then routes by left-key hash and
-    joins locally in the SAME program — co-partitioning makes every
-    match shard-local, exactly the property the reference gets from
-    co-partitioned shuffle outputs."""
+    right-key hash; each stream bucket routes by left-key hash and
+    joins locally against its shard's build rows — co-partitioning
+    makes every match shard-local, exactly the property the reference
+    gets from co-partitioned shuffle outputs.  Both sides leave
+    through send slots sized by the rows the host COUNTED each shard
+    to send each destination (`_materialize`), so an exchange is paid
+    by the rows that cross and not by `n` times its input's padding."""
 
     SUPPORTED_TYPES = ("inner", "left_outer", "left_semi", "left_anti")
 
@@ -556,58 +606,41 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
                 exprs_key(self.right_keys), repr(self._schema))
 
     def _materialize(self) -> list[list[ColumnarBatch]]:
-        """The join stage as O(1) partitioned programs per side: the
-        build side runs one exchange-scan program (route by right-key
-        hash, all rounds in one lax.scan) + mid-stage shrink + one
-        tail program folding the per-shard build batch; each stream
-        bucket runs one exchange-scan program (route by left-key
-        hash) + shrink, then one probe program joining the TIGHT
-        routed rounds against the resident build shard.  Host syncs
-        happen only at stage exits (the shrink counts and each
-        bucket's true totals); overflow of the output-capacity guess
-        re-dispatches that bucket's probe program at the
-        JoinGatherer-style re-bucketed capacity."""
+        """The join stage as O(1) partitioned programs per side.  A
+        side's rounds (the build side once, the stream side a bucket)
+        leave through send slots sized by COUNTED rows, as the
+        window's stage does (`_route_counted`: a count program over
+        the side's key hash, ONE fetch of the (R, n, n) destination
+        counts — the one readback a side and bucket — and the route
+        program, all rounds in one lax.scan, at `pad_capacity` of the
+        largest count; no fetch after the exchange).  The build side
+        then folds to one batch a shard (a tail program); each stream
+        bucket runs one probe program joining the TIGHT routed rounds
+        against the resident build shard.  A side whose rows all hash
+        to one destination counts its way back to a slot of the
+        input's capacity: one path, nothing to set.  Overflow of the
+        probe's output-capacity guess re-dispatches that bucket's
+        probe program at the JoinGatherer-style re-bucketed
+        capacity."""
         from spark_rapids_tpu.parallel import spmd as S
-        from spark_rapids_tpu.parallel.exchange import route_shard
 
         n = self.num_partitions
         jkey = self._join_key()
         chunks: list[list[ColumnarBatch]] = [[] for _ in range(n)]
         semi_anti = self.join_type in ("left_semi", "left_anti")
 
-        def build_body(b: ColumnarBatch) -> ColumnarBatch:
-            return route_shard(b, self._route_build(b), n, DATA_AXIS)
-
-        def stream_body(b: ColumnarBatch) -> ColumnarBatch:
-            return route_shard(b, self._route_stream(b), n, DATA_AXIS)
-
-        def exchanged(prog, rounds, side: str) -> ColumnarBatch:
-            """One side's rounds through its exchange program, counted
-            and under the stage's span."""
-            xs = S.shard_stack_rounds(rounds, self.mesh)
-            cap, rows = _grid_capacity(rounds), _grid_rows(rounds)
-            width = self._tick_exchange(xs, cap, rows)
-            with _trace.span("collective.join.exchange", side=side,
-                             input_capacity=cap, capacity=cap,
-                             rows=rows, rounds=len(rounds),
-                             row_bytes=width):
-                return prog(xs)
+        def exchanged(rounds, side: str, route):
+            return self._route_counted(
+                rounds, jkey + (side,), route,
+                "collective.join.exchange", side=side)
 
         with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
             build_rounds = S.pad_rounds_pow2(
                 list(self._shard_rounds(self.children[1])),
                 self.children[1].schema, n)
-            bprog = S.make_exchange_scan_stage(
-                self.mesh, jkey + ("build",), build_body,
-                len(build_rounds), op=self.name, donate=True)
-            ys_b = exchanged(bprog, build_rounds, "build")
-            bcounts = S.stage_counts(ys_b)
-            shrunk_b = S.shrink_rounds(ys_b, bcounts, mesh=self.mesh)
+            rounds_b, bcounts = exchanged(build_rounds, "build",
+                                          self._route_build)
             self.metrics["buildRows"].add(int(bcounts.sum()))
-            build_rows = int(bcounts.sum(axis=0).max()) \
-                if bcounts.size else 0
-            rounds_b = S.pad_rounds_pow2(
-                shrunk_b, self.children[1].schema, n)
             btail = S.make_stage_tail(
                 self.mesh, jkey + ("buildfold",), lambda b: b,
                 len(rounds_b), op=self.name, donate=True)
@@ -616,14 +649,8 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
             def run_bucket(bucket):
                 bucket = S.pad_rounds_pow2(bucket,
                                            self.children[0].schema, n)
-                rprog = S.make_exchange_scan_stage(
-                    self.mesh, jkey + ("stream",), stream_body,
-                    len(bucket), op=self.name, donate=True)
-                ys = exchanged(rprog, bucket, "stream")
-                counts2 = S.stage_counts(ys)
-                rounds2 = S.pad_rounds_pow2(
-                    S.shrink_rounds(ys, counts2, mesh=self.mesh),
-                    self.children[0].schema, n)
+                rounds2, counts2 = exchanged(bucket, "stream",
+                                             self._route_stream)
                 xs2 = S.shard_stack_rounds(rounds2, self.mesh)
                 # probe out-capacity from the LIVE routed maximum, not
                 # the padded round capacity or the whole build side:
@@ -633,7 +660,7 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
                 # max(cap, build_rows) guess at 0.505x per device).
                 # An undershoot is safe: the totals check below
                 # re-buckets and re-dispatches at the true capacity.
-                live_max = int(counts2.max()) if counts2.size else 0
+                live_max = int(counts2.max())
                 cap_guess = 64 if semi_anti else pad_capacity(
                     max(live_max, 64))
                 while True:
@@ -659,18 +686,15 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
                 for d in range(n):
                     chunks[d].extend(per[d])
 
+            # `_shard_rounds` always yields a round, so a bucket runs
             bucket: list = []
-            any_bucket = False
             for shards in self._shard_rounds(self.children[0]):
                 bucket.append(shards)
                 if len(bucket) == self.bucket_rounds:
                     run_bucket(bucket)
-                    any_bucket = True
                     bucket = []
-            if bucket or not any_bucket:
-                run_bucket(bucket or [
-                    [ColumnarBatch.empty(self.children[0].schema)
-                     for _ in range(n)]])
+            if bucket:
+                run_bucket(bucket)
         return chunks
 
 
@@ -912,13 +936,7 @@ class TpuCollectiveWindowExec(_CollectiveBase):
         Like the sort, the stage ignores bucketRounds: a partition's
         rows may sit in any round, so every round is resident while
         the route program runs."""
-        import numpy as np
-
         from spark_rapids_tpu.parallel import spmd as S
-        from spark_rapids_tpu.parallel.exchange import (
-            destination_counts,
-            route_shard,
-        )
 
         child = self.children[0]
         n = self.num_partitions
@@ -927,30 +945,9 @@ class TpuCollectiveWindowExec(_CollectiveBase):
         with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
             rounds = S.pad_rounds_pow2(
                 list(self._shard_rounds(child)), child.schema, n)
-            xs = S.shard_stack_rounds(rounds, self.mesh)
-            count = S.make_scan_stage(
-                "spmdroutecount", self.mesh, wkey,
-                lambda b: destination_counts(b, self._route(b), n),
-                len(rounds), op=self.name)
-            sent = S.fetch(count(xs))  # [round, source, destination]
-            slot = pad_capacity(int(sent.max()))
-            route = S.make_exchange_scan_stage(
-                self.mesh, wkey + (slot,),
-                lambda b: route_shard(b, self._route(b), n, DATA_AXIS,
-                                      slot),
-                len(rounds), op=self.name, donate=True,
+            rounds2, received = self._route_counted(
+                rounds, wkey, self._route, "collective.window.exchange",
                 tag="spmdwinroute")
-            rows = int(sent.sum())
-            width = self._tick_exchange(xs, slot, rows)
-            with _trace.span("collective.window.exchange",
-                             input_capacity=_grid_capacity(rounds),
-                             capacity=slot, rows=rows,
-                             rounds=len(rounds), row_bytes=width):
-                routed = route(xs)
-            received = sent.sum(axis=1).astype(np.int32)
-            rounds2 = S.pad_rounds_pow2(
-                S.shrink_rounds(routed, received, mesh=self.mesh),
-                child.schema, n)
             tail = S.make_stage_tail(
                 self.mesh, wkey, self._win._window_batch, len(rounds2),
                 op=self.name, donate=True)

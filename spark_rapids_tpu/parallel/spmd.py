@@ -345,10 +345,11 @@ def shrink_rounds(batch: ColumnarBatch,
     """THE mid-stage shrink: split a (R, n, capacity, ...) exchange
     program output into a rectangular rounds[r][d] grid of shrunk
     batches (empty rounds kept), using ONE stage-exit counts fetch.
-    The exchange program's outputs carry the worst-case n x cap
-    receive capacity per shard; shrinking here — once per stage, not
-    once per round — is what keeps the tail program's merge/sort/join
-    work proportional to the LIVE rows instead of the padding.  Under
+    The exchange program's outputs carry the receive capacity per
+    shard, n x the send slot's (the worst-case n x cap where nothing
+    was counted); shrinking here — once per stage, not once per round
+    — is what keeps the tail program's merge/sort/join work
+    proportional to the LIVE rows instead of the padding.  Under
     mesh serving each shard column adopts its mesh device here, so the
     tail program's re-assembly finds every piece device-born."""
     if counts is None:

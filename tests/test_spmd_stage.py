@@ -410,9 +410,9 @@ def _agg_node(exec_):
                 if type(nd).__name__ == "TpuCollectiveHashAggregateExec")
 
 
-def _traced_collect(exec_):
-    """collect_exec under the tracer: (answer, the stage's
-    `collective.agg.exchange` span attrs, one dict a bucket)."""
+def _traced_collect(exec_, span: str = "collective.agg.exchange"):
+    """collect_exec under the tracer: (answer, the attrs of the stage's
+    `span`s, one dict a dispatch of its exchange program)."""
     from spark_rapids_tpu import trace
     from spark_rapids_tpu.plan.planner import collect_exec
 
@@ -420,8 +420,7 @@ def _traced_collect(exec_):
     trace.clear()
     try:
         got = collect_exec(exec_)
-        spans = [e.attrs for e in trace.snapshot()
-                 if e.name == "collective.agg.exchange"]
+        spans = [e.attrs for e in trace.snapshot() if e.name == span]
     finally:
         trace.disable()
         trace.clear()
@@ -557,6 +556,226 @@ def test_spmd_agg_string_keys_with_nulls_digest(collective_session,
     groups = {(a, b) for a, b in zip(k1, k2)}
     assert {(r[1], r[2]) for r in rows} == groups
     assert sum(r[0] for r in rows) == 2000
+
+
+# ------------------------------------------------------------------ #
+# The join stage: both sides leave through counted send slots
+# ------------------------------------------------------------------ #
+
+JOIN_DEV = 4
+BROADCAST_KEY = "spark.rapids.tpu.sql.autoBroadcastJoinThresholdBytes"
+
+
+@pytest.fixture
+def mesh4_session():
+    s = TpuSession()
+    s.enable_collective_shuffle(JOIN_DEV)
+    yield s
+    s.disable_collective_shuffle()
+
+
+def _join_node(exec_):
+    return next(nd for nd in exec_._walk()
+                if type(nd).__name__ == "TpuCollectiveHashJoinExec")
+
+
+def _join_tables(same_key: bool = False) -> tuple:
+    """2,048 stream rows against 512 build rows with distinct keys (no
+    stream row matches twice, so the probe's guess never overflows);
+    `same_key`: 512 against 16, every key of both sides 7."""
+    rng = np.random.default_rng(41)
+    if same_key:
+        lk, rk = np.full(512, 7), np.full(16, 7)
+    else:
+        lk, rk = rng.integers(0, 3000, 2048), rng.permutation(3000)[:512]
+    return (pa.table({"k": lk.astype(np.int64),
+                      "lv": np.arange(len(lk), dtype=np.int64)}),
+            pa.table({"k": rk.astype(np.int64),
+                      "rv": np.arange(len(rk), dtype=np.int64)}))
+
+
+def _join_query(session, conf, lt: pa.Table, rt: pa.Table,
+                batch_rows: int, how: str = "inner"):
+    """A shuffled join whose rounds are one batch each: a round closes
+    once one shard holds `roundRows`, so with the batch as large every
+    round is one loaded shard beside three empty ones (what the
+    four-chip cells run), four rounds a stream bucket."""
+    conf.set(BROADCAST_KEY, -1)
+    conf.set(ROUND_KEY, batch_rows)
+    conf.set(BATCH_KEY, batch_rows)
+    conf.set(BUCKET_KEY, 4)
+    return session.create_dataframe(lt).join(
+        session.create_dataframe(rt), on="k", how=how)
+
+
+def _one_device(df, conf) -> pa.Table:
+    conf.set(TRANSPORT_KEY, "local")
+    try:
+        return df.collect(engine="tpu")
+    finally:
+        conf.set(TRANSPORT_KEY, "collective")
+
+
+def _counted_slots(node) -> dict:
+    """side -> the slot capacity each of its exchanges must leave at,
+    reckoned on the host: the side's own `_shard_rounds` staging cut
+    into the stage's buckets, every staged batch's partition ids, the
+    largest (round, source, destination) count of a bucket padded."""
+    from spark_rapids_tpu.columnar.column import pad_capacity
+
+    def largest(rounds, route) -> int:
+        worst = 0
+        for shards in rounds:
+            for b in shards:
+                rows = b.concrete_num_rows()
+                pid = np.asarray(route(b))[:rows]
+                worst = max(worst, int(np.bincount(
+                    pid, minlength=JOIN_DEV).max()))
+        return pad_capacity(worst)
+
+    build = list(node._shard_rounds(node.children[1]))
+    stream = list(node._shard_rounds(node.children[0]))
+    step = node.bucket_rounds
+    return {"build": [largest(build, node._route_build)],
+            "stream": [largest(stream[i:i + step], node._route_stream)
+                       for i in range(0, len(stream), step)]}
+
+
+def test_spmd_join_exchange_sized_to_counted_slots(mesh4_session,
+                                                   conf_sandbox):
+    """Both sides of the join stage leave through send slots of the
+    capacity the largest COUNTED (source, destination) rows pad to, not
+    of the input's: the `collective.join.exchange` span says both, and
+    `collectiveBytes` is what the all_to_alls were sized to carry."""
+    from spark_rapids_tpu.plan.planner import plan_query
+
+    lt, rt = _join_tables()
+    df = _join_query(mesh4_session, conf_sandbox, lt, rt, 256)
+    twin, _ = plan_query(df._plan, mesh4_session.conf)
+    want_slots = _counted_slots(_join_node(twin))
+
+    exec_, _ = plan_query(df._plan, mesh4_session.conf)
+    got, spans = _traced_collect(exec_, "collective.join.exchange")
+    by_side = {side: [s for s in spans if s["side"] == side]
+               for side in ("build", "stream")}
+    assert len(by_side["build"]) == 1 and len(by_side["stream"]) == 2
+    for side, held in by_side.items():
+        assert [s["capacity"] for s in held] == want_slots[side], held
+        for s in held:
+            assert s["capacity"] < s["input_capacity"] == 256, s
+            assert s["row_bytes"] == 18, s  # two int64 and validity
+    metrics = _join_node(exec_).metrics
+    assert metrics["collectiveBytes"].value == sum(
+        s["rounds"] * JOIN_DEV * JOIN_DEV * s["capacity"] * s["row_bytes"]
+        for s in spans)
+    assert metrics["collectiveRows"].value == lt.num_rows + rt.num_rows
+    assert sum(s["rows"] for s in spans) == lt.num_rows + rt.num_rows
+    assert metrics["buildRows"].value == rt.num_rows
+    assert got.num_rows > 0
+    assert _canon(got) == _canon(_one_device(df, conf_sandbox))
+
+
+def test_spmd_join_exchange_stands_aside_for_one_destination(
+        mesh4_session, conf_sandbox):
+    """Every key equal: a round's rows all leave for one destination,
+    the counted slot IS the input's capacity and the exchange runs at
+    the size it always did — no shape test, no knob, same answer."""
+    from spark_rapids_tpu.plan.planner import plan_query
+
+    lt, rt = _join_tables(same_key=True)
+    df = _join_query(mesh4_session, conf_sandbox, lt, rt, 128)
+    exec_, _ = plan_query(df._plan, mesh4_session.conf)
+    got, spans = _traced_collect(exec_, "collective.join.exchange")
+    assert {s["side"] for s in spans} == {"build", "stream"}, spans
+    for s in spans:
+        if s["side"] == "stream":
+            assert s["capacity"] == s["input_capacity"] == 128, s
+        else:  # 16 rows of a batch of 16
+            assert s["capacity"] == s["input_capacity"] == 16, s
+    assert got.num_rows == lt.num_rows * rt.num_rows
+    assert _canon(got) == _canon(_one_device(df, conf_sandbox))
+
+
+@pytest.mark.parametrize("how", ["inner", "left_outer", "left_semi",
+                                 "left_anti"])
+def test_spmd_join_null_keys_and_strings_digest(mesh4_session,
+                                                conf_sandbox, how):
+    """NULL keys on both sides (they match nothing, and the outer and
+    anti joins keep the stream's), a string column a side, repeated
+    keys, two rounds a bucket: the counted exchange keeps sender order,
+    so two collective collects are identical row for row, and equal to
+    the one-device and the CPU engine's answers as sets of rows."""
+    rng = np.random.default_rng(43)
+    words = np.array(["", "a", "Ünï", "delta-long-value", "zz"])
+
+    def keys(n, hi):
+        return pa.array([None if x == hi else x
+                         for x in rng.integers(0, hi + 1, n).tolist()],
+                        pa.int64())
+
+    lt = pa.table({"k": keys(600, 40),
+                   "ls": pa.array(words[rng.integers(0, 5, 600)].tolist(),
+                                  pa.string()),
+                   "lv": np.arange(600, dtype=np.int64)})
+    rt = pa.table({"rk": keys(90, 60),
+                   "rs": pa.array([None if x == 4 else str(words[x])
+                                   for x in rng.integers(0, 5, 90)],
+                                  pa.string())})
+    conf_sandbox.set(BROADCAST_KEY, -1)
+    conf_sandbox.set(ROUND_KEY, 64)
+    conf_sandbox.set(BATCH_KEY, 64)
+    conf_sandbox.set(BUCKET_KEY, 2)
+
+    def q(s):
+        return s.create_dataframe(lt).join(
+            s.create_dataframe(rt), left_on=[col("k")],
+            right_on=[col("rk")], how=how)
+
+    mesh_answer, single = _collect_both(mesh4_session, q, conf_sandbox)
+    again = q(mesh4_session).collect(engine="tpu")
+    assert mesh_answer.to_pydict() == again.to_pydict()
+    assert _canon(mesh_answer) == _canon(single)
+    assert _canon(mesh_answer) == _canon(
+        q(mesh4_session).collect(engine="cpu"))
+    null_keys = sum(k is None for k in lt["k"].to_pylist())
+    assert null_keys > 0
+    kept = sum(k is None for k in mesh_answer["k"].to_pylist())
+    assert kept == (null_keys if how in ("left_outer", "left_anti")
+                    else 0)
+
+
+def test_spmd_join_stage_dispatch_budget(mesh4_session, conf_sandbox):
+    """Eight stream rounds, O(1) dispatches a side and bucket: a count
+    and a route program each, the build side's fold and a probe a
+    bucket — the rounds run inside the programs' scans."""
+    from spark_rapids_tpu.plan.planner import collect_exec, plan_query
+    from spark_rapids_tpu.trace import ledger
+
+    lt, rt = _join_tables()
+    df = _join_query(mesh4_session, conf_sandbox, lt, rt, 256)
+    exec_, _ = plan_query(df._plan, mesh4_session.conf)
+    assert "stage=spmd(bucket=4)" in exec_.tree_string()
+
+    ledger.enable()
+    ledger.reset_stats()
+    try:
+        got = collect_exec(exec_)
+        ledger.LEDGER.flush(timeout=10.0)
+        snap = _collective_programs(ledger.snapshot())
+        rounds = _join_node(exec_).metrics["collectiveRounds"].value
+        by_tag: dict = {}
+        for p in snap.values():
+            by_tag[p["tag"]] = by_tag.get(p["tag"], 0) + p["dispatches"]
+        # 2 build rounds, 8 stream rounds in 2 buckets
+        assert rounds == 10, rounds
+        assert by_tag == {"spmdroutecount": 3, "spmdxchg": 3,
+                          "spmdtail": 1, "spmdjoin": 2}, snap
+        assert all(p["devices"] == JOIN_DEV for p in snap.values()), snap
+        assert max(p["rounds"] for p in snap.values()) == 4, snap
+    finally:
+        ledger.disable()
+        ledger.reset_stats()
+    assert _canon(got) == _canon(_one_device(df, conf_sandbox))
 
 
 def test_spmd_explain_shows_stage_decision(collective_session,
