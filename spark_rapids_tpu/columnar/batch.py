@@ -378,107 +378,88 @@ def concat_batches_traced(batches: Sequence[ColumnarBatch]
     return stacked.compact(keep)
 
 
+def _place(out: jax.Array, piece: jax.Array, n: jax.Array,
+           off: jax.Array) -> jax.Array:
+    """`piece` written whole into `out` from row `off`, its rows from
+    `n` on zeroed."""
+    live = jnp.arange(piece.shape[0], dtype=jnp.int32) < n
+    live = live.reshape((-1,) + (1,) * (piece.ndim - 1))
+    piece = jnp.where(live, piece, jnp.zeros((), piece.dtype))
+    return jax.lax.dynamic_update_slice(
+        out, piece, (off,) + (jnp.zeros((), off.dtype),) * (piece.ndim - 1))
+
+
+def _pack(pieces: list, ns: list[int], cap: int) -> jax.Array:
+    """The first `ns[i]` rows of each piece, one after another, in an
+    array of `cap` rows that is zero after them.
+
+    No shape here follows a row count, so the same programs serve
+    whatever the counts are (a slice of exactly `n` rows is a program
+    of its own for every `n`: the pieces an exchange hands its reduce
+    side have another count in every partition and under every seed).
+    Each piece is written whole, in order, so a later piece overwrites
+    the zeroed rows an earlier one left over its place; the buffer has
+    room past `cap` for the longest piece written at the last row."""
+    from spark_rapids_tpu.execs.jit_cache import cached_jit
+
+    place = cached_jit(("concat_place",), lambda: _place, op="Concat")
+    room = max((p.shape[0] for p, n in zip(pieces, ns) if n), default=0)
+    out = jnp.zeros((cap + room,) + pieces[0].shape[1:], pieces[0].dtype)
+    off = 0
+    for p, n in zip(pieces, ns):
+        if n:
+            out = place(out, p, np.int32(n), np.int32(off))
+            off += n
+    return out[:cap]
+
+
 def _concat_cols(parts: list, ns: list[int], cap: int,
                  dtype: T.DataType) -> AnyColumn:
     """Concatenate column parts into one capacity-`cap` column
     (recursive for nested types)."""
     f = T.Field("_", dtype)
+    valid = _pack([p.validity for p in parts], ns, cap)
     if isinstance(f.dtype, T.StructType):
-        valid = jnp.zeros(cap, jnp.bool_)
-        off = 0
-        for p, n in zip(parts, ns):
-            if n == 0:
-                continue
-            valid = jax.lax.dynamic_update_slice(
-                valid, p.validity[:n], (off,))
-            off += n
         kids = tuple(
             _concat_cols([p.children[i] for p in parts], ns, cap,
                          cf.dtype)
             for i, cf in enumerate(f.dtype.fields))
         return StructColumn(kids, valid, f.dtype)
+
+    def widened(arrays: list, width: int) -> list:
+        """Second axis padded to `width` (a map's or list's longest
+        entry count, a string's byte width)."""
+        return [jnp.pad(a, ((0, 0), (0, width - a.shape[1])))
+                if a.shape[1] < width else a for a in arrays]
+
+    def lengths() -> jax.Array:
+        return _pack([p.lengths.astype(jnp.int32) for p in parts], ns, cap)
+
     if isinstance(f.dtype, T.MapType):
         kphys = T.to_numpy_dtype(f.dtype.key)
         vphys = T.to_numpy_dtype(f.dtype.value)
         L = max(p.max_len for p in parts)
-        keys = jnp.zeros((cap, L), kphys)
-        values = jnp.zeros((cap, L), vphys)
-        evalid = jnp.zeros((cap, L), jnp.bool_)
-        lengths = jnp.zeros(cap, jnp.int32)
-        valid = jnp.zeros(cap, jnp.bool_)
-        off = 0
-        for p, n in zip(parts, ns):
-            if n == 0:
-                continue
-            pk, pv, pe = p.keys[:n], p.values[:n], \
-                p.entry_validity[:n]
-            if p.max_len < L:
-                pad = ((0, 0), (0, L - p.max_len))
-                pk, pv, pe = (jnp.pad(x, pad) for x in (pk, pv, pe))
-            keys = jax.lax.dynamic_update_slice(keys, pk, (off, 0))
-            values = jax.lax.dynamic_update_slice(values, pv,
-                                                  (off, 0))
-            evalid = jax.lax.dynamic_update_slice(evalid, pe,
-                                                  (off, 0))
-            lengths = jax.lax.dynamic_update_slice(
-                lengths, p.lengths[:n].astype(jnp.int32), (off,))
-            valid = jax.lax.dynamic_update_slice(
-                valid, p.validity[:n], (off,))
-            off += n
-        return MapColumn(keys, values, evalid, lengths, valid,
-                         f.dtype)
+        return MapColumn(
+            _pack(widened([p.keys.astype(kphys) for p in parts], L),
+                  ns, cap),
+            _pack(widened([p.values.astype(vphys) for p in parts], L),
+                  ns, cap),
+            _pack(widened([p.entry_validity for p in parts], L), ns, cap),
+            lengths(), valid, f.dtype)
     if isinstance(f.dtype, T.ListType):
         phys = T.to_numpy_dtype(f.dtype.element)
         L = max(p.max_len for p in parts)  # type: ignore[union-attr]
-        values = jnp.zeros((cap, L), phys)
-        lengths = jnp.zeros(cap, jnp.int32)
-        evalid = jnp.zeros((cap, L), jnp.bool_)
-        valid = jnp.zeros(cap, jnp.bool_)
-        off = 0
-        for p, n in zip(parts, ns):
-            if n == 0:
-                continue
-            pv, pe = p.values[:n], p.elem_validity[:n]
-            if p.max_len < L:
-                pv = jnp.pad(pv, ((0, 0), (0, L - p.max_len)))
-                pe = jnp.pad(pe, ((0, 0), (0, L - p.max_len)))
-            values = jax.lax.dynamic_update_slice(values, pv, (off, 0))
-            evalid = jax.lax.dynamic_update_slice(evalid, pe, (off, 0))
-            lengths = jax.lax.dynamic_update_slice(
-                lengths, p.lengths[:n].astype(jnp.int32), (off,))
-            valid = jax.lax.dynamic_update_slice(
-                valid, p.validity[:n], (off,))
-            off += n
-        return ListColumn(values, lengths, evalid, valid, f.dtype)
+        return ListColumn(
+            _pack(widened([p.values.astype(phys) for p in parts], L),
+                  ns, cap),
+            lengths(),
+            _pack(widened([p.elem_validity for p in parts], L), ns, cap),
+            valid, f.dtype)
     if isinstance(f.dtype, T.StringType):
         w = pad_width(max(p.width for p in parts))  # type: ignore[union-attr]
-        chars = jnp.zeros((cap, w), jnp.uint8)
-        lengths = jnp.zeros(cap, jnp.int32)
-        valid = jnp.zeros(cap, jnp.bool_)
-        off = 0
-        for p, n in zip(parts, ns):
-            if n == 0:
-                continue
-            pc = p.chars[:n]
-            if p.width < w:
-                pc = jnp.pad(pc, ((0, 0), (0, w - p.width)))
-            chars = jax.lax.dynamic_update_slice(chars, pc, (off, 0))
-            lengths = jax.lax.dynamic_update_slice(
-                lengths, p.lengths[:n].astype(jnp.int32), (off,))
-            valid = jax.lax.dynamic_update_slice(
-                valid, p.validity[:n], (off,))
-            off += n
-        return StringColumn(chars, lengths, valid)
+        return StringColumn(
+            _pack(widened([p.chars for p in parts], w), ns, cap),
+            lengths(), valid)
     phys = T.to_numpy_dtype(f.dtype)
-    data = jnp.zeros(cap, phys)
-    valid = jnp.zeros(cap, jnp.bool_)
-    off = 0
-    for p, n in zip(parts, ns):
-        if n == 0:
-            continue
-        data = jax.lax.dynamic_update_slice(
-            data, p.data[:n].astype(phys), (off,))
-        valid = jax.lax.dynamic_update_slice(
-            valid, p.validity[:n], (off,))
-        off += n
-    return Column(data, valid, f.dtype)
+    return Column(_pack([p.data.astype(phys) for p in parts], ns, cap),
+                  valid, f.dtype)

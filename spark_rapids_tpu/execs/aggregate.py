@@ -348,12 +348,15 @@ class TpuHashAggregateExec(TpuExec):
         return groupby_aggregate(partial, list(range(self.n_keys)),
                                  self.merge_specs, self.partial_schema)
 
-    def _merge_traced(self, partials: ColumnarBatch) -> ColumnarBatch:
-        """The merge program over the concatenated partials, under an
-        `agg.merge` span that says how many partial rows went in."""
+    def _merge_traced(self, partials: ColumnarBatch,
+                      pending: int) -> ColumnarBatch:
+        """The merge program over the `pending` partials, concatenated,
+        under an `agg.merge` span that says how many partial rows went
+        in."""
         rows = partials.num_rows
         with _trace.span("agg.merge", capacity=partials.capacity,
                          rows=rows if isinstance(rows, int) else None,
+                         pending=pending,
                          path=_PATHS.get(self._path_keys[1])):
             return self._jit_merge(_as_device_rows(partials))
 
@@ -915,7 +918,7 @@ class TpuHashAggregateExec(TpuExec):
             def att():
                 if "b" not in state:
                     state["b"] = drain_pending(commit=False)
-                return self._merge_traced(state["b"])
+                return self._merge_traced(state["b"], len(pending))
 
             try:
                 merged = R.run_with_oom_retry(att, desc="agg.merge")
@@ -1094,7 +1097,7 @@ class TpuHashAggregateExec(TpuExec):
                     state["b"] = drain_pending(commit=False)
                 m = state["b"]
                 if not single or self.mode == "final":
-                    m = self._merge_traced(m)
+                    m = self._merge_traced(m, len(pending))
                 if self.mode == "partial":
                     return m
                 return self._jit_finalize(_as_device_rows(m))

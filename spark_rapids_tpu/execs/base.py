@@ -225,12 +225,14 @@ class MetricTimer:
     With `op` set (the owning exec's name) and tracing enabled, the
     timed region is also recorded as an ``exec.<op>`` span — the
     NvtxWithMetrics pairing: operators get timeline spans for free
-    wherever they already time themselves."""
+    wherever they already time themselves; `attrs` are said on that
+    span beside `op`."""
 
     def __init__(self, metric: Optional[TpuMetric],
-                 op: Optional[str] = None):
+                 op: Optional[str] = None, **attrs):
         self.metric = metric
         self.op = op
+        self.attrs = attrs
         self._observed = None
 
     def observe(self, out):
@@ -248,7 +250,7 @@ class MetricTimer:
             # reaper's metric.settle span)
             _trace.record_complete(
                 f"exec.{self.op}", self.t0,
-                time.perf_counter_ns() - self.t0, op=self.op)
+                time.perf_counter_ns() - self.t0, op=self.op, **self.attrs)
         if self.metric is None:
             return False
         if self._observed is not None and exc[0] is None \

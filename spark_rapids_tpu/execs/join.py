@@ -31,6 +31,7 @@ cannot express)."""
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 from typing import Iterator, Optional, Sequence
 
@@ -132,6 +133,8 @@ class _HashJoinBase(TpuExec):
 
     def additional_metrics(self):
         return [("buildRows", "MODERATE"), ("probeBatches", "MODERATE"),
+                ("streamRows", "MODERATE"),
+                ("unmatchedBuildRows", "MODERATE"),
                 ("specHits", "MODERATE"), ("specOverflows", "MODERATE")]
 
     @property
@@ -164,7 +167,8 @@ class _HashJoinBase(TpuExec):
         rows = b.concrete_num_rows()
         self.metrics["buildRows"].add(rows)
         _trace.event("join.build", op=self.name, rows=rows,
-                     capacity=b.capacity, batches=len(collected))
+                     capacity=b.capacity, batches=len(collected),
+                     join_type=self.join_type)
         return b
 
     def _empty_build(self) -> ColumnarBatch:
@@ -296,9 +300,19 @@ class _HashJoinBase(TpuExec):
             stream.compact(keep), op=self.name)
         matched_b_acc = None
         sizes_output = self.join_type not in ("left_semi", "left_anti")
-        pred = SP.predictor(self._cache_key() + ("sizing",)) \
-            if sizes_output and SP.speculation_enabled() \
-            and SP.tag_enabled("join.probe") else None
+        speculates = sizes_output and SP.speculation_enabled() \
+            and SP.tag_enabled("join.probe")
+
+        def predictor_of(stream):
+            """One predictor a stream capacity: what the host knows of
+            a batch before its count arrives.  Tasks of unlike sizes
+            (a scan split 10 files and 5) then each predict from their
+            own counts, and neither's bucket moves with the order the
+            tasks ran in."""
+            return SP.predictor(self._cache_key()
+                                + ("sizing", stream.capacity)) \
+                if speculates else None
+
         chunk = get_conf().get(JOIN_OUTPUT_CHUNK_ROWS)
         chunk_cap_ceiling = pad_capacity(chunk)
 
@@ -312,9 +326,13 @@ class _HashJoinBase(TpuExec):
             harvester — nothing in this batch waits on the link."""
             nonlocal matched_b_acc
             self.metrics["probeBatches"].add(1)
+            # deferred like every row metric: no readback here
+            self.metrics["streamRows"].add_lazy(stream.num_rows)
             out = None
             spec = None
-            with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
+            with MetricTimer(self.metrics[TOTAL_TIME], op=self.name,
+                             join_type=self.join_type,
+                             capacity=stream.capacity) as t:
                 stream = stream.with_device_num_rows()
                 st, total = jit_probe(build, stream)
                 if self.join_type == "full_outer":
@@ -327,6 +345,7 @@ class _HashJoinBase(TpuExec):
                     out = t.observe(jit_semi_compact(stream, keep))
                 else:
                     t.observe(total)
+                    pred = predictor_of(stream)
                     cap = pred.predict(cap_ceiling=chunk_cap_ceiling) \
                         if pred is not None else None
                     if cap is not None:
@@ -350,6 +369,7 @@ class _HashJoinBase(TpuExec):
             if out is not None:
                 yield self._count_output(out)
                 return
+            pred = predictor_of(stream)
             if fut is not None:
                 # usually free (harvested); a genuine stall on a
                 # backlogged harvester must still land in this
@@ -364,7 +384,7 @@ class _HashJoinBase(TpuExec):
             if pred is not None:
                 # a ladder re-run of a failed batch re-dispatches and
                 # observes the same count again (and may re-tick
-                # specHits/specOverflows): the EWMA skew is bounded to
+                # specHits/specOverflows): the predictor's skew is bounded to
                 # failure paths and re-observing the true count is
                 # harmless, so no cross-attempt dedup is attempted
                 pred.observe(n_total)
@@ -457,11 +477,25 @@ class _HashJoinBase(TpuExec):
             return ColumnarBatch(cols, compacted.num_rows, self._schema)
 
         from spark_rapids_tpu.execs.jit_cache import cached_jit
+        from spark_rapids_tpu.parallel import pipeline as P
 
-        out = cached_jit(self._cache_key() + ("unmatched",),
-                         lambda: unmatched,
-                         op=self.name)(build, matched_b)
-        if out.concrete_num_rows() > 0:
+        # the one readback sizes the batch (every operator above pays
+        # by capacity, and a side that mostly matched leaves few rows);
+        # under the span, and counted as the stage's readback, so that
+        # the chip's wait for it has a name
+        with _trace.span("join.unmatched", op=self.name,
+                         join_type=self.join_type,
+                         build_capacity=build.capacity) as sp, \
+                MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
+            out = cached_jit(self._cache_key() + ("unmatched",),
+                             lambda: unmatched,
+                             op=self.name)(build, matched_b)
+            rows = P.device_read_int(out.num_rows, tag="join.unmatched")
+            sp.note(rows=rows)
+            out = t.observe(dataclasses.replace(out, num_rows=rows)
+                            .shrink_to_capacity(pad_capacity(rows)))
+        self.metrics["unmatchedBuildRows"].add(rows)
+        if rows:
             yield self._count_output(out)
 
 

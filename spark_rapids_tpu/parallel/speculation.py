@@ -13,10 +13,11 @@ this shape-driven sync: JoinGatherer sizes output chunks from a target
 
 This module is that pattern for sizing:
 
-- :class:`SizePredictor` — per-program-key EWMA of observed output
-  counts (keyed by the same structural key ``jit_cache.cached_jit``
-  uses), scaled by a safety factor and clamped to pow2 capacity
-  buckets, with a conservative sync-on-first-batches warm-up;
+- :class:`SizePredictor` — per program key, the largest of the last
+  observed output counts (keyed by the same structural key
+  ``jit_cache.cached_jit`` uses), scaled by a safety factor and
+  clamped to pow2 capacity buckets, with a conservative
+  sync-on-first-batches warm-up;
 - the exec dispatches its expansion/gather at the SPECULATED bucket
   immediately and harvests the true count asynchronously
   (``parallel.pipeline.device_read_async``);
@@ -49,7 +50,7 @@ SPECULATION_ENABLED = register(
     "spark.rapids.tpu.sql.speculation.enabled", True,
     "Enable speculative output sizing: joins/aggregates/exchanges "
     "dispatch their output expansion at a predicted pow2 capacity "
-    "bucket (per-program-key EWMA of observed counts) and harvest the "
+    "bucket (per program key, from the largest recent count) and harvest the "
     "true count asynchronously, instead of blocking on a per-batch "
     "device->host sizing readback (the JoinGatherer guess-then-recover "
     "shape, ref: JoinGatherer.scala:55).  Undershoots emit "
@@ -66,13 +67,13 @@ SPECULATION_WARMUP_BATCHES = register(
     "spark.rapids.tpu.sql.speculation.warmupBatches", 1,
     "Observed batches per program key before the predictor speculates; "
     "warm-up batches pay the conservative blocking sizing sync and "
-    "seed the EWMA.",
+    "seed the predictor.",
     check=lambda v: v >= 1)
 
 SPECULATION_TEST_FORCE_CAPACITY = register(
     "spark.rapids.tpu.sql.speculation.testForceCapacity", 0,
     "Test aid: when > 0, a warmed-up predictor returns exactly this "
-    "capacity bucket instead of its EWMA-derived one (forces the "
+    "capacity bucket instead of its observed one (forces the "
     "under-/over-speculation paths deterministically).",
     internal=True)
 
@@ -84,7 +85,7 @@ SPECULATION_ADAPTIVE_MIN_HIT_RATE = register(
     "auto-DISABLED for the rest of the process (or until "
     "reset_stats) — its execs revert to the conservative blocking "
     "sizing sync.  BISECT_q3_r07's conviction: a workload whose output "
-    "counts the EWMA cannot track pays continuation chunks on every "
+    "counts the predictor cannot track pays continuation chunks on every "
     "batch, and turning speculation off recovered 1.294x on q3.  The "
     "disable lands as a speculation.disabled event-log counter and a "
     "speculation.disabled trace instant; 0.0 = never disable.",
@@ -98,10 +99,16 @@ SPECULATION_ADAPTIVE_WINDOW = register(
     "one unlucky warm-up batch cannot convict a tag.",
     check=lambda v: v >= 2)
 
-#: EWMA step: ~4 batches of memory — fast enough to track a selectivity
-#: shift mid-stream, slow enough that one outlier batch does not thrash
-#: the bucket choice
-_EWMA_ALPHA = 0.4
+#: observations a predictor remembers.  It predicts from the LARGEST
+#: of them: a function of which counts were seen and not of the order
+#: they came in, so a query run again predicts what it predicted the
+#: round before whatever order its tasks ran in, and compiles no new
+#: expansion.  (An average leaning to the newest count moves with that
+#: order, and crosses a bucket's edge from round to round where two
+#: tasks' counts lie either side of it.)  Long enough to hold a round
+#: of one operator's batches, short enough to follow a shift of
+#: selectivity
+_WINDOW = 16
 
 
 def speculation_enabled(conf=None) -> bool:
@@ -110,24 +117,23 @@ def speculation_enabled(conf=None) -> bool:
 
 
 class SizePredictor:
-    """EWMA of observed output counts for ONE program key.  Thread-safe:
-    partition-wise joins and exchange map tasks observe concurrently."""
+    """The last `_WINDOW` observed output counts of ONE program key,
+    and their largest.  Thread-safe: partition-wise joins and exchange
+    map tasks observe concurrently."""
 
-    __slots__ = ("key", "ewma", "observations", "_lock")
+    __slots__ = ("key", "recent", "observations", "_lock")
 
     def __init__(self, key):
         self.key = key
-        self.ewma = 0.0
+        self.recent: "collections.deque" = collections.deque(
+            maxlen=_WINDOW)
         self.observations = 0
         self._lock = threading.Lock()
 
     def observe(self, n: int) -> None:
         with self._lock:
             self.observations += 1
-            if self.observations == 1:
-                self.ewma = float(n)
-            else:
-                self.ewma += _EWMA_ALPHA * (float(n) - self.ewma)
+            self.recent.append(int(n))
 
     def predict(self, conf=None,
                 cap_ceiling: Optional[int] = None) -> Optional[int]:
@@ -137,14 +143,15 @@ class SizePredictor:
 
         conf = conf or get_conf()
         with self._lock:
-            obs, ewma = self.observations, self.ewma
+            obs = self.observations
+            largest = max(self.recent, default=0)
         if obs < int(conf.get(SPECULATION_WARMUP_BATCHES)):
             return None
         forced = int(conf.get(SPECULATION_TEST_FORCE_CAPACITY))
         if forced > 0:
             cap = pad_capacity(forced)
         else:
-            est = ewma * float(conf.get(SPECULATION_SAFETY_FACTOR))
+            est = largest * float(conf.get(SPECULATION_SAFETY_FACTOR))
             cap = pad_capacity(max(1, int(est)))
         if cap_ceiling is not None:
             cap = min(cap, cap_ceiling)

@@ -47,7 +47,7 @@ def test_predictor_warms_up_then_buckets():
     assert p.predict() is None  # warm-up: no observations
     p.observe(100)
     cap = p.predict()
-    # pow2 bucket of ewma(100) * safetyFactor(1.5) = 150 -> 256
+    # pow2 bucket of 100 * safetyFactor(1.5) = 150 -> 256
     assert cap == 256
     # ceiling clamp
     assert p.predict(cap_ceiling=64) == 64
@@ -68,7 +68,38 @@ def test_predictor_force_capacity_override():
     p = SP.predictor(("t", "k3"))
     assert p.predict() is None  # force does not bypass warm-up
     p.observe(100000)
-    assert p.predict() == 32  # pad_capacity(20), not the EWMA bucket
+    assert p.predict() == 32  # pad_capacity(20), not the observed bucket
+
+
+@pytest.mark.parametrize("order", [(30, 55, 30, 30, 55, 55),
+                                   (55, 30, 55, 55, 30, 30),
+                                   (30, 55, 55, 30, 30, 55)])
+def test_predictor_answers_alike_whatever_order_the_counts_came_in(order):
+    """Two tasks whose counts lie either side of a bucket's edge over
+    the safety factor (64 / 1.5 = 42.7): an average that leans to the
+    newest count answers 64 after two 30s and 128 after two 55s, and
+    each answer it had not given before is a new expansion program.
+    Once a round has shown both counts, the largest of those seen
+    answers 128 after any order."""
+    p = SP.predictor(("t", "order", order))
+    p.observe(order[0])
+    p.observe(order[1])
+    said = set()
+    for n in order[2:]:
+        said.add(p.predict())
+        p.observe(n)
+    assert said == {128}
+
+
+def test_predictor_forgets_counts_older_than_its_window():
+    p = SP.predictor(("t", "window"))
+    p.observe(1000)
+    assert p.predict() == 2048
+    for _ in range(SP._WINDOW - 1):
+        p.observe(10)
+    assert p.predict() == 2048  # the 1000 is the oldest it still holds
+    p.observe(10)
+    assert p.predict() == 16
 
 
 def test_predictor_shared_by_key():
