@@ -9,7 +9,10 @@ RequireSingleBatch), then every stream batch probes it through the dense
 group-id kernel in ops.join.  Output sizing mirrors JoinGatherer: one
 device->host sync per stream batch reads the pair count, then a
 statically-shaped expansion program (globally cached per capacity
-bucket) emits the joined batch.
+bucket) emits the joined batch at the bucket of the counted pairs, in
+chunks of `join.outputChunkRows`.  The join counts, then expands: it
+guesses no capacity (`expandRows` over `expandCapacityRows` is the
+fill, over a half).
 
 Three physical strategies (chosen by the planner, like GpuOverrides
 choosing BroadcastHashJoin vs ShuffledHashJoin by build-side size):
@@ -135,7 +138,10 @@ class _HashJoinBase(TpuExec):
         return [("buildRows", "MODERATE"), ("probeBatches", "MODERATE"),
                 ("streamRows", "MODERATE"),
                 ("unmatchedBuildRows", "MODERATE"),
-                ("specHits", "MODERATE"), ("specOverflows", "MODERATE")]
+                # pairs counted, and the capacities their expansion
+                # programs ran at, summed: the ratio is the fill
+                ("expandRows", "MODERATE"),
+                ("expandCapacityRows", "MODERATE")]
 
     @property
     def _build_child(self) -> TpuExec:
@@ -274,16 +280,13 @@ class _HashJoinBase(TpuExec):
         reference gets the same overlap from JoinGatherer's bounded
         gathers + the stream iterator's prefetch).
 
-        With SPECULATIVE SIZING on (parallel.speculation, the default),
-        even that readback leaves the critical path: the expansion for
-        batch k is dispatched at the predictor's capacity bucket inside
-        dispatch(k) itself — before anyone knows the true pair count —
-        and the count is harvested asynchronously.  retire(k) then only
-        reconciles: a hit yields the already-dispatched chunk, an
-        undershoot appends continuation chunks from offset=cap (the
-        expand_pairs live mask makes both safe; no rollback exists).
-        Steady state runs with ZERO blocking sizing readbacks; warm-up
-        batches pay the conservative sync and seed the predictor."""
+        The join COUNTS, THEN EXPANDS: retire(k) reads batch k's pair
+        count and only then dispatches its expansion, at the count's
+        own capacity bucket.  An expansion costs by the capacity it
+        runs at, not by its rows (0.33-0.36 us a row of capacity on a
+        v5e), so a bucket guessed ahead of the count and one too large
+        doubles the join's largest program; the readback it would
+        spare is some 2 ms behind the next batch's probe."""
         if build is None:
             if self.join_type in ("inner", "left_semi", "cross"):
                 return  # empty build: no output
@@ -291,7 +294,6 @@ class _HashJoinBase(TpuExec):
 
         from spark_rapids_tpu.execs.jit_cache import cached_jit
         from spark_rapids_tpu.parallel import pipeline as P
-        from spark_rapids_tpu.parallel import speculation as SP
 
         jit_probe = cached_jit(self._cache_key() + ("probe",),
                                lambda: self._probe, op=self.name)
@@ -300,36 +302,18 @@ class _HashJoinBase(TpuExec):
             stream.compact(keep), op=self.name)
         matched_b_acc = None
         sizes_output = self.join_type not in ("left_semi", "left_anti")
-        speculates = sizes_output and SP.speculation_enabled() \
-            and SP.tag_enabled("join.probe")
-
-        def predictor_of(stream):
-            """One predictor a stream capacity: what the host knows of
-            a batch before its count arrives.  Tasks of unlike sizes
-            (a scan split 10 files and 5) then each predict from their
-            own counts, and neither's bucket moves with the order the
-            tasks ran in."""
-            return SP.predictor(self._cache_key()
-                                + ("sizing", stream.capacity)) \
-                if speculates else None
-
         chunk = get_conf().get(JOIN_OUTPUT_CHUNK_ROWS)
-        chunk_cap_ceiling = pad_capacity(chunk)
 
         build = build.with_device_num_rows()
 
         def dispatch(stream):
             """Async half: probe dispatch (+ semi/anti compaction,
-            which needs no readback).  With a warmed-up predictor the
-            output expansion at the SPECULATED bucket is dispatched
-            here too, and the true pair count goes to the async
-            harvester — nothing in this batch waits on the link."""
+            which needs no readback)."""
             nonlocal matched_b_acc
             self.metrics["probeBatches"].add(1)
             # deferred like every row metric: no readback here
             self.metrics["streamRows"].add_lazy(stream.num_rows)
             out = None
-            spec = None
             with MetricTimer(self.metrics[TOTAL_TIME], op=self.name,
                              join_type=self.join_type,
                              capacity=stream.capacity) as t:
@@ -345,99 +329,47 @@ class _HashJoinBase(TpuExec):
                     out = t.observe(jit_semi_compact(stream, keep))
                 else:
                     t.observe(total)
-                    pred = predictor_of(stream)
-                    cap = pred.predict(cap_ceiling=chunk_cap_ceiling) \
-                        if pred is not None else None
-                    if cap is not None:
-                        o = self._jit_expand(cap)(
-                            build, stream, st, total,
-                            jnp.asarray(0, jnp.int32))
-                        if self.condition is not None:
-                            o = self._jit_condition(o)
-                        spec = (cap, t.observe(o))
-            fut = P.device_read_async(total, tag="join.probe") \
-                if spec is not None else None
-            return stream, st, total, out, spec, fut
+            return stream, st, total, out
 
         def retire(entry):
-            """Reconciliation half.  Speculated batches harvest the
-            (usually already-fetched) count and either yield the
-            in-flight chunk (hit) or continue from offset=cap
-            (undershoot).  Warm-up / speculation-off batches pay the
-            one blocking readback per stream batch, as before."""
-            stream, st, total, out, spec, fut = entry
+            """Sizing half: the one blocking readback a stream batch,
+            then the expansion in chunks sized by what it read."""
+            stream, st, total, out = entry
             if out is not None:
                 yield self._count_output(out)
                 return
-            pred = predictor_of(stream)
-            if fut is not None:
-                # usually free (harvested); a genuine stall on a
-                # backlogged harvester must still land in this
-                # operator's clock like the sync it replaced
-                with MetricTimer(self.metrics[TOTAL_TIME], op=self.name):
-                    n_total = int(fut.result())
-            else:
-                with MetricTimer(self.metrics[TOTAL_TIME], op=self.name):
-                    n_total = P.device_read_int(total, tag="join.probe")
-                if pred is not None:
-                    SP.record_sync("join.probe")
-            if pred is not None:
-                # a ladder re-run of a failed batch re-dispatches and
-                # observes the same count again (and may re-tick
-                # specHits/specOverflows): the predictor's skew is bounded to
-                # failure paths and re-observing the true count is
-                # harmless, so no cross-attempt dedup is attempted
-                pred.observe(n_total)
-            if not n_total:
-                if spec is not None:
-                    # sync-free even though the chunk is discarded
-                    self.metrics["specHits"].add(1)
-                    SP.record_hit("join.probe", spec[0], 0)
-                return
-            start = 0
-            if spec is not None:
-                cap, o = spec
-                if n_total <= cap:
-                    self.metrics["specHits"].add(1)
-                    SP.record_hit("join.probe", cap, n_total)
-                    # the chunk was sized by a prediction with room to
-                    # spare; now that the count is here, a bucket or
-                    # more too large is cut to the rows' own, because
-                    # every operator above pays by capacity (q67: an
-                    # Expand of nine times it)
-                    yield self._count_output(
-                        o.shrink_to_capacity(pad_capacity(n_total)))
-                    return
-                # undershoot: the speculated chunk covers [0, cap);
-                # continuation chunks pick up from there — expand_pairs
-                # is offset-windowed, so no work is redone or rolled
-                # back
-                self.metrics["specOverflows"].add(1)
-                SP.record_overflow("join.probe", cap, n_total)
-                yield self._count_output(o)
-                start = cap
-            out_cap = pad_capacity(min(n_total - start, chunk))
+            with MetricTimer(self.metrics[TOTAL_TIME], op=self.name):
+                n_total = P.device_read_int(total, tag="join.probe")
+            self.metrics["expandRows"].add(n_total)
             # target-size chunks, spillable between yields (ref:
             # JoinGatherer.scala:55,138 — output in bounded gathers,
-            # never one giant batch).  Each chunk's compute gets its
-            # own timed region so consumer time between yields never
-            # lands in this operator's clock.
-            for off in range(start, n_total, out_cap):
+            # never one giant batch); expand_pairs is offset-windowed,
+            # so a chunk redoes none of the one before.  Each chunk's
+            # compute gets its own timed region so consumer time
+            # between yields never lands in this operator's clock.
+            off = 0
+            while off < n_total:
+                out_cap = pad_capacity(min(n_total - off, chunk))
                 with MetricTimer(self.metrics[TOTAL_TIME], op=self.name):
                     o = self._jit_expand(out_cap)(
                         build, stream, st, total,
                         jnp.asarray(off, jnp.int32))
                     if self.condition is not None:
                         o = self._jit_condition(o)
+                self.metrics["expandCapacityRows"].add(out_cap)
+                if _trace.TRACER.enabled:
+                    _trace.event("join.expand", op=self.name,
+                                 join_type=self.join_type,
+                                 rows=min(n_total - off, out_cap),
+                                 capacity=out_cap, offset=off)
                 yield self._count_output(o)
+                off += out_cap
 
         # Batch-granular OOM split-and-retry (execs/retry.py): each
         # stream batch is one ladder unit.  dispatch failures carry
         # their error into the ladder as the first failure; retire
-        # failures discard the in-flight (possibly speculated) entry
-        # and RE-DISPATCH from the input batch — at the split size
-        # after a bisect, re-predicting through the live predictor, so
-        # no stale predictor capacity leaks into the retried chunks.
+        # failures discard the in-flight entry and RE-DISPATCH from
+        # the input batch — at the split size after a bisect.
         from spark_rapids_tpu.execs.retry import guarded_pipeline
 
         dispatch_guarded, retire_guarded = guarded_pipeline(

@@ -1,35 +1,35 @@
 """Speculative output sizing: predict data-dependent output counts so
-stream loops never block on a per-batch sizing readback.
+the aggregate's and the exchange's stream loops do not block on a
+per-batch sizing readback.
 
-The join-heavy Q3 and the grouped Q1 keep one structural
-serialization: the per-batch device->host SIZING sync (join pair count, aggregate partial
-row count, exchange split counts) that the software pipeline can only
-defer by a single batch — the expansion/shrink for batch k still waits
-on batch k's count before it can dispatch.  The reference never pays
-this shape-driven sync: JoinGatherer sizes output chunks from a target
-(ref: JoinGatherer.scala:55), and the OOM-retry framework
+The grouped Q1 and every hash exchange keep one structural
+serialization: the per-batch device->host SIZING sync (aggregate
+partial row count, exchange split counts) that the software pipeline
+can only defer by a single batch.  The OOM-retry framework
 (RmmRapidsRetryIterator.scala ``withRetry``, mirrored by
-``execs/retry.py``) is the repo's blessed "guess, then recover" shape.
-
-This module is that pattern for sizing:
+``execs/retry.py``) is the repo's blessed "guess, then recover" shape,
+and this module is that pattern for sizing:
 
 - :class:`SizePredictor` — per program key, the largest of the last
   observed output counts (keyed by the same structural key
   ``jit_cache.cached_jit`` uses), scaled by a safety factor and
   clamped to pow2 capacity buckets, with a conservative
   sync-on-first-batches warm-up;
-- the exec dispatches its expansion/gather at the SPECULATED bucket
-  immediately and harvests the true count asynchronously
-  (``parallel.pipeline.device_read_async``);
-- reconciliation is cheap by construction: ``ops/join.py``
-  ``expand_pairs(state, out_cap, offset)`` emits statically-shaped
-  chunks with a live mask, so an undershoot is not a rollback — the
-  exec emits continuation chunks from ``offset`` — and an overshoot
-  only costs masked dead rows (trimmed when chunks are
-  spilled/coalesced).
+- the aggregate registers a big partial unshrunk, runs its merge
+  bookkeeping on the predicted estimate and harvests the true count
+  asynchronously (``parallel.pipeline.device_read_async``); the drain
+  reconciles.  The exchange's map loop harvests its split counts the
+  same way and registers slices as they arrive.
+
+The JOIN does not speculate (PR 35): it reads each stream batch's pair
+count behind the next batch's probe and expands at the count's own
+bucket (``execs/join.py``).  An expansion costs by the capacity it runs
+at, and a bucket guessed at 1.5 x the count was one too large for every
+count over two thirds of its bucket: 0.4-1.3 s a join on a v5e against
+some 2 ms a readback.
 
 Hit/overflow counters feed ``bench.py``'s
-``q*_speculation_hit_rate`` fields and the per-exec
+``q*_speculation_hit_rate`` fields and the aggregate's
 ``specHits``/``specOverflows`` metrics shown by
 ``df.explain("analyze")``; ``speculation.hit``/``speculation.overflow``
 instants land on the structured trace timeline.  Docs:
@@ -48,19 +48,20 @@ from spark_rapids_tpu.parallel import pipeline as _P
 
 SPECULATION_ENABLED = register(
     "spark.rapids.tpu.sql.speculation.enabled", True,
-    "Enable speculative output sizing: joins/aggregates/exchanges "
-    "dispatch their output expansion at a predicted pow2 capacity "
-    "bucket (per program key, from the largest recent count) and harvest the "
-    "true count asynchronously, instead of blocking on a per-batch "
-    "device->host sizing readback (the JoinGatherer guess-then-recover "
-    "shape, ref: JoinGatherer.scala:55).  Undershoots emit "
-    "continuation chunks; overshoots only cost masked dead rows.")
+    "Enable speculative output sizing: aggregates keep a big partial "
+    "unshrunk and run their merge bookkeeping on a predicted row count "
+    "(per program key, from the largest recent count), exchanges "
+    "register their slices as split counts arrive; both harvest the "
+    "true count asynchronously instead of blocking on a per-batch "
+    "device->host sizing readback.  An overshoot costs dead padded "
+    "rows until the drain, an undershoot one merge a batch late.")
 
 SPECULATION_SAFETY_FACTOR = register(
     "spark.rapids.tpu.sql.speculation.safetyFactor", 1.5,
-    "Multiplier applied to the predicted output count before pow2 "
-    "bucket clamping.  Larger values trade dead padded rows for fewer "
-    "undershoot continuation chunks.",
+    "Multiplier applied to the largest recent output count before pow2 "
+    "bucket clamping.  Larger values trade dead padded rows in an "
+    "aggregate's pending partials for fewer undershoots (a merge "
+    "triggered a batch late).",
     check=lambda v: v >= 1.0)
 
 SPECULATION_WARMUP_BATCHES = register(
@@ -79,14 +80,15 @@ SPECULATION_TEST_FORCE_CAPACITY = register(
 
 SPECULATION_ADAPTIVE_MIN_HIT_RATE = register(
     "spark.rapids.tpu.sql.speculation.adaptive.minHitRate", 0.0,
-    "Adaptive kill-switch: when > 0, a predictor TAG (join.probe, "
-    "agg.size, ...) whose rolling hit rate over the last "
+    "Adaptive kill-switch: when > 0, a predictor TAG (agg.size) "
+    "whose rolling hit rate over the last "
     "speculation.adaptive.window outcomes falls below this is "
     "auto-DISABLED for the rest of the process (or until "
     "reset_stats) — its execs revert to the conservative blocking "
     "sizing sync.  BISECT_q3_r07's conviction: a workload whose output "
-    "counts the predictor cannot track pays continuation chunks on every "
-    "batch, and turning speculation off recovered 1.294x on q3.  The "
+    "counts the predictor cannot track pays for every miss, and turning "
+    "speculation off recovered 1.294x on q3 (through the join, which "
+    "has since stopped guessing).  The "
     "disable lands as a speculation.disabled event-log counter and a "
     "speculation.disabled trace instant; 0.0 = never disable.",
     check=lambda v: 0.0 <= v <= 1.0)
@@ -102,12 +104,11 @@ SPECULATION_ADAPTIVE_WINDOW = register(
 #: observations a predictor remembers.  It predicts from the LARGEST
 #: of them: a function of which counts were seen and not of the order
 #: they came in, so a query run again predicts what it predicted the
-#: round before whatever order its tasks ran in, and compiles no new
-#: expansion.  (An average leaning to the newest count moves with that
-#: order, and crosses a bucket's edge from round to round where two
-#: tasks' counts lie either side of it.)  Long enough to hold a round
-#: of one operator's batches, short enough to follow a shift of
-#: selectivity
+#: round before whatever order its tasks ran in.  (An average leaning
+#: to the newest count moves with that order, and crosses a bucket's
+#: edge from round to round where two tasks' counts lie either side of
+#: it.)  Long enough to hold a round of one operator's batches, short
+#: enough to follow a shift of selectivity
 _WINDOW = 16
 
 
@@ -118,8 +119,8 @@ def speculation_enabled(conf=None) -> bool:
 
 class SizePredictor:
     """The last `_WINDOW` observed output counts of ONE program key,
-    and their largest.  Thread-safe: partition-wise joins and exchange
-    map tasks observe concurrently."""
+    and their largest.  Thread-safe: an aggregate's tasks observe
+    concurrently."""
 
     __slots__ = ("key", "recent", "observations", "_lock")
 
@@ -251,8 +252,8 @@ def record_hit(tag: str, cap: int = 0, actual: int = 0) -> None:
 
 
 def record_overflow(tag: str, cap: int = 0, actual: int = 0) -> None:
-    """Undershoot: the speculated chunk was emitted, and the exec
-    continued with chunks from offset=cap (no rollback)."""
+    """Undershoot: the true count passed the estimate the exec ran
+    on (no rollback: the drain reconciles)."""
     with _STATS_LOCK:
         _stat(tag)["overflows"] += 1
         tripped = _observe_outcome_locked(tag, False)
@@ -274,7 +275,7 @@ def tag_enabled(tag: str) -> bool:
     """False once the adaptive kill-switch convicted this tag — the
     exec should skip predictor creation / speculation and pay the
     blocking sizing sync (which the kill-switch has just proven
-    cheaper than the continuation-chunk churn)."""
+    cheaper than the misses)."""
     with _STATS_LOCK:
         return tag not in _DISABLED
 

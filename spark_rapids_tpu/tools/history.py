@@ -58,9 +58,11 @@ SPILL_THRASH_BYTES = 32 << 20
 #: per-query compile-cache miss budget (a steady-state query should
 #: re-use programs; sustained misses mean shape-bucketing is broken)
 JIT_MISS_BUDGET = 16
-#: per-query blocking-readback budget (speculative sizing exists to
-#: drive the STEADY-STATE count to ~0; warm-up syncs, sort sample
-#: fetches and the final result fetch are legitimate, hence the slack)
+#: per-query blocking-readback budget (speculative sizing drives the
+#: aggregate's and the exchange's steady-state count to ~0; a join
+#: pays one a stream batch behind the next probe; warm-up syncs, sort
+#: sample fetches and the final result fetch are legitimate, hence
+#: the slack)
 BLOCKING_READBACK_BUDGET = 32
 #: pipeline occupancy below this, with real traffic, means stages ran
 #: starved/serial (the items floor keeps tiny unit-test-sized queries
@@ -562,8 +564,9 @@ def _hc_blocking_readbacks(q: QueryRecord) -> Optional[str]:
     r = q.counter("pipeline.readbacks")
     if r > BLOCKING_READBACK_BUDGET:
         return (f"{int(r)} blocking device->host readbacks (budget "
-                f"{BLOCKING_READBACK_BUDGET}) — speculative sizing is "
-                "not engaging (docs/speculation.md)")
+                f"{BLOCKING_READBACK_BUDGET}) — a join's stream is many "
+                "small batches, or speculative sizing is not engaging "
+                "(docs/speculation.md)")
     return None
 
 

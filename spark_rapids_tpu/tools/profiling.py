@@ -419,10 +419,11 @@ def render_analyze(ev: QueryEvent,
     busy - self (concurrent execution the aggregate timers hide).
     Span figures aggregate per operator CLASS (spans carry the exec
     name), so two instances of one class — a partial and a final
-    aggregate — show the class total on each.  Speculative-sizing
-    operators surface their `specHits`/`specOverflows` counters through
-    the regular metric annotations — a join showing only specHits ran
-    its stream loop sync-free.  `cache_stats` (a per-query
+    aggregate — show the class total on each.  The aggregate's
+    `specHits`/`specOverflows` and the join's `expandRows`/
+    `expandCapacityRows` (pairs counted over the capacities expanded
+    at: the fill) come through the regular metric annotations.
+    `cache_stats` (a per-query
     jit_cache.cache_stats() delta) appends the compile-cache hit
     rate.  `ledger` (a per-query `trace.ledger.summarize(delta)`,
     present when the device ledger is on) adds a per-operator

@@ -203,13 +203,10 @@ def _expected_rows(left: pa.Table, right: pa.Table):
 
 
 def test_join_stream_loop_one_readback_per_batch_with_lookahead():
-    """THE acceptance criterion (PR 2, the non-speculative pipelined
-    contract): at most one blocking device->host readback per stream
-    batch, and batch k's readback happens only after batch k+1's probe
-    is already dispatched.  Speculative sizing (which removes the
-    readback entirely; tests/test_speculation.py) is pinned OFF so the
-    deferred-readback ordering stays covered on its own."""
-    get_conf().set("spark.rapids.tpu.sql.speculation.enabled", False)
+    """THE acceptance criterion (PR 2, the pipelined contract): at
+    most one blocking device->host readback per stream batch, and
+    batch k's readback happens only after batch k+1's probe is already
+    dispatched."""
     join, left, right, n_batches = _join_exec()
     assert n_batches >= 4
     with P.trace_events() as events:

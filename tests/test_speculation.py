@@ -1,8 +1,8 @@
-"""Speculative output sizing (parallel/speculation.py): predictor
-contracts, CPU-parity of speculative joins across every join type at
-forced under/over-speculated capacities, speculative aggregate and
-exchange sizing, and THE acceptance test — zero blocking sizing
-readbacks on the steady-state portion of an inner join stream."""
+"""Output sizing: the predictor's contracts and the speculative
+aggregate and exchange sizing (parallel/speculation.py), and the join,
+which counts and then expands: its expansion's capacity is the bucket
+of the counted pairs, one blocking readback a stream batch behind the
+next batch's probe, on/off parity across every join type."""
 
 from __future__ import annotations
 
@@ -167,37 +167,94 @@ def test_join_speculative_parity_all_types(join_type):
         or sum(off.values()) == 0
 
 
+def _host_join(join_type, left, right) -> Counter:
+    """The plain reference: a dict of the build side's rows by key, a
+    loop over the stream side's; NULL keys match nothing."""
+    by_key: dict = {}
+    for k, w in zip(right["k"].to_pylist(), right["w"].to_pylist()):
+        by_key.setdefault(k, []).append(w)
+    out: Counter = Counter()
+    seen = set()
+    for k, v in zip(left["k"].to_pylist(), left["v"].to_pylist()):
+        ws = by_key.get(k, ()) if k is not None else ()
+        for w in ws:
+            out[(k, v, k, w)] += 1
+        if ws:
+            seen.add(k)
+        elif join_type != "inner":
+            out[(k, v, None, None)] += 1
+    if join_type == "full_outer":
+        for k, ws in by_key.items():
+            if k not in seen:
+                out.update((None, None, k, w) for w in ws)
+    return out
+
+
+def _expand_events(run) -> tuple:
+    """`run()`'s result and the attributes of its `join.expand`
+    events, in the order the chunks were dispatched."""
+    from spark_rapids_tpu import trace
+
+    trace.clear()
+    trace.enable()
+    try:
+        out = run()
+        return out, [s.attrs for s in trace.snapshot()
+                     if s.name == "join.expand"]
+    finally:
+        trace.disable()
+        trace.clear()
+
+
+@pytest.mark.parametrize("count", [600, 700, 1024, 1025])
+def test_join_expands_at_the_bucket_of_its_counted_pairs(count):
+    """Every stream row matches one build row, so a batch of `count`
+    rows counts `count` pairs: under two thirds of the 1,024 bucket
+    (600), over two thirds of it (700: 1.5 x the count is 1,050, which
+    a guess pads to 2,048), at its edge (1,024) and one past it.  The
+    expansion runs at `pad_capacity` of the count on the first batch
+    and on the later ones alike, and the tail batch at its own."""
+    from spark_rapids_tpu.columnar.column import pad_capacity
+
+    tail = 40
+    n = 2 * count + tail
+    left = pa.table({"k": np.arange(n, dtype=np.int64) % 50,
+                     "v": np.arange(n, dtype=np.int64)})
+    right = pa.table({"k": np.arange(50, dtype=np.int64),
+                      "w": np.arange(50, dtype=np.int64)})
+    ex = _join_exec("inner", left, right, batch_rows=count)
+    got, events = _expand_events(lambda: _rows(ex))
+    assert got == _host_join("inner", left, right)
+    assert [e["rows"] for e in events] == [count, count, tail]
+    assert [e["capacity"] for e in events] \
+        == [pad_capacity(count)] * 2 + [pad_capacity(tail)]
+    assert all(e["offset"] == 0 and e["join_type"] == "inner"
+               for e in events)
+    assert ex.metrics["expandRows"].value == n
+    assert ex.metrics["expandCapacityRows"].value \
+        == sum(e["capacity"] for e in events)
+    # the fill: over a half for any count over MIN_CAPACITY
+    assert 2 * n > ex.metrics["expandCapacityRows"].value
+
+
 @pytest.mark.parametrize("join_type", ("inner", "left_outer",
                                        "full_outer"))
-def test_join_forced_under_speculation_continuation(join_type):
-    """testForceCapacity far below the true pair count: every
-    speculated batch overflows and must emit continuation chunks from
-    offset=cap — same rows as speculation off."""
+def test_join_continuation_chunks_equal_a_host_join(join_type):
+    """`join.outputChunkRows` well under a batch's pair count (a 32-row
+    batch matches some 200 pairs): the expansion goes on in chunks of
+    64 from `offset`, the last at the bucket of what is left, and the
+    rows are the host reference's."""
+    get_conf().set("spark.rapids.tpu.sql.join.outputChunkRows", 64)
     left, right = _join_tables(n_stream=128, dup=8)
-    get_conf().set(ENABLED, True)
-    get_conf().set(FORCE, 8)  # each 32-row batch matches ~32*8 pairs
     ex = _join_exec(join_type, left, right)
-    on = _rows(ex)
-    assert ex.metrics["specOverflows"].value > 0, \
-        "forced under-speculation never took the continuation path"
-    get_conf().set(ENABLED, False)
-    off = _rows(_join_exec(join_type, left, right))
-    assert on == off
-
-
-def test_join_forced_over_speculation_masked_rows_trimmed():
-    """testForceCapacity far above the true count: every batch hits,
-    and the dead padded rows never reach the output."""
-    left, right = _join_tables(n_stream=128)
-    get_conf().set(ENABLED, True)
-    get_conf().set(FORCE, 1 << 14)
-    ex = _join_exec("inner", left, right)
-    on = _rows(ex)
-    assert ex.metrics["specHits"].value > 0
-    assert ex.metrics["specOverflows"].value == 0
-    get_conf().set(ENABLED, False)
-    off = _rows(_join_exec("inner", left, right))
-    assert on == off
+    got, events = _expand_events(lambda: _rows(ex))
+    assert got == _host_join(join_type, left, right)
+    assert len(events) > 2 * ex.metrics["probeBatches"].value
+    assert {e["capacity"] for e in events if e["rows"] == 64} == {64}
+    assert all(e["capacity"] < 2 * max(e["rows"], 8) for e in events)
+    assert any(e["offset"] >= 128 for e in events)
+    assert sum(e["rows"] for e in events) \
+        == ex.metrics["expandRows"].value
 
 
 @pytest.mark.parametrize("join_type", ("inner", "left_outer",
@@ -219,62 +276,42 @@ def test_join_empty_build_side(join_type):
         assert sum(on.values()) == 96  # every stream row preserved
 
 
-def test_join_warmup_batches_pay_the_sync():
-    """warmupBatches=3 with lookahead 1: the first 4 retires happen
-    before the predictor has 3 observations at dispatch time, so
-    exactly 4 blocking sizing readbacks; everything after speculates."""
-    get_conf().set(ENABLED, True)
-    get_conf().set(WARMUP, 3)
-    left, right = _join_tables(n_stream=320)
-    with P.trace_events() as events:
-        on = _rows(_join_exec("inner", left, right))
-    ev = [kind for kind, tag in events if tag == "join.probe"]
-    assert ev.count("readback") == 4
-    assert ev.count("spec_hit") + ev.count("spec_overflow") \
-        == ev.count("dispatch") - 4
-    get_conf().set(ENABLED, False)
-    off = _rows(_join_exec("inner", left, right))
-    assert on == off
-
-
-def test_join_steady_state_zero_blocking_sizing_readbacks(monkeypatch):
-    """THE acceptance criterion: with speculation on (the default),
-    the steady-state portion of an inner-join stream performs ZERO
-    blocking sizing readbacks — only the warm-up prefix (warmupBatches
-    + the lookahead window) pays the sync.
-
-    The harvest grace window is widened FOR THIS TEST ONLY: under
-    full-suite load the harvester thread can be preempted past the
-    25ms production grace, degrading one speculative retire into an
-    extra blocking readback — a CI scheduler stall, not a speculation
-    regression.  The wide window keeps this test measuring the
-    dispatch PROTOCOL (did the exec route sizing through a harvest
-    future?) instead of thread-scheduling noise; a real regression —
-    the exec syncing inline per batch — still fails, because the
-    warm-up readbacks it would multiply are inline device_read calls
-    that never touch the grace path."""
-    monkeypatch.setattr(P, "_HARVEST_GRACE_S", 2.0)
-    left, right = _join_tables(n_stream=480)
+def test_join_one_blocking_readback_a_batch_at_the_default_conf():
+    """The join counts, then expands: at the DEFAULT conf every stream
+    batch pays exactly one blocking `join.probe` readback, each after
+    the NEXT batch's probe was dispatched; nothing is harvested on the
+    side and nothing is guessed under that tag."""
     assert get_conf().get(ENABLED) is True  # the default
+    left, right = _join_tables(n_stream=320)
     ex = _join_exec("inner", left, right)
     with P.trace_events() as events:
         got = _rows(ex)
     ev = [kind for kind, tag in events if tag == "join.probe"]
     n_batches = ev.count("dispatch")
-    assert n_batches >= 10
-    # warm-up prefix: warmupBatches(1) + lookahead(1) blocking syncs
-    assert ev.count("readback") == 2, ev
-    # ... and they are all BEFORE the first speculative retire: the
-    # steady state is sync-free
-    first_spec = next(i for i, k in enumerate(ev)
-                      if k in ("spec_hit", "spec_overflow"))
-    assert all(k != "readback" for k in ev[first_spec:]), ev
-    # every steady-state batch resolved speculatively
-    assert ev.count("spec_hit") + ev.count("spec_overflow") \
-        == n_batches - 2
-    assert ex.metrics["specHits"].value \
-        + ex.metrics["specOverflows"].value == n_batches - 2
-    assert sum(got.values()) > 0
+    assert n_batches == 10
+    assert set(ev) == {"dispatch", "readback"}, ev
+    assert ev.count("readback") == n_batches
+    seen_d = seen_r = 0
+    for kind in ev:
+        if kind == "dispatch":
+            seen_d += 1
+        else:
+            seen_r += 1
+            assert seen_d >= min(seen_r + 1, n_batches), ev
+    assert "join.probe" not in SP.stats()
+    assert got == _host_join("inner", left, right)
+
+
+def test_join_second_pass_over_the_same_batches_compiles_nothing():
+    """A capacity is a function of the count, so the same batches ask
+    for the same programs: the second pass misses the cache nowhere."""
+    from spark_rapids_tpu.execs.jit_cache import cache_stats
+
+    left, right = _join_tables(n_stream=320, dup=3)
+    first = _rows(_join_exec("left_outer", left, right))
+    before = cache_stats()["misses"]
+    assert _rows(_join_exec("left_outer", left, right)) == first
+    assert cache_stats()["misses"] == before
 
 
 def test_join_speculation_off_trace_is_the_pr2_pattern():
@@ -302,6 +339,17 @@ def _agg_df(session, n=4096, keys=64):
             .group_by(col("k")).agg((sum_(col("v")), "sv")))
 
 
+def _per_batch_agg_sizing(monkeypatch) -> None:
+    """Put `_agg_df` on the aggregate's per-batch sizing path: no
+    partial is small enough to defer its count, sixteen batches, one
+    partition."""
+    from spark_rapids_tpu.execs import aggregate as agg_mod
+
+    monkeypatch.setattr(agg_mod, "_DEFER_SYNC_CAP", 0)
+    get_conf().set("spark.rapids.tpu.sql.batchSizeRows", 256)
+    get_conf().set("spark.rapids.tpu.sql.shuffle.partitions", 1)
+
+
 def _table_rows(tbl) -> list:
     return sorted(zip(*tbl.to_pydict().values()))
 
@@ -310,11 +358,7 @@ def test_aggregate_speculative_sizing_parity(session, monkeypatch):
     """Force the per-batch sizing path (capacity cap 0) on a grouped
     aggregate: speculative registration + async harvest + drain
     reconciliation must match speculation off exactly (integer sums)."""
-    from spark_rapids_tpu.execs import aggregate as agg_mod
-
-    monkeypatch.setattr(agg_mod, "_DEFER_SYNC_CAP", 0)
-    get_conf().set("spark.rapids.tpu.sql.batchSizeRows", 256)
-    get_conf().set("spark.rapids.tpu.sql.shuffle.partitions", 1)
+    _per_batch_agg_sizing(monkeypatch)
     df = _agg_df(session)
     get_conf().set(ENABLED, True)
     with P.trace_events() as events:
@@ -334,11 +378,7 @@ def test_aggregate_speculation_off_sizing_path_unchanged(session,
                                                          monkeypatch):
     """Kill switch: the sizing path pays its one blocking readback per
     big partial, exactly the pre-speculation behavior."""
-    from spark_rapids_tpu.execs import aggregate as agg_mod
-
-    monkeypatch.setattr(agg_mod, "_DEFER_SYNC_CAP", 0)
-    get_conf().set("spark.rapids.tpu.sql.batchSizeRows", 256)
-    get_conf().set("spark.rapids.tpu.sql.shuffle.partitions", 1)
+    _per_batch_agg_sizing(monkeypatch)
     get_conf().set(ENABLED, False)
     df = _agg_df(session)
     with P.trace_events() as events:
@@ -391,21 +431,24 @@ def test_explain_analyze_shows_speculation_and_jit_cache(session):
         "w": np.arange(16, dtype=np.int64),
     }))
     df = left.join(right, left_on=[col("k")], right_on=[col("k")])
-    df.collect(engine="tpu")  # warm the predictor + compile cache
+    df.collect(engine="tpu")
     out = df.explain("analyze")
     assert "jit cache:" in out
-    assert "specHits" in out, out  # the join ran sync-free batches
+    # the join's fill: pairs counted, and the capacities expanded at
+    assert "expandRows" in out and "expandCapacityRows" in out, out
 
 
-def test_speculation_stats_and_hit_rate():
-    left, right = _join_tables(n_stream=320)
-    _rows(_join_exec("inner", left, right))
+def test_speculation_stats_and_hit_rate(session, monkeypatch):
+    """Driven by the aggregate's per-batch sizing path (the join sizes
+    from its count and records nothing here)."""
+    _per_batch_agg_sizing(monkeypatch)
+    _agg_df(session).collect(engine="tpu")
     st = SP.stats()
-    assert "join.probe" in st
-    s = st["join.probe"]
+    assert set(st) == {"agg.size"}
+    s = st["agg.size"]
     assert s["hits"] + s["overflows"] > 0
     assert 0.0 <= SP.hit_rate() <= 1.0
-    assert SP.hit_rate(tags=("join.probe",)) == SP.hit_rate()
+    assert SP.hit_rate(tags=("agg.size",)) == SP.hit_rate()
     SP.reset_stats()
     assert SP.stats() == {}
 

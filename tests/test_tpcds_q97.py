@@ -274,16 +274,14 @@ def test_a_distinct_of_near_unique_pairs_is_np_unique(n_batches):
 
 # -- tasks of unlike sizes under one join --------------------------------- #
 
-def test_a_joins_batches_of_unlike_capacity_predict_apart():
+def test_a_joins_unlike_batches_in_another_order_compile_nothing():
     """A scan split into tasks of 10 files and 5 hands one join stream
-    batches of two capacities.  Each capacity has a predictor of its
-    own, so the small task's expansion is sized by the small task's
-    counts, and neither's bucket moves with the order the tasks ran
-    in: a second pass over the same batches compiles nothing new."""
+    batches of two capacities.  Each batch's expansion is sized by its
+    own counted pairs, so no bucket moves with the order the tasks ran
+    in: a second pass over the same batches in another order compiles
+    nothing new."""
     from spark_rapids_tpu.execs.jit_cache import cache_stats
-    from spark_rapids_tpu.parallel import speculation as SP
 
-    SP.reset_predictors()
     rng = np.random.default_rng(5)
     big = np.stack([rng.integers(1, 40, 900), rng.integers(1, 5, 900)], 1)
     small = np.stack([rng.integers(1, 40, 100), rng.integers(1, 5, 100)], 1)
@@ -299,13 +297,11 @@ def test_a_joins_batches_of_unlike_capacity_predict_apart():
             TpuBatchSourceExec(stream, L_SCHEMA),
             _source(R_SCHEMA, right, 1))
 
-    first = _rows(join([big, small, big, small]))
+    ex = join([big, small, big, small])
+    first = _rows(ex)
     assert len(first) == 2 * (len(big) + len(small))
-    sizing = {k[-1]: p for k, p in SP._PREDICTORS.items()
-              if k[-2] == "sizing"}
-    assert sorted(sizing) == [128, 1024]  # one a stream capacity
-    assert set(sizing[128].recent) == {100}
-    assert set(sizing[1024].recent) == {900}
+    assert ex.metrics["expandRows"].value == len(first)
+    assert ex.metrics["expandCapacityRows"].value == 2 * (1024 + 128)
     before = cache_stats()["misses"]
     assert _rows(join([small, small, big, big])) == first
     assert cache_stats()["misses"] == before
