@@ -138,6 +138,7 @@ class _CollectiveBase(TpuExec):
         TpuSortExec does for a global sort: a filter's output keeps its
         input's capacity, and a stage is paid by capacity."""
         from spark_rapids_tpu.execs.sort import _COUNT_ABOVE_CAPACITY
+        from spark_rapids_tpu.parallel.pipeline import device_read_int
 
         n = self.num_partitions
         budget = get_conf().get(COLLECTIVE_ROUND_ROWS)
@@ -146,7 +147,7 @@ class _CollectiveBase(TpuExec):
         yielded = False
         for p in range(child.num_partitions):
             for b in child.execute_partition(p):
-                r = b.concrete_num_rows()
+                r = device_read_int(b.num_rows, tag="mesh.drain")
                 tgt = rows.index(min(rows))  # least-loaded shard
                 b = _dc.replace(b, num_rows=r)
                 if size_to_rows and b.capacity > _COUNT_ABOVE_CAPACITY:
@@ -259,7 +260,10 @@ class _CollectiveBase(TpuExec):
         with lk:
             out = getattr(self, "_shards_out", None)
             if out is None:
-                out = self._shards_out = self._materialize()
+                # every mesh.* span and counts fetch of the stage says
+                # which operator's stage it served
+                with _trace.trace_context(op=self.name):
+                    out = self._shards_out = self._materialize()
         return out
 
     def execute_partition(self, p: int) -> Iterator[ColumnarBatch]:

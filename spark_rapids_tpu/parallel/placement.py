@@ -19,7 +19,9 @@ stage inputs anywhere else in execs// parallel/):
   source (numpy / python) counts ``host_uploads``; a jax Array already
   resident on the target device counts ``device_born`` and skips the
   copy when it is exactly placed; anything else is a
-  ``d2d_transfers`` device-to-device move;
+  ``d2d_transfers`` device-to-device move, whose bytes add to
+  ``d2d_bytes`` (what crosses chips through ``device_put``, outside
+  any collective);
 - :func:`adopt_batch` is the PRODUCER-side half: stage outputs adopt
   their shard's device as they are shrunk (spmd.shrink_rounds /
   unstack_*), so the next stage's assembly finds every piece
@@ -43,7 +45,7 @@ import threading
 import jax
 
 _STATS = {"host_uploads": 0, "device_born": 0, "d2d_transfers": 0,
-          "control_uploads": 0, "adoptions": 0}
+          "d2d_bytes": 0, "control_uploads": 0, "adoptions": 0}
 _LOCK = threading.Lock()
 
 
@@ -68,7 +70,9 @@ def place_piece(x, device, control: bool = False):
         if len(devs) == 1:
             return x  # already exactly placed: zero-copy adoption
         return jax.device_put(x, device)
-    _bump("d2d_transfers")
+    with _LOCK:
+        _STATS["d2d_transfers"] += 1
+        _STATS["d2d_bytes"] += x.nbytes
     return jax.device_put(x, device)
 
 

@@ -26,8 +26,12 @@ collective tier (execs/collective.py) compiles a program —
 - per-round host syncs are DEFERRED to program boundaries: one
   ``stage_counts`` fetch of a program's output row-count array
   replaces the per-round per-shard `concrete_num_rows` + shrink
-  choreography (the aggregate stage fetches twice a bucket: its
-  update program's counts size the exchange, docs/spmd.md).
+  choreography (the aggregate stage fetches twice a bucket, three
+  times over a ROLLUP, and once more after its tail: its update
+  program's counts size the exchange, docs/spmd.md).  Every such
+  fetch goes through `pipeline.device_read` (tag ``mesh.counts``),
+  which counts it, and the host's work between the programs has one
+  span a call: ``mesh.stack``, ``mesh.shrink``, ``mesh.launch``.
 
 Programs compile through execs/jit_cache.cached_jit with the sharding
 spec pair folded into the structural key (plus parallel.mesh.mesh_key,
@@ -48,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu import types as T
 from spark_rapids_tpu.columnar.batch import (
     ColumnarBatch,
@@ -69,6 +74,7 @@ from spark_rapids_tpu.parallel.exchange import (
     take_piece,
 )
 from spark_rapids_tpu.parallel.mesh import DATA_AXIS, mesh_key
+from spark_rapids_tpu.parallel.pipeline import device_read
 
 
 def rounds_sharding(mesh) -> NamedSharding:
@@ -159,8 +165,45 @@ def _assemble(mesh, per_dev: list, control: bool = False) -> jax.Array:
         shape, rounds_sharding(mesh), pieces)
 
 
+def _leaves(batch: ColumnarBatch) -> list:
+    """Every array of a flat-schema batch's columns."""
+    out: list = []
+    for c in batch.columns:
+        out += (c.chars, c.lengths, c.validity) \
+            if isinstance(c, StringColumn) else (c.data, c.validity)
+    return out
+
+
 def shard_stack_rounds(rounds: Sequence[Sequence[ColumnarBatch]],
                        mesh) -> ColumnarBatch:
+    """`_stack_rounds` under ONE ``mesh.stack`` span a call, which
+    says what the host did for the stage's input: the batches
+    `unify_batches` had to pad up to the common capacity, and what
+    `place_piece` found on its device, moved chip to chip (with the
+    bytes) or uploaded (the difference of `placement.stats()` over
+    the call)."""
+    if not _trace.TRACER.enabled:
+        return _stack_rounds(rounds, mesh)
+    from spark_rapids_tpu.parallel import placement as _placement
+
+    flat = [b for shards in rounds for b in shards]
+    before = _placement.stats()
+    with _trace.span("mesh.stack", rounds=len(rounds),
+                     shards=len(rounds[0])) as sp:
+        out = _stack_rounds(rounds, mesh)
+        after = _placement.stats()
+        stacked = [x.shape[2:] for x in _leaves(out)]
+        sp.note(capacity=stacked[0][0], leaves=len(stacked),
+                repadded=sum([x.shape for x in _leaves(b)] != stacked
+                             for b in flat),
+                **{k: after[k] - before[k]
+                   for k in ("host_uploads", "device_born",
+                             "d2d_transfers", "d2d_bytes")})
+    return out
+
+
+def _stack_rounds(rounds: Sequence[Sequence[ColumnarBatch]],
+                  mesh) -> ColumnarBatch:
     """Assemble R rounds of n per-shard batches into ONE global sharded
     batch: every leaf becomes a (R, n, capacity, ...) jax Array under
     ``NamedSharding(mesh, P(None, "data"))``, with shard d's slice
@@ -243,30 +286,28 @@ def sample_fracs(mesh, n_rounds: int, k: int,
 
 def stage_counts(batch: ColumnarBatch) -> np.ndarray:
     """THE stage-exit sync: fetch the output row-count array (shape
-    (n,) or (R, n)) in one device_get.  Everything the host needs per
+    (n,) or (R, n)) in one readback.  Everything the host needs per
     round (live rows per shard, shrink sizes) comes out of this single
-    fetch."""
-    return np.asarray(jax.device_get(batch.num_rows))
+    fetch.  Through `device_read`, as every fetch at a stage boundary
+    is: `stage.mesh.counts.readbacks` counts it and a `pipe.readback`
+    span tagged ``mesh.counts`` times it."""
+    return np.asarray(device_read(batch.num_rows, tag="mesh.counts"))
 
 
 def fetch(arr) -> np.ndarray:
-    """Host fetch of a small stage-exit diagnostic array (the join
-    stage's per-round true totals) — one device_get at a stage
-    boundary, never inside the round loop."""
-    return np.asarray(jax.device_get(arr))
+    """Host fetch of a small stage-exit array (the join stage's
+    per-round true totals, an exchange's destination counts) — one
+    readback at a stage boundary, never inside the round loop, counted
+    and timed as `stage_counts`' is."""
+    return np.asarray(device_read(arr, tag="mesh.counts"))
 
 
 def row_bytes(batch: ColumnarBatch) -> int:
     """Device bytes one row of a round-stacked batch takes: every
     column's data, validity and, for strings, the padded character
     matrix and the lengths.  Read off the arrays' shapes on the host."""
-    total = 0
-    for c in batch.columns:
-        leaves = (c.chars, c.lengths, c.validity) \
-            if isinstance(c, StringColumn) else (c.data, c.validity)
-        for leaf in leaves:
-            total += leaf.dtype.itemsize * int(np.prod(leaf.shape[3:]))
-    return total
+    return sum(leaf.dtype.itemsize * int(np.prod(leaf.shape[3:]))
+               for leaf in _leaves(batch))
 
 
 def _slice_shard(batch: ColumnarBatch, idx: tuple, rows: int,
@@ -293,6 +334,20 @@ def _slice_shard(batch: ColumnarBatch, idx: tuple, rows: int,
     return out
 
 
+def _shrink_span(batch: ColumnarBatch, counts: np.ndarray,
+                 pieces: int):
+    """The ``mesh.shrink`` span round one cut of a stacked stage
+    output into `pieces` batches, opened once its counts are on the
+    host: a `take_piece` and a `shrink_to_capacity` a leaf a piece."""
+    if not _trace.TRACER.enabled:
+        return _trace.span("mesh.shrink")
+    leaves = _leaves(batch)
+    return _trace.span("mesh.shrink", pieces=pieces,
+                       rows=int(counts.sum()),
+                       capacity=leaves[0].shape[counts.ndim],
+                       leaves=len(leaves))
+
+
 def _adoption_devices(mesh) -> Optional[list]:
     """Mesh device list when producer-side adoption is on (mesh
     serving), else None — the default keeps shrink outputs wherever
@@ -315,9 +370,10 @@ def unstack_stage(batch: ColumnarBatch,
     if counts is None:
         counts = stage_counts(batch)
     devs = _adoption_devices(mesh)
-    return [_slice_shard(batch, (d,), int(counts[d]),
-                         devs[d] if devs else None)
-            for d in range(counts.shape[0])]
+    with _shrink_span(batch, counts, counts.shape[0]):
+        return [_slice_shard(batch, (d,), int(counts[d]),
+                             devs[d] if devs else None)
+                for d in range(counts.shape[0])]
 
 
 def unstack_round_stage(batch: ColumnarBatch,
@@ -330,12 +386,13 @@ def unstack_round_stage(batch: ColumnarBatch,
     r_count, n = counts.shape
     devs = _adoption_devices(mesh)
     out: list[list[ColumnarBatch]] = [[] for _ in range(n)]
-    for d in range(n):
-        for r in range(r_count):
-            rows = int(counts[r, d])
-            if rows:
-                out[d].append(_slice_shard(
-                    batch, (r, d), rows, devs[d] if devs else None))
+    with _shrink_span(batch, counts, int(np.count_nonzero(counts))):
+        for d in range(n):
+            for r in range(r_count):
+                rows = int(counts[r, d])
+                if rows:
+                    out[d].append(_slice_shard(
+                        batch, (r, d), rows, devs[d] if devs else None))
     return out
 
 
@@ -356,10 +413,11 @@ def shrink_rounds(batch: ColumnarBatch,
         counts = stage_counts(batch)
     r_count, n = counts.shape
     devs = _adoption_devices(mesh)
-    return [[_slice_shard(batch, (r, d), int(counts[r, d]),
-                          devs[d] if devs else None)
-             for d in range(n)]
-            for r in range(r_count)]
+    with _shrink_span(batch, counts, r_count * n):
+        return [[_slice_shard(batch, (r, d), int(counts[r, d]),
+                              devs[d] if devs else None)
+                 for d in range(n)]
+                for r in range(r_count)]
 
 
 # ------------------------------------------------------------------ #
@@ -393,11 +451,21 @@ def _stage_jit(key: tuple, make_fn, mesh, op, in_shardings,
     from spark_rapids_tpu.execs.jit_cache import cached_jit
 
     n = int(mesh.shape[DATA_AXIS])
-    return cached_jit(
+    prog = cached_jit(
         key + (mesh_key(mesh),), make_fn, op=op,
         in_shardings=in_shardings, out_shardings=out_shardings,
         donate=donate,
         meta={"devices": n, "rounds": n_rounds})
+
+    def launch(*args):
+        # the host's side of one dispatch of a stage program
+        if not _trace.TRACER.enabled:
+            return prog(*args)
+        with _trace.span("mesh.launch", op=op, program=key[0],
+                         rounds=n_rounds, devices=n):
+            return prog(*args)
+
+    return launch
 
 
 def _rounds_scan_stage(tag: str, mesh, key: tuple, body: Callable,
