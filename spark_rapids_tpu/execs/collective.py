@@ -180,7 +180,7 @@ class _CollectiveBase(TpuExec):
 
         n = self.num_partitions
         width = S.row_bytes(xs)
-        n_rounds = int(xs.num_rows.shape[0])
+        n_rounds = S.stacked_rounds(xs)
         self.metrics["collectiveBytes"].add(
             n_rounds * n * n * slot_capacity * width)
         if rows is not None:
@@ -199,10 +199,9 @@ class _CollectiveBase(TpuExec):
         under `span`.  Nothing is guessed, so nothing overflows: rows
         that all hash to one destination count a slot of the input's
         capacity.  The same counts say what every shard received, so
-        the mid-stage shrink needs no fetch of its own: returns the
-        received rows as a rounds[r][d] grid at tight capacity (padded
-        to a power of two of rounds, ready to stack again) and their
-        (R, n) counts."""
+        the mid-stage boundary needs no fetch of its own: returns the
+        received rows stacked at tight capacity (`spmd.restage`), the
+        next stage program's input, and their (R, n) counts."""
         import numpy as np
 
         from spark_rapids_tpu.parallel import spmd as S
@@ -230,9 +229,14 @@ class _CollectiveBase(TpuExec):
                          row_bytes=width, **attrs):
             routed = prog(xs)
         received = sent.sum(axis=1).astype(np.int32)
-        return S.pad_rounds_pow2(
-            S.shrink_rounds(routed, received, mesh=self.mesh),
-            routed.schema, n), received
+        return self._restage((routed, received)), received
+
+    def _restage(self, *stacked):
+        """`spmd.restage` of this stage's `(stacked output, counts)`
+        pairs: the boundary between two of its programs."""
+        from spark_rapids_tpu.parallel import spmd as S
+
+        return S.restage(stacked, self.mesh, op=self.name)
 
     # -- per-partition serving ----------------------------------------- #
 
@@ -355,16 +359,20 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
         """The aggregation stage as O(1) partitioned programs.  Per
         round bucket: an update program (map-side partial aggregation,
         rounds folded into a lax.scan, no collective), ONE counts
-        fetch + shrink that cuts every (round, shard) partial to its
-        counted rows, then an exchange program (in-program hash
-        all_to_all -> reduce-side merge, the same scan) at THAT
-        capacity — the shuffle carries groups, not the input round's
-        padding.  After the last bucket: one more counts fetch +
-        shrink and one tail program (cross-round merge + finalize) at
-        tight capacity — same keys always land on the same shard, so
-        the cross-round fold is shard-local.  A group-by whose
-        partials are as many as its rows counts its way back to the
-        input's bucket, so one path serves both.  Over a ROLLUP's
+        fetch and the boundary program (`spmd.restage`) that cuts the
+        stacked partials to the capacity their largest count pads to,
+        then an exchange program (in-program hash all_to_all ->
+        reduce-side merge, the same scan) at THAT capacity — the
+        shuffle carries groups, not the input round's padding — and
+        one more counts fetch and boundary program, so a bucket's
+        merged rounds wait at tight capacity.  After the last bucket:
+        the buckets' rounds end to end (one more boundary program
+        where there are several; one bucket goes on as it is) and one
+        tail program (cross-round merge + finalize) — same keys
+        always land on the same shard, so the cross-round fold is
+        shard-local.  A group-by whose partials are as many as its
+        rows counts its way back to the input's bucket, the boundary
+        runs no program, and one path serves both.  Over a ROLLUP's
         Expand the map side is the rollup path's two programs
         (`_rollup_partials`)."""
         from spark_rapids_tpu.parallel import spmd as S
@@ -379,20 +387,21 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
         ko = list(range(self._agg.n_keys))
 
         def counted_partials(bucket):
-            """The update program over `bucket`, its partials cut to
-            their counted rows and stacked again, and the slot the
-            exchange leaves at: their capacity."""
+            """The update program over `bucket`, its stacked partials
+            cut to their counted rows, and the slot the exchange
+            leaves at: their capacity."""
             update = S.make_update_scan_stage(
                 self.mesh, akey, self._pre, len(bucket),
                 op=self.name, donate=True)
             partials = update(S.shard_stack_rounds(bucket, self.mesh))
             counts = S.stage_counts(partials)
-            sized = S.shrink_rounds(partials, counts, mesh=self.mesh)
-            return (S.shard_stack_rounds(sized, self.mesh), counts,
-                    _grid_capacity(sized), None)
+            xs = self._restage((partials, counts))
+            return xs, counts, S.stacked_capacity(xs), None
 
         with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
-            shrunk: list[list[ColumnarBatch]] = []  # rounds[r][d]
+            # a bucket's merged rounds, stacked at tight capacity,
+            # with their (R, n) counts
+            shrunk: list[tuple] = []
             bucket: list = []
 
             def flush(bucket):
@@ -424,7 +433,9 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
                                  rounds=len(bucket), row_bytes=width,
                                  **how):
                     merged = prog(xs)
-                shrunk.extend(S.shrink_rounds(merged, mesh=self.mesh))
+                received = S.stage_counts(merged)
+                shrunk.append((self._restage((merged, received)),
+                               received))
 
             for shards in self._shard_rounds(child):
                 bucket.append(shards)
@@ -433,12 +444,10 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
                     bucket = []
             if bucket:
                 flush(bucket)
-            rounds2 = S.pad_rounds_pow2(
-                shrunk, self._agg.partial_schema, n)
-            xs2 = S.shard_stack_rounds(rounds2, self.mesh)
+            xs2 = self._restage(*shrunk)
             tail = S.make_stage_tail(self.mesh, akey, self._finalize,
-                                     len(rounds2), op=self.name,
-                                     donate=True)
+                                     S.stacked_rounds(xs2),
+                                     op=self.name, donate=True)
             final = t.observe(tail(xs2))
         counts = S.stage_counts(final)
         out = []
@@ -617,10 +626,12 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
         the side's key hash, ONE fetch of the (R, n, n) destination
         counts — the one readback a side and bucket — and the route
         program, all rounds in one lax.scan, at `pad_capacity` of the
-        largest count; no fetch after the exchange).  The build side
-        then folds to one batch a shard (a tail program); each stream
-        bucket runs one probe program joining the TIGHT routed rounds
-        against the resident build shard.  A side whose rows all hash
+        largest count; no fetch after the exchange; the boundary
+        program, `spmd.restage`, cuts what arrived to its counted
+        rows).  The build side then folds to one batch a shard (a
+        tail program); each stream bucket runs one probe program
+        joining the TIGHT routed rounds against the resident build
+        shard.  A side whose rows all hash
         to one destination counts its way back to a slot of the
         input's capacity: one path, nothing to set.  Overflow of the
         probe's output-capacity guess re-dispatches that bucket's
@@ -642,20 +653,19 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
             build_rounds = S.pad_rounds_pow2(
                 list(self._shard_rounds(self.children[1])),
                 self.children[1].schema, n)
-            rounds_b, bcounts = exchanged(build_rounds, "build",
-                                          self._route_build)
+            xs_b, bcounts = exchanged(build_rounds, "build",
+                                      self._route_build)
             self.metrics["buildRows"].add(int(bcounts.sum()))
             btail = S.make_stage_tail(
                 self.mesh, jkey + ("buildfold",), lambda b: b,
-                len(rounds_b), op=self.name, donate=True)
-            build = btail(S.shard_stack_rounds(rounds_b, self.mesh))
+                S.stacked_rounds(xs_b), op=self.name, donate=True)
+            build = btail(xs_b)
 
             def run_bucket(bucket):
                 bucket = S.pad_rounds_pow2(bucket,
                                            self.children[0].schema, n)
-                rounds2, counts2 = exchanged(bucket, "stream",
-                                             self._route_stream)
-                xs2 = S.shard_stack_rounds(rounds2, self.mesh)
+                xs2, counts2 = exchanged(bucket, "stream",
+                                         self._route_stream)
                 # probe out-capacity from the LIVE routed maximum, not
                 # the padded round capacity or the whole build side:
                 # pad_capacity honors the pow2x3 bucket policy, so a
@@ -675,7 +685,7 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
                         self.mesh, jkey + (cap_guess,),
                         lambda s, b, c=cap_guess:
                             self._join_local(s, b, c),
-                        len(rounds2), op=self.name)
+                        S.stacked_rounds(xs2), op=self.name)
                     outs, totals = prog(xs2, build)
                     if semi_anti:
                         break
@@ -757,8 +767,9 @@ class TpuCollectiveSortExec(_CollectiveBase):
         route program (in-program sampling at host-chosen fractional
         positions — no per-batch row-count sync — all_gather-pooled
         dynamic range bounds, the range-routed all_to_all over a
-        scanned rounds axis), ONE mid-stage counts fetch + shrink,
-        then the tail program sorting each shard at tight capacity —
+        scanned rounds axis), ONE mid-stage counts fetch and the
+        boundary program (`spmd.restage`), then the tail program
+        sorting each shard at tight capacity —
         shard index order IS the total order.  The sort stage ignores
         bucketRounds: bounds must see every round's sample, so every
         round is resident while the route program runs."""
@@ -795,13 +806,10 @@ class TpuCollectiveSortExec(_CollectiveBase):
                     self.mesh, skey, part, len(rounds),
                     self.SAMPLE_PER_SHARD, op=self.name, donate=True)
                 routed = rprog(xs, fracs)
-                rounds2 = S.pad_rounds_pow2(
-                    S.shrink_rounds(routed, mesh=self.mesh),
-                    child.schema, n)
-                xs2 = S.shard_stack_rounds(rounds2, self.mesh)
+                xs2 = self._restage((routed, S.stage_counts(routed)))
                 tail = S.make_stage_tail(self.mesh, skey, local_sort,
-                                         len(rounds2), op=self.name,
-                                         donate=True)
+                                         S.stacked_rounds(xs2),
+                                         op=self.name, donate=True)
                 out = t.observe(tail(xs2))
         counts = S.stage_counts(out)
         return [[b]
@@ -857,7 +865,9 @@ class TpuCollectiveSortExec(_CollectiveBase):
         bounds = jit_bounds(samples)
 
         # pass 2: per-bucket range routing against the shared bounds
-        shrunk: list[list[ColumnarBatch]] = []
+        # (as the aggregate's buckets: each cut as it arrives, then
+        # end to end)
+        shrunk: list[tuple] = []
         for bucket in buckets:
             xs = S.shard_stack_rounds(bucket, self.mesh)
             self._tick_exchange(xs, _grid_capacity(bucket),
@@ -865,12 +875,12 @@ class TpuCollectiveSortExec(_CollectiveBase):
             rprog = S.make_bounds_route_stage(
                 self.mesh, skey, part, len(bucket), op=self.name,
                 donate=True)
-            shrunk.extend(S.shrink_rounds(rprog(xs, bounds),
-                                          mesh=self.mesh))
-        rounds2 = S.pad_rounds_pow2(shrunk, child.schema, n)
-        xs2 = S.shard_stack_rounds(rounds2, self.mesh)
+            routed = rprog(xs, bounds)
+            counts = S.stage_counts(routed)
+            shrunk.append((self._restage((routed, counts)), counts))
+        xs2 = self._restage(*shrunk)
         tail = S.make_stage_tail(self.mesh, skey, local_sort,
-                                 len(rounds2), op=self.name,
+                                 S.stacked_rounds(xs2), op=self.name,
                                  donate=True)
         return t.observe(tail(xs2))
 
@@ -935,8 +945,9 @@ class TpuCollectiveWindowExec(_CollectiveBase):
         stage's one readback, which gives the send slots' capacity,
         the rows every shard receives and therefore the stage's output
         counts too.  The route program sends the rows through the
-        all_to_all at that slot capacity; the tail program runs the
-        window per shard over its received rounds at tight capacity.
+        all_to_all at that slot capacity and the boundary program
+        (`spmd.restage`) cuts what arrived to it; the tail program runs
+        the window per shard over its received rounds at tight capacity.
         Like the sort, the stage ignores bucketRounds: a partition's
         rows may sit in any round, so every round is resident while
         the route program runs."""
@@ -949,13 +960,13 @@ class TpuCollectiveWindowExec(_CollectiveBase):
         with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
             rounds = S.pad_rounds_pow2(
                 list(self._shard_rounds(child)), child.schema, n)
-            rounds2, received = self._route_counted(
+            xs2, received = self._route_counted(
                 rounds, wkey, self._route, "collective.window.exchange",
                 tag="spmdwinroute")
             tail = S.make_stage_tail(
-                self.mesh, wkey, self._win._window_batch, len(rounds2),
-                op=self.name, donate=True)
-            out = t.observe(tail(S.shard_stack_rounds(rounds2, self.mesh)))
+                self.mesh, wkey, self._win._window_batch,
+                S.stacked_rounds(xs2), op=self.name, donate=True)
+            out = t.observe(tail(xs2))
         # a window emits the rows it was handed
         return [[b] for b in S.unstack_stage(
             out, received.sum(axis=0), mesh=self.mesh)]

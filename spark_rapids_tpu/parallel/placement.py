@@ -23,9 +23,10 @@ stage inputs anywhere else in execs// parallel/):
   ``d2d_bytes`` (what crosses chips through ``device_put``, outside
   any collective);
 - :func:`adopt_batch` is the PRODUCER-side half: stage outputs adopt
-  their shard's device as they are shrunk (spmd.shrink_rounds /
-  unstack_*), so the next stage's assembly finds every piece
-  device-born;
+  their shard's device as a stage's exit cuts them (spmd.unstack_*),
+  so the next stage's assembly finds every piece device-born (between
+  two programs of one stage `spmd.restage` is a partitioned program:
+  its outputs are born on their shards' devices);
 - the counters surface as ``placement.*`` event-log counters and the
   ``placement_host_uploads`` bench field — steady state under mesh
   serving is ZERO host uploads (the smoke gate

@@ -31,7 +31,15 @@ collective tier (execs/collective.py) compiles a program —
   program's counts size the exchange, docs/spmd.md).  Every such
   fetch goes through `pipeline.device_read` (tag ``mesh.counts``),
   which counts it, and the host's work between the programs has one
-  span a call: ``mesh.stack``, ``mesh.shrink``, ``mesh.launch``.
+  span a call: ``mesh.stack``, ``mesh.shrink``, ``mesh.launch``;
+- a stage is taken apart and put together ONCE each: `shard_stack_rounds`
+  stacks per-shard batches at its ENTRY, `unstack_stage` /
+  `unstack_round_stage` cut per-shard batches at its EXIT, and BETWEEN
+  two of its programs stands `restage`, one cached program from a
+  program's stacked output to the next one's stacked input (the cut
+  to the counted capacity, several buckets' rounds end to end, rounds
+  up to a power of two), shard-local, where the host once ran some
+  five eager programs a leaf a (round, shard) piece.
 
 Programs compile through execs/jit_cache.cached_jit with the sharding
 spec pair folded into the structural key (plus parallel.mesh.mesh_key,
@@ -174,6 +182,18 @@ def _leaves(batch: ColumnarBatch) -> list:
     return out
 
 
+def _with_leaves(like: ColumnarBatch, leaves, num_rows) -> ColumnarBatch:
+    """`like`'s columns over other arrays, given in `_leaves`' order."""
+    it = iter(leaves)
+    cols: list[AnyColumn] = []
+    for c in like.columns:
+        if isinstance(c, StringColumn):
+            cols.append(StringColumn(next(it), next(it), next(it)))
+        else:
+            cols.append(Column(next(it), next(it), c.dtype))
+    return ColumnarBatch(cols, num_rows, like.schema)
+
+
 def shard_stack_rounds(rounds: Sequence[Sequence[ColumnarBatch]],
                        mesh) -> ColumnarBatch:
     """`_stack_rounds` under ONE ``mesh.stack`` span a call, which
@@ -254,13 +274,17 @@ def _stack_rounds(rounds: Sequence[Sequence[ColumnarBatch]],
     return ColumnarBatch(cols, num_rows, schema)
 
 
+def _pow2(rounds: int) -> int:
+    """The next power of two, a round count's bucket."""
+    return 1 << (rounds - 1).bit_length() if rounds > 1 else 1
+
+
 def pad_rounds_pow2(rounds: list, schema: T.Schema, n: int) -> list:
     """Pad a round list with rounds of empty shard batches up to the
     next power of two, so the in-program scan length (part of the
     compiled program's key) takes a handful of bucketed values instead
     of minting one executable per data-dependent round count."""
-    r = len(rounds)
-    want = 1 << (r - 1).bit_length() if r > 1 else 1
+    want = _pow2(len(rounds))
     out = list(rounds)
     while len(out) < want:
         out.append([ColumnarBatch.empty(schema) for _ in range(n)])
@@ -280,7 +304,8 @@ def sample_fracs(mesh, n_rounds: int, k: int,
 
 
 # ------------------------------------------------------------------ #
-# Stage exit: ONE host sync, then unstack + shrink
+# Stage exit: ONE host sync, then unstack + shrink into per-shard
+# batches for a one-chip consumer
 # ------------------------------------------------------------------ #
 
 
@@ -316,16 +341,8 @@ def _slice_shard(batch: ColumnarBatch, idx: tuple, rows: int,
     # partitioned stage output is wholly resident on one device, and
     # an eager getitem on the sharded array would launch an unguarded
     # cross-device gather (exchange.take_piece documents the hazard)
-    cols: list[AnyColumn] = []
-    for c in batch.columns:
-        if isinstance(c, StringColumn):
-            cols.append(StringColumn(take_piece(c.chars, idx),
-                                     take_piece(c.lengths, idx),
-                                     take_piece(c.validity, idx)))
-        else:
-            cols.append(Column(take_piece(c.data, idx),
-                               take_piece(c.validity, idx), c.dtype))
-    out = ColumnarBatch(cols, rows, batch.schema)
+    out = _with_leaves(
+        batch, [take_piece(x, idx) for x in _leaves(batch)], rows)
     out = out.shrink_to_capacity(max(MIN_CAPACITY,
                                      pad_capacity(rows)))
     if device is not None:
@@ -336,13 +353,15 @@ def _slice_shard(batch: ColumnarBatch, idx: tuple, rows: int,
 
 def _shrink_span(batch: ColumnarBatch, counts: np.ndarray,
                  pieces: int):
-    """The ``mesh.shrink`` span round one cut of a stacked stage
-    output into `pieces` batches, opened once its counts are on the
-    host: a `take_piece` and a `shrink_to_capacity` a leaf a piece."""
+    """The ``mesh.shrink`` span round one cut of a stage's stacked
+    output into `pieces` batches for a one-chip consumer, opened once
+    its counts are on the host: a `take_piece` and a
+    `shrink_to_capacity` a leaf a piece (``path="pieces"``; the
+    mid-stage boundary's says ``path="program"``, `restage`)."""
     if not _trace.TRACER.enabled:
         return _trace.span("mesh.shrink")
     leaves = _leaves(batch)
-    return _trace.span("mesh.shrink", pieces=pieces,
+    return _trace.span("mesh.shrink", path="pieces", pieces=pieces,
                        rows=int(counts.sum()),
                        capacity=leaves[0].shape[counts.ndim],
                        leaves=len(leaves))
@@ -396,28 +415,124 @@ def unstack_round_stage(batch: ColumnarBatch,
     return out
 
 
-def shrink_rounds(batch: ColumnarBatch,
-                  counts: Optional[np.ndarray] = None,
-                  mesh=None) -> list[list[ColumnarBatch]]:
-    """THE mid-stage shrink: split a (R, n, capacity, ...) exchange
-    program output into a rectangular rounds[r][d] grid of shrunk
-    batches (empty rounds kept), using ONE stage-exit counts fetch.
-    The exchange program's outputs carry the receive capacity per
-    shard, n x the send slot's (the worst-case n x cap where nothing
-    was counted); shrinking here — once per stage, not once per round
-    — is what keeps the tail program's merge/sort/join work
-    proportional to the LIVE rows instead of the padding.  Under
-    mesh serving each shard column adopts its mesh device here, so the
-    tail program's re-assembly finds every piece device-born."""
-    if counts is None:
-        counts = stage_counts(batch)
-    r_count, n = counts.shape
-    devs = _adoption_devices(mesh)
-    with _shrink_span(batch, counts, r_count * n):
-        return [[_slice_shard(batch, (r, d), int(counts[r, d]),
-                              devs[d] if devs else None)
-                 for d in range(n)]
-                for r in range(r_count)]
+# ------------------------------------------------------------------ #
+# Mid-stage boundary: ONE program from a stage program's stacked
+# output to the next one's stacked input
+# ------------------------------------------------------------------ #
+
+
+def stacked_rounds(batch: ColumnarBatch) -> int:
+    """The rounds of a round-stacked batch, read off its `num_rows`."""
+    return int(batch.num_rows.shape[0])
+
+
+def stacked_capacity(batch: ColumnarBatch) -> int:
+    """The capacity a round-stacked batch's leaves stand at."""
+    return int(_leaves(batch)[0].shape[2])
+
+
+def _restaged_shapes(parts: Sequence[list], counts: Sequence[np.ndarray]
+                     ) -> list[tuple]:
+    """The shapes, leaf for leaf, the next stage program's input has:
+    the rounds of every part, up to a power of two; the capacity the
+    largest counted piece pads to (what cutting every piece to
+    `pad_capacity` of its rows and unifying them again comes to, and
+    never more than a part already has); string widths up to their
+    `pad_width` bucket."""
+    r2 = _pow2(sum(c.shape[0] for c in counts))
+    cap2 = max(min(lv[0].shape[2],
+                   max(MIN_CAPACITY, pad_capacity(int(c.max()))))
+               for lv, c in zip(parts, counts))
+    return [(r2, x.shape[1], cap2) + tuple(
+                pad_width(max(w)) for w in zip(
+                    *(lv[li].shape[3:] for lv in parts)))
+            for li, x in enumerate(parts[0])]
+
+
+def restage(stacked: Sequence[tuple], mesh,
+            op: Optional[str] = None) -> ColumnarBatch:
+    """THE mid-stage boundary: what one stage program returned (or the
+    programs of several buckets, in order), each round-stacked
+    `(R, n, capacity, ...)` a leaf with every shard's slice on its own
+    chip and paired with the `(R, n)` row counts the host already
+    holds for it, to the NEXT stage program's stacked input.  One
+    cached program a call (tag ``spmdrestage``, through `_stage_jit`
+    like every stage program; the inputs donated): per leaf a slice
+    on the capacity axis to what the largest counted piece pads to,
+    the parts' rounds end to end, rounds of zeros up to a power of two
+    (`pad_rounds_pow2` gives the reason).  Nothing is gathered and
+    nothing leaves its chip; `num_rows` is the host's counts, an
+    argument of the program.  Keyed by what the shapes are, so a new
+    seed mints one only where it mints the next stage program too:
+    that one is keyed by the same capacity bucket.  Where the output
+    would have the input's shapes (a group-by whose partials are as
+    many as its rows) no program runs and the stacked batch goes on
+    as it is.
+
+    Cutting before the next program is what keeps its merge, sort or
+    join work proportional to the LIVE rows: an exchange program's
+    outputs carry the receive capacity per shard, n x the send
+    slot's.  Under mesh serving the output is born on its shards'
+    devices, so the next stage finds every piece in place.
+
+    ONE ``mesh.shrink`` span a call, ``path="program"``, with `pieces`,
+    `rows`, `capacity` (the input's), `to_capacity`, `leaves` and
+    `skipped`; the program's ``mesh.launch`` nests inside it."""
+    n = int(mesh.shape[DATA_AXIS])
+    first = stacked[0][0]
+    parts = [_leaves(b) for b, _ in stacked]
+    counts = [np.asarray(c, np.int32) for _, c in stacked]
+    assert all(c.shape == (lv[0].shape[0], n)
+               for lv, c in zip(parts, counts)), "counts a (round, shard)"
+    want = _restaged_shapes(parts, counts)
+    r2, _, cap2 = want[0][:3]
+    skip = len(parts) == 1 and [x.shape for x in parts[0]] == want
+    span = _trace.span("mesh.shrink")
+    if _trace.TRACER.enabled:
+        span = _trace.span(
+            "mesh.shrink", path="program",
+            pieces=n * sum(c.shape[0] for c in counts),
+            rows=sum(int(c.sum()) for c in counts),
+            capacity=max(lv[0].shape[2] for lv in parts),
+            to_capacity=cap2, leaves=len(want), skipped=skip)
+    with span:
+        if skip:
+            return first
+
+        def make():
+            def fit(x, shape: tuple):
+                # per shard (R, 1, capacity, ...): cut or pad every
+                # axis past the shard's to the output's
+                x = x[(slice(None), slice(None))
+                      + tuple(slice(0, s) for s in shape[2:])]
+                return jnp.pad(x, ((0, 0), (0, 0)) + tuple(
+                    (0, s - have) for s, have in
+                    zip(shape[2:], x.shape[2:])))
+
+            def shard_fn(shard_parts, num_rows):
+                out = []
+                for li, shape in enumerate(want):
+                    x = jnp.concatenate(
+                        [fit(lv[li], shape) for lv in shard_parts])
+                    out.append(jnp.pad(
+                        x, ((0, r2 - x.shape[0]),)
+                        + ((0, 0),) * (x.ndim - 1)))
+                return out, num_rows
+
+            return _shard_map(shard_fn, mesh,
+                              (P(None, DATA_AXIS),) * 2,
+                              P(None, DATA_AXIS))
+
+        key = tuple(tuple((x.shape, str(x.dtype)) for x in lv)
+                    for lv in parts)
+        prog = _stage_jit(
+            ("spmdrestage", key, cap2, r2), make, mesh, op,
+            (rounds_sharding(mesh),) * 2, rounds_sharding(mesh),
+            (0,), r2)
+        num_rows = np.zeros((r2, n), np.int32)
+        live = np.concatenate(counts)
+        num_rows[:len(live)] = live
+        return _with_leaves(first, *prog(parts, num_rows))
 
 
 # ------------------------------------------------------------------ #
@@ -498,10 +613,10 @@ def make_update_scan_stage(mesh, key: tuple, body: Callable,
     rounds axis applying `body` (the partial-aggregate update) per
     shard, NO collective.  Emits the round-stacked partials at the
     input's capacity with their (R, n) row counts; the host fetches
-    those counts once and cuts every partial to its counted rows
-    (`shrink_rounds`) BEFORE the exchange program, so the all_to_all
-    and the reduce-side merge are sized to the groups the shuffle
-    carries and not to the input round's padding."""
+    those counts once and the boundary program cuts the partials to
+    their counted rows (`restage`) BEFORE the exchange program, so the
+    all_to_all and the reduce-side merge are sized to the groups the
+    shuffle carries and not to the input round's padding."""
     return _rounds_scan_stage("spmdupdate", mesh, key, body, n_rounds,
                               op, donate)
 
@@ -516,9 +631,9 @@ def make_exchange_scan_stage(mesh, key: tuple, body: Callable,
     inside `body`, as do any fused map/reduce phases).  Emits the
     round-stacked per-shard outputs at the receive capacity, n x the
     send slot's (the input's capacity, or what `body` was told the
-    host counted); the host shrinks them ONCE at stage exit
-    (`shrink_rounds`) before the tail program, so the tail's work is
-    proportional to live rows, not padding."""
+    host counted); the boundary program cuts them ONCE (`restage`)
+    before the tail program, so the tail's work is proportional to
+    live rows, not padding."""
     return _rounds_scan_stage(tag, mesh, key, body, n_rounds, op,
                               donate)
 

@@ -28,7 +28,8 @@ def exchange_one_round(mesh, shards, tag, pre=None, post=None):
     step = S.make_exchange_scan_stage(mesh, ("test_exchange", tag),
                                       body, 1)
     out = step(S.shard_stack_rounds([shards], mesh))
-    return S.shrink_rounds(out)[0]
+    return [got[0] if got else ColumnarBatch.empty(out.schema)
+            for got in S.unstack_round_stage(out)]
 
 
 def make_shards(schema, n_rows_per_shard, seed=0):

@@ -4,6 +4,9 @@ ONE `mesh.stack`, `mesh.shrink` or `mesh.launch` span a call, every
 fetch at a stage boundary goes through `pipeline.device_read` (the
 `stage.mesh.counts` / `stage.mesh.drain` counters, tracer on or off),
 and `placement.place_piece` counts the bytes it moves chip to chip.
+A stage stacks its input once, at its entry, and cuts pieces once, at
+its exit: between two of its programs stands `spmd.restage`, one
+program under a `mesh.shrink` span that says `path="program"`.
 What the benchmark's `idle_mesh_*`, `mesh_host_s`, `mesh_syncs` and
 `mesh_d2d_bytes` readers read, on the 8-virtual-device mesh."""
 
@@ -64,6 +67,15 @@ def _sort(s):
     return s.create_dataframe(_table(1500, 11)).order_by(col("k"))
 
 
+def _window(s):
+    from spark_rapids_tpu.exprs.window import Window, rank
+
+    t = _table(1500, 13)
+    return s.create_dataframe(t).select(
+        col("k"), col("v"),
+        rank().over(Window.partition_by("v").order_by("k")).alias("r"))
+
+
 @dataclasses.dataclass(frozen=True)
 class Stage:
     """One collective stage of one round and one bucket, and what its
@@ -72,24 +84,33 @@ class Stage:
     query: object
     op: str
     programs: tuple  # the stage programs it launches, once each call
-    stacks: int
-    shrinks: int
+    stacks: int  # its entries: a side of the join has its own
+    shrinks: int  # boundaries (a program or skipped) and the exit's cut
     fetches: int
 
 
 STAGES = {
-    # update, counts, shrink, exchange + merge, counts, shrink, tail,
-    # counts, unstack
+    # stack, update, counts, boundary (every key is distinct, so the
+    # partials stand at the input's capacity already: no program),
+    # exchange + merge, counts, boundary, the buckets end to end (one:
+    # no program), tail, counts, unstack
     "agg": Stage(_agg, "TpuCollectiveHashAggregateExec",
-                 ("spmdtail", "spmdupdate", "spmdxchg"), 3, 3, 3),
-    # a side: count, fetch, route, shrink by the same counts; the
-    # build side's fold; the probe, its totals, its unstack
+                 ("spmdrestage", "spmdtail", "spmdupdate", "spmdxchg"),
+                 1, 4, 3),
+    # a side: stack, count, fetch, route, boundary by the same counts;
+    # the build side's fold; the probe, its totals, its unstack
     "join": Stage(_join, "TpuCollectiveHashJoinExec",
-                  ("spmdjoin", "spmdroutecount", "spmdroutecount",
-                   "spmdtail", "spmdxchg", "spmdxchg"), 4, 3, 4),
-    # route, counts, shrink, tail, counts, unstack
+                  ("spmdjoin", "spmdrestage", "spmdrestage",
+                   "spmdroutecount", "spmdroutecount", "spmdtail",
+                   "spmdxchg", "spmdxchg"), 2, 3, 4),
+    # stack, route, counts, boundary, tail, counts, unstack
     "sort": Stage(_sort, "TpuCollectiveSortExec",
-                  ("spmdsortroute", "spmdtail"), 2, 2, 2),
+                  ("spmdrestage", "spmdsortroute", "spmdtail"), 1, 2, 2),
+    # stack, count, fetch, route, boundary by the same counts, tail,
+    # unstack by them too
+    "window": Stage(_window, "TpuCollectiveWindowExec",
+                    ("spmdrestage", "spmdroutecount", "spmdtail",
+                     "spmdwinroute"), 1, 2, 1),
 }
 
 
@@ -133,6 +154,29 @@ def test_a_stage_names_its_host_work_one_span_a_call(session, name):
     for a in shrinks:
         assert a["pieces"] >= 1 and a["leaves"] >= 4
         assert 0 <= a["rows"] <= a["pieces"] * a["capacity"]
+    # a boundary that ran a program, and no other, launched a restage
+    ran = [a for a in shrinks
+           if a["path"] == "program" and not a["skipped"]]
+    assert len(ran) == stage.programs.count("spmdrestage")
+    assert all(a["to_capacity"] < a["capacity"] for a in ran)
+    # from an entry's stack to the stage's exit (or the other side's
+    # entry) the host stacks nothing and cuts no piece: what stands
+    # before the last launch is a boundary, what follows it the exit
+    runs, run = [], None
+    for e in sorted(mine, key=lambda e: e.ts_ns):
+        if e.name == "mesh.stack":
+            run = []
+            runs.append(run)
+        elif e.name in ("mesh.shrink", "mesh.launch"):
+            run.append(e)
+    assert len(runs) == stage.stacks
+    for run in runs:
+        last = max(i for i, e in enumerate(run)
+                   if e.name == "mesh.launch")
+        cuts = [(i < last, e.attrs["path"]) for i, e in enumerate(run)
+                if e.name == "mesh.shrink"]
+        assert all(path == ("program" if inside else "pieces")
+                   for inside, path in cuts), cuts
     syncs = [a for a in named("pipe.readback")
              if a["tag"] == "mesh.counts"]
     assert len(syncs) == stage.fetches

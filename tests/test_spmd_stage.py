@@ -7,6 +7,7 @@ results identical to the one-device answer of the same session
 round-staging contracts the stage input rides on."""
 
 import ast
+import dataclasses
 import inspect
 import pathlib
 
@@ -193,6 +194,167 @@ def test_shard_stack_rounds_is_global_and_sharded(mesh8):
     counts = np.asarray(jax.device_get(xs.num_rows))
     assert counts.shape == (2, N_DEV)
     assert counts.sum() == 2 * N_DEV * 16
+
+
+# ------------------------------------------------------------------ #
+# The mid-stage boundary: one program from a stage program's stacked
+# output to the next one's stacked input
+# ------------------------------------------------------------------ #
+
+RESTAGE_DEV = 4
+_KVS = T.Schema([T.Field("k", T.LONG), T.Field("v", T.DOUBLE),
+                 T.Field("s", T.STRING)])
+
+
+@dataclasses.dataclass(frozen=True)
+class Restage:
+    """A boundary's input: a `(rounds, capacity)` and a `(R, n)` count
+    array a part (a part is what one bucket's program returned), the
+    string column's width, and whether a program has to run."""
+
+    parts: tuple  # ((rounds, capacity), counts) a part
+    width: int = 8
+    runs: bool = True
+
+
+def _counts(*rounds) -> np.ndarray:
+    return np.asarray(rounds, np.int32)
+
+
+RESTAGES = {
+    "one round": Restage((((1, 64), _counts([5, 9, 3, 7])),)),
+    "three rounds padded to four": Restage(
+        (((3, 32), _counts([1, 2, 3, 4], [9, 0, 2, 1], [4, 4, 4, 4])),)),
+    "two buckets of unlike capacities": Restage(
+        (((2, 64), _counts([20, 3, 1, 0], [7, 7, 7, 7])),
+         ((1, 16), _counts([2, 16, 0, 5])))),
+    "a string column's width after pad_width": Restage(
+        (((2, 32), _counts([3, 1, 2, 2], [0, 8, 1, 1])),), width=5),
+    "a shard with no rows": Restage(
+        (((2, 64), _counts([6, 0, 11, 2], [1, 0, 3, 9])),)),
+    "counts that pad back to the input's capacity": Restage(
+        (((2, 16), _counts([12, 3, 0, 1], [2, 2, 9, 4])),), runs=False),
+}
+
+
+def _stacked(mesh, shape: tuple, counts: np.ndarray, width: int,
+             seed: int) -> tuple:
+    """A stage program's output as the boundary finds it: every leaf
+    one `(R, n, capacity, ...)` array under `rounds_sharding`, rows
+    past a piece's count left as the program left them (not zero).
+    Returns the batch and its leaves on the host."""
+    import jax
+
+    from spark_rapids_tpu.columnar.column import Column, StringColumn
+    from spark_rapids_tpu.parallel import spmd as S
+
+    rng = np.random.default_rng(seed)
+    lead = (shape[0], RESTAGE_DEV, shape[1])
+    host = [rng.integers(-99, 99, lead).astype(np.int64),
+            rng.random(lead) < 0.9,
+            rng.random(lead),
+            rng.random(lead) < 0.9,
+            rng.integers(97, 123, lead + (width,)).astype(np.uint8),
+            rng.integers(0, width + 1, lead).astype(np.int32),
+            rng.random(lead) < 0.9]
+    sharding = S.rounds_sharding(mesh)
+    k, kv, v, vv, chars, lens, sv, rows = [
+        jax.device_put(x, sharding) for x in host + [counts]]
+    batch = ColumnarBatch(
+        [Column(k, kv, T.LONG), Column(v, vv, T.DOUBLE),
+         StringColumn(chars, lens, sv)], rows, _KVS)
+    return batch, host
+
+
+def _the_old_path(parts: list, width: int) -> list:
+    """What `shard_stack_rounds(pad_rounds_pow2(shrink_rounds(...)))`
+    made of the same input, as shapes: every (round, shard) piece cut
+    to `pad_capacity` of its rows (never grown), rounds of empty
+    batches up to a power of two, all unified to the largest capacity
+    and the `pad_width` bucket of the widest string."""
+    from spark_rapids_tpu.columnar.column import (
+        MIN_CAPACITY,
+        pad_capacity,
+        pad_width,
+    )
+
+    caps = [min(cap, max(MIN_CAPACITY, pad_capacity(int(rows))))
+            for (_, cap), counts in parts for rows in counts.flat]
+    rounds = sum(r for (r, _), _ in parts)
+    r2 = 1 << (rounds - 1).bit_length() if rounds > 1 else 1
+    lead = (r2, RESTAGE_DEV, max(caps))
+    return [(lead, np.int64), (lead, np.bool_), (lead, np.float64),
+            (lead, np.bool_), (lead + (pad_width(width),), np.uint8),
+            (lead, np.int32), (lead, np.bool_)]
+
+
+@pytest.mark.parametrize("name", sorted(RESTAGES))
+def test_restage_is_the_old_cut_and_stack_in_one_program(name):
+    """`spmd.restage` against what it replaced (a `take_piece` and a
+    `shrink_to_capacity` a leaf a piece, then `unify_batches`' pads, a
+    `jnp.stack` a leaf a device and `place_piece`): leaf for leaf the
+    same shapes and dtypes, so the next stage program keeps its key;
+    the live rows bit-equal; shard d's slice on mesh device d; ONE
+    `spmdrestage` launch inside ONE `mesh.shrink` span, and no program
+    at all where the output would have the input's shapes."""
+    from spark_rapids_tpu import trace
+    from spark_rapids_tpu.parallel import spmd as S
+    from spark_rapids_tpu.parallel.mesh import make_mesh
+
+    case = RESTAGES[name]
+    mesh = make_mesh(RESTAGE_DEV)
+    made = [_stacked(mesh, shape, counts, case.width, seed)
+            for seed, (shape, counts) in enumerate(case.parts)]
+    counts = [c for _, c in case.parts]
+    trace.enable()
+    trace.clear()
+    try:
+        out = S.restage([(b, c) for (b, _), c in zip(made, counts)],
+                        mesh, op="Test")
+        events = trace.snapshot()
+    finally:
+        trace.disable()
+        trace.clear()
+
+    want = _the_old_path(list(case.parts), case.width)
+    got = S._leaves(out)
+    assert [(x.shape, x.dtype) for x in got] == want
+    rows = np.asarray(out.num_rows)
+    assert rows.dtype == np.int32 and rows.shape == want[0][0][:2]
+    assert (rows[:sum(len(c) for c in counts)]
+            == np.concatenate(counts)).all()
+    assert not rows[sum(len(c) for c in counts):].any()
+    # live rows, bit for bit, part after part on the rounds axis
+    r0 = 0
+    for (_, host), part_counts in zip(made, counts):
+        for (r, d), n_rows in np.ndenumerate(part_counts):
+            for x, h in zip(got, host):
+                live = np.asarray(x)[r0 + r, d, :n_rows]
+                if h.ndim == 4:  # the characters, zeros past the width
+                    assert not live[:, case.width:].any()
+                    live = live[:, :case.width]
+                assert (live == h[r, d, :n_rows]).all()
+        r0 += len(part_counts)
+    # nothing left its chip: shard d's slice is on mesh device d
+    devs = list(mesh.devices.flat)
+    for x in got + [out.num_rows]:
+        assert {s.index[1].start: s.device
+                for s in x.addressable_shards} == dict(enumerate(devs))
+
+    launches = [e.attrs for e in events if e.name == "mesh.launch"]
+    cut, = [e.attrs for e in events if e.name == "mesh.shrink"]
+    assert cut["path"] == "program"
+    assert cut["skipped"] is not case.runs
+    assert cut["pieces"] == RESTAGE_DEV * sum(len(c) for c in counts)
+    assert cut["rows"] == sum(int(c.sum()) for c in counts)
+    assert cut["capacity"] == max(cap for (_, cap), _ in case.parts)
+    assert cut["to_capacity"] == want[0][0][2] and cut["leaves"] == 7
+    if case.runs:
+        assert [a["program"] for a in launches] == ["spmdrestage"]
+        assert launches[0]["rounds"] == want[0][0][0]
+        assert launches[0]["op"] == "Test"
+    else:
+        assert not launches and out is made[0][0]
 
 
 def test_mesh_key_identity(mesh8):
@@ -430,7 +592,8 @@ def _traced_collect(exec_, span: str = "collective.agg.exchange"):
 def test_spmd_stage_dispatch_budget(collective_session, conf_sandbox):
     """Many exchange rounds, O(1) program dispatches: with the round
     budget forced tiny (8+ rounds' worth of input), the warm agg stage
-    still executes as two programs a bucket (update, exchange) plus one
+    still executes as four programs a bucket (update, exchange and the
+    boundary after each) plus the buckets' rounds end to end and one
     fold — the rounds run as an in-program scan, not a Python loop of
     dispatches — and the ledger attributes the partitioned programs
     with their mesh width and in-program round counts."""
@@ -452,11 +615,13 @@ def test_spmd_stage_dispatch_budget(collective_session, conf_sandbox):
         rounds = _agg_node(exec_).metrics["collectiveRounds"].value
         buckets = -(-rounds // 8)
         assert rounds >= 8, rounds
-        # stage budget: an update and an exchange program a bucket and
-        # one fold — never one dispatch per round
-        assert dispatches == 2 * buckets + 1, snap
+        # stage budget: an update and an exchange program a bucket,
+        # a boundary program after each, one more to put several
+        # buckets' rounds end to end, and one fold — never one
+        # dispatch per round
+        assert dispatches == 4 * buckets + (buckets > 1) + 1, snap
         assert {p["tag"] for p in snap.values()} == {
-            "spmdupdate", "spmdxchg", "spmdtail"}, snap
+            "spmdupdate", "spmdxchg", "spmdrestage", "spmdtail"}, snap
         assert all(p["devices"] == N_DEV for p in snap.values()), snap
         scan_rounds = max(p["rounds"] for p in snap.values())
         assert scan_rounds >= 8, snap  # rounds folded INTO a program
@@ -745,9 +910,9 @@ def test_spmd_join_null_keys_and_strings_digest(mesh4_session,
 
 
 def test_spmd_join_stage_dispatch_budget(mesh4_session, conf_sandbox):
-    """Eight stream rounds, O(1) dispatches a side and bucket: a count
-    and a route program each, the build side's fold and a probe a
-    bucket — the rounds run inside the programs' scans."""
+    """Eight stream rounds, O(1) dispatches a side and bucket: a
+    count, a route and a boundary program each, the build side's fold
+    and a probe a bucket — the rounds run inside the programs' scans."""
     from spark_rapids_tpu.plan.planner import collect_exec, plan_query
     from spark_rapids_tpu.trace import ledger
 
@@ -769,7 +934,8 @@ def test_spmd_join_stage_dispatch_budget(mesh4_session, conf_sandbox):
         # 2 build rounds, 8 stream rounds in 2 buckets
         assert rounds == 10, rounds
         assert by_tag == {"spmdroutecount": 3, "spmdxchg": 3,
-                          "spmdtail": 1, "spmdjoin": 2}, snap
+                          "spmdrestage": 3, "spmdtail": 1,
+                          "spmdjoin": 2}, snap
         assert all(p["devices"] == JOIN_DEV for p in snap.values()), snap
         assert max(p["rounds"] for p in snap.values()) == 4, snap
     finally:
