@@ -1911,6 +1911,19 @@ def _sort_cpu(plan: L.Sort) -> pa.Table:
     return child.take(idx)
 
 
+def _null_safe_codes(l, r) -> tuple:
+    """Both sides' values of a `<=>` key as int32 codes of one
+    dictionary in which NULL is an entry of its own, so that Arrow's
+    join, whose NULL keys match nothing, matches NULL with NULL."""
+    l, r = (a.combine_chunks() if isinstance(a, pa.ChunkedArray) else a
+            for a in (l, r))
+    if r.type != l.type:
+        r = r.cast(l.type)
+    codes = pc.dictionary_encode(pa.concat_arrays([l, r]),
+                                 null_encoding="encode").indices
+    return codes[:len(l)], codes[len(l):]
+
+
 def _join_cpu(plan: L.Join) -> pa.Table:
     left = execute_cpu(plan.children[0])
     right = execute_cpu(plan.children[1])
@@ -1926,8 +1939,11 @@ def _join_cpu(plan: L.Join) -> pa.Table:
         lkeys, rkeys = [], []
         for i, (lk, rk) in enumerate(zip(plan.left_keys, plan.right_keys)):
             ln, rn = f"__lk{i}", f"__rk{i}"
-            tmpl = tmpl.append_column(ln, cpu_eval(lk, left))
-            tmpr = tmpr.append_column(rn, cpu_eval(rk, right))
+            lv, rv = cpu_eval(lk, left), cpu_eval(rk, right)
+            if i < len(plan.null_safe) and plan.null_safe[i]:
+                lv, rv = _null_safe_codes(lv, rv)
+            tmpl = tmpl.append_column(ln, lv)
+            tmpr = tmpr.append_column(rn, rv)
             lkeys.append(ln)
             rkeys.append(rn)
         left, right = tmpl, tmpr

@@ -268,12 +268,14 @@ class TpuAdaptiveJoinExec(TpuExec):
     to static planning."""
 
     def __init__(self, left_keys, right_keys, join_type: str,
-                 left_exchange, right_exchange, condition=None):
+                 left_exchange, right_exchange, condition=None,
+                 null_safe=()):
         super().__init__(left_exchange, right_exchange)
         self.left_keys = list(left_keys)
         self.right_keys = list(right_keys)
         self.join_type = join_type
         self.condition = condition
+        self.null_safe = tuple(null_safe)
         self._decided: Optional[TpuExec] = None
         self._decision = "undecided"
         self._lock = threading.Lock()
@@ -292,7 +294,8 @@ class TpuAdaptiveJoinExec(TpuExec):
 
         return TpuShuffledHashJoinExec(
             self.left_keys, self.right_keys, self.join_type, lex, rex,
-            condition=self.condition, partition_wise=True)
+            condition=self.condition, partition_wise=True,
+            null_safe=self.null_safe)
 
     @property
     def schema(self) -> T.Schema:
@@ -357,7 +360,8 @@ class TpuAdaptiveJoinExec(TpuExec):
                                   f"{nbytes >> 10}KiB<=thr]")
                 self._decided = TpuBroadcastHashJoinExec(
                     self.left_keys, self.right_keys, jt, lex, rex,
-                    condition=self.condition, build_side=side)
+                    condition=self.condition, build_side=side,
+                    null_safe=self.null_safe)
             else:
                 target = conf.get(ADVISORY_PARTITION_BYTES)
                 lb_list = [b for b, _ in lstats]

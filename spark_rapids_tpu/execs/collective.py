@@ -532,8 +532,12 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
 
     def __init__(self, left_keys, right_keys, join_type: str,
                  left: TpuExec, right: TpuExec, mesh,
-                 bucket_rounds: Optional[int] = None):
-        from spark_rapids_tpu.execs.join import _nullable_fields
+                 bucket_rounds: Optional[int] = None,
+                 null_safe=()):
+        from spark_rapids_tpu.execs.join import (
+            _nullable_fields,
+            normalize_null_safe,
+        )
 
         assert join_type in self.SUPPORTED_TYPES, join_type
         super().__init__(left, right)
@@ -542,6 +546,9 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
         self.left_keys = list(left_keys)
         self.right_keys = list(right_keys)
         self.join_type = join_type
+        # a NULL key hashes to one destination on both sides (the
+        # hash skips it), so a null-safe match stays shard-local
+        self.null_safe = normalize_null_safe(null_safe, len(self.left_keys))
         if join_type in ("left_semi", "left_anti"):
             self._schema = left.schema
         else:
@@ -554,8 +561,9 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
         return self._schema
 
     def node_desc(self) -> str:
-        ks = ", ".join(f"{lk.name}={rk.name}" for lk, rk in
-                       zip(self.left_keys, self.right_keys))
+        from spark_rapids_tpu.execs.join import describe_keys
+
+        ks = describe_keys(self.left_keys, self.right_keys, self.null_safe)
         return (f"TpuCollectiveHashJoinExec {self.join_type} [{ks}] "
                 f"[all_to_all x{self.num_partitions}] "
                 f"[{self._stage_desc()}]")
@@ -597,7 +605,7 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
         jt = self.join_type
         st = join_state(build, routed, bkc, skc,
                         "inner" if jt in ("left_semi", "left_anti")
-                        else jt)
+                        else jt, self.null_safe)
         if jt in ("left_semi", "left_anti"):
             keep = st.matched_s if jt == "left_semi" \
                 else (st.live_s & ~st.matched_s)
@@ -615,8 +623,11 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
     def _join_key(self) -> tuple:
         from spark_rapids_tpu.execs.jit_cache import exprs_key
 
-        return ("cjoin", self.join_type, exprs_key(self.left_keys),
-                exprs_key(self.right_keys), repr(self._schema))
+        key = ("cjoin", self.join_type, exprs_key(self.left_keys),
+               exprs_key(self.right_keys), repr(self._schema))
+        if self.null_safe:
+            key += (("null_safe", self.null_safe),)
+        return key
 
     def _materialize(self) -> list[list[ColumnarBatch]]:
         """The join stage as O(1) partitioned programs per side.  A

@@ -30,7 +30,21 @@ left_semi, left_anti, cross.  Inner joins with a residual condition and
 keyless conditional inner joins (nested-loop via the constant-key cross
 trick) apply the condition as a post-filter; conditional outer joins
 fall back to the CPU engine (as the reference falls back for cases cudf
-cannot express)."""
+cannot express).
+
+**Null-safe keys.**  Each key pair is `=` (a NULL key matches nothing)
+or, where `null_safe` marks it, `<=>` (NULL equals NULL and nothing
+else): `ops.join.compute_gids` ranks the keys with grouping equality
+either way and leaves a null-safe key out of the flags that bar a row.
+`DataFrame.intersect` / `subtract` lower to `left_semi` / `left_anti`
+joins whose every key is null-safe, as Spark's
+ReplaceIntersectWithSemiJoin / ReplaceExceptWithAntiJoin do.  The flag
+is part of a program's cache key only where a key has it, so a plain
+join compiles what it always did.  `left_semi` and `left_anti` have run
+on a v5e since PR 38 (`tpcds-sf10-setops.q38-q87`: three keys, two of
+them strings); their probe programs are named apart from the other
+joins' (`__left_semi_probe`, `__left_anti_probe`, and the shared
+`__semi_compact`)."""
 
 from __future__ import annotations
 
@@ -68,6 +82,29 @@ JOIN_TYPES = ("inner", "left_outer", "right_outer", "full_outer",
               "left_semi", "left_anti", "cross")
 
 
+def normalize_null_safe(null_safe, n_keys: int) -> tuple:
+    """Which of a join's `n_keys` key pairs compare with `<=>`, one
+    bool a pair; `()` where none does, so that a plain join's cache
+    keys and descriptions are what they were before the flag."""
+    if isinstance(null_safe, bool):
+        null_safe = (null_safe,) * n_keys
+    flags = tuple(bool(f) for f in null_safe)
+    if not any(flags):
+        return ()
+    if len(flags) != n_keys:
+        raise ValueError(f"{len(flags)} null-safe flags for "
+                         f"{n_keys} join keys")
+    return flags
+
+
+def describe_keys(left_keys, right_keys, null_safe) -> str:
+    """`a=b, c<=>d`: a join's key pairs, as `explain()` prints them."""
+    return ", ".join(
+        f"{l.name}{'<=>' if at < len(null_safe) and null_safe[at] else '='}"
+        f"{r.name}"
+        for at, (l, r) in enumerate(zip(left_keys, right_keys)))
+
+
 def _nullable_fields(schema: T.Schema) -> list[T.Field]:
     return [T.Field(f.name, f.dtype, True) for f in schema.fields]
 
@@ -80,10 +117,12 @@ class _HashJoinBase(TpuExec):
                  right_keys: Sequence[Expression], join_type: str,
                  left: TpuExec, right: TpuExec,
                  condition: Optional[Expression] = None,
-                 build_side: Optional[str] = None):
+                 build_side: Optional[str] = None,
+                 null_safe: Sequence[bool] = ()):
         super().__init__(left, right)
         assert join_type in JOIN_TYPES, join_type
         self.join_type = join_type
+        self.null_safe = normalize_null_safe(null_safe, len(left_keys))
         if join_type == "cross" or not left_keys:
             # cross product AND keyless conditional inner joins (nested
             # loop): equi-join on a constant key — every pair shares the
@@ -130,8 +169,7 @@ class _HashJoinBase(TpuExec):
         return self._schema
 
     def node_desc(self) -> str:
-        ks = ", ".join(f"{l.name}={r.name}" for l, r in
-                       zip(self.left_keys, self.right_keys))
+        ks = describe_keys(self.left_keys, self.right_keys, self.null_safe)
         return f"{self.name} {self.join_type} [{ks}]"
 
     def additional_metrics(self):
@@ -174,7 +212,8 @@ class _HashJoinBase(TpuExec):
         self.metrics["buildRows"].add(rows)
         _trace.event("join.build", op=self.name, rows=rows,
                      capacity=b.capacity, batches=len(collected),
-                     join_type=self.join_type)
+                     join_type=self.join_type,
+                     null_safe=sum(self.null_safe))
         return b
 
     def _empty_build(self) -> ColumnarBatch:
@@ -194,7 +233,7 @@ class _HashJoinBase(TpuExec):
         jt = "left_outer" if self.join_type in (
             "left_outer", "right_outer", "full_outer") else "inner" \
             if self.join_type == "cross" else self.join_type
-        st = join_state(build, stream, bkc, skc, jt)
+        st = join_state(build, stream, bkc, skc, jt, self.null_safe)
         total = jnp.sum(st.cnt_s).astype(jnp.int32)
         return st, total
 
@@ -225,6 +264,8 @@ class _HashJoinBase(TpuExec):
                 # not share programs
                 repr(self.children[0].schema), repr(self.children[1].schema),
                 repr(self._schema))
+            if self.null_safe:
+                key = self._ck = key + (("null_safe", self.null_safe),)
         return key
 
     def _jit_expand(self, out_cap: int):
@@ -295,13 +336,17 @@ class _HashJoinBase(TpuExec):
         from spark_rapids_tpu.execs.jit_cache import cached_jit
         from spark_rapids_tpu.parallel import pipeline as P
 
-        jit_probe = cached_jit(self._cache_key() + ("probe",),
-                               lambda: self._probe, op=self.name)
+        sizes_output = self.join_type not in ("left_semi", "left_anti")
+        # a semi or anti probe's key leads with a tag of its own, so a
+        # device trace tells its program from the other joins' probes
+        probe_key = self._cache_key() + ("probe",) if sizes_output \
+            else (f"{self.join_type}_probe",) + self._cache_key()
+        jit_probe = cached_jit(probe_key, lambda: self._probe,
+                               op=self.name)
         jit_semi_compact = cached_jit(
             ("semi_compact",), lambda: lambda stream, keep:
             stream.compact(keep), op=self.name)
         matched_b_acc = None
-        sizes_output = self.join_type not in ("left_semi", "left_anti")
         chunk = get_conf().get(JOIN_OUTPUT_CHUNK_ROWS)
 
         build = build.with_device_num_rows()
@@ -316,6 +361,7 @@ class _HashJoinBase(TpuExec):
             out = None
             with MetricTimer(self.metrics[TOTAL_TIME], op=self.name,
                              join_type=self.join_type,
+                             null_safe=sum(self.null_safe),
                              capacity=stream.capacity) as t:
                 stream = stream.with_device_num_rows()
                 st, total = jit_probe(build, stream)

@@ -390,9 +390,10 @@ class _Parser:
 
     def parse_select(self, sub: bool = False) -> dict:
         """One full query: [WITH name AS (...), ...]
-        core (UNION [ALL] core)* ORDER BY/LIMIT.  `sub` parses a
-        parenthesized subquery (stops at the closing paren instead of
-        requiring end-of-input)."""
+        term ((UNION [ALL] | INTERSECT | EXCEPT | MINUS) term)*
+        ORDER BY/LIMIT, a term a SELECT core or a parenthesized full
+        query.  `sub` parses a parenthesized subquery (stops at the
+        closing paren instead of requiring end-of-input)."""
         ctes: list[tuple] = []
         if self.accept("with"):
             # common table expressions: each name scopes over the rest
@@ -411,20 +412,17 @@ class _Parser:
                 self.expect_op(")")
                 if not self.accept_op(","):
                     break
-        q = self._select_core()
-        unions: list[tuple] = []  # (member q dict, dedup?)
-        while self.at("union"):
+        q = self._query_term()
+        unions: list[tuple] = []  # (member q dict, dedup?, set op)
+        while self.at("union", "intersect", "except", "minus"):
+            op = {"minus": "except"}.get(self.kw(), self.kw())
             self.i += 1
             dedup = not self.accept("all")
-            if self.peek()[0] == "op" and self.peek()[1] == "(":
-                # parenthesized member: a full subquery (its own
-                # ORDER BY/LIMIT/unions allowed inside the parens)
-                self.i += 1
-                member = self.parse_select(sub=True)
-                self.expect_op(")")
-            else:
-                member = self._select_core()
-            unions.append((member, dedup))
+            if dedup:
+                self.accept("distinct")
+            elif op != "union":
+                raise SqlError(f"{op.upper()} ALL is not supported")
+            unions.append((self._query_term(), dedup, op))
         q["unions"] = unions
         q["ctes"] = ctes
         q["order_by"] = self._order_by_clause()
@@ -441,6 +439,18 @@ class _Parser:
                 t = self.peek()
                 raise SqlError(f"unexpected trailing {t[1]!r} at {t[2]}")
         return q
+
+    def _query_term(self) -> dict:
+        """One member of a set-operation chain: a SELECT core, or a
+        parenthesized full query (its own ORDER BY/LIMIT/set
+        operations allowed inside the parens)."""
+        if self.peek()[0] == "op" and self.peek()[1] == "(":
+            self.i += 1
+            member = self.parse_select(sub=True)
+            self.expect_op(")")
+            return {"paren": member, "order_by": [], "limit": None,
+                    "unions": [], "ctes": []}
+        return self._select_core()
 
     def _order_by_clause(self) -> list[tuple]:
         order_by: list[tuple] = []
@@ -576,7 +586,8 @@ class _Parser:
         if self.peek()[0] == "op" and self.peek()[1] == "(":
             # derived table: FROM ( SELECT ... ) [AS] alias
             self.i += 1
-            if self.kw() != "select":
+            if self.kw() != "select" and not (
+                    self.peek()[0] == "op" and self.peek()[1] == "("):
                 raise SqlError(
                     f"expected SELECT in derived table at "
                     f"{self.peek()[2]}")
@@ -999,6 +1010,7 @@ class _Parser:
 _CLAUSE_KWS = {"from", "where", "group", "having", "order", "limit",
                "as", "on", "join", "inner", "left", "right", "full",
                "and", "or", "not", "asc", "desc", "nulls", "union",
+               "intersect", "except", "minus",
                "when", "then", "else", "end", "between", "in", "like",
                "is", "by"}
 _TABLE_STOP_KWS = _CLAUSE_KWS
@@ -1228,25 +1240,29 @@ class SqlSession:
         for cname, cq in q.get("ctes") or []:
             scope[cname.lower()] = self._lower(cq, scope)
         if q.get("unions"):
-            # left-associative UNION chain; plain UNION dedups (Spark's
-            # Distinct over Union), outer ORDER BY/LIMIT bind the chain
+            # a set-operation chain, left-associative, INTERSECT
+            # binding tighter than UNION and EXCEPT (the standard's and
+            # Spark's precedence); plain UNION dedups (Spark's Distinct
+            # over Union), INTERSECT and EXCEPT are the DISTINCT forms
+            # (DataFrame.intersect / subtract); outer ORDER BY/LIMIT
+            # bind the chain
             core = dict(q, unions=[], order_by=[], limit=None, ctes=[])
-            out = self._lower(core, scope)
-            for member, dedup in q["unions"]:
+            terms = [(self._lower(core, scope), None, None)]
+            for member, dedup, op in q["unions"]:
                 m = self._lower(member, scope)
-                try:
-                    # DataFrame.union validates column count and applies
-                    # WidenSetOperationTypes at the engine layer;
-                    # surface its deliberate analysis failures as
-                    # SqlError (incidental TypeErrors still propagate)
-                    out = out.union(m)
-                except AnalysisException as e:
-                    raise SqlError(str(e)) from None
-                if dedup:
-                    out = out.group_by(
-                        *[B.ColumnReference(f.name)
-                          for f in out.schema.fields]).agg()
+                if op == "intersect":
+                    left, ldedup, lop = terms[-1]
+                    terms[-1] = (self._set_op(left, m, op, True),
+                                 ldedup, lop)
+                else:
+                    terms.append((m, dedup, op))
+            out = terms[0][0]
+            for m, dedup, op in terms[1:]:
+                out = self._set_op(out, m, op, dedup)
             return self._order_and_limit(out, q)
+        if q.get("paren") is not None:
+            return self._order_and_limit(
+                self._lower(q["paren"], scope), q)
 
         # resolve tables and alias -> column-set mapping (a table name
         # may be a parsed derived-table subquery)
@@ -1583,6 +1599,22 @@ class SqlSession:
             no_insub(e, "ORDER BY")
         for _how, _tr, on in q["joins"]:
             no_insub(on, "JOIN ON")
+
+    @staticmethod
+    def _set_op(left, right, op: str, dedup: bool):
+        """One set operation through the DataFrame API, which checks
+        the column count and applies WidenSetOperationTypes at the
+        engine layer; its deliberate analysis failures surface as
+        SqlError (incidental TypeErrors still propagate)."""
+        try:
+            if op == "intersect":
+                return left.intersect(right)
+            if op == "except":
+                return left.subtract(right)
+            out = left.union(right)
+        except AnalysisException as e:
+            raise SqlError(str(e)) from None
+        return out.distinct() if dedup else out
 
     def _order_and_limit(self, out, q: dict):
         """Outer ORDER BY (names or 1-based ordinals) + LIMIT."""

@@ -21,7 +21,10 @@ here is different by construction:
    with a bigger bucket).
 
 NULL join keys never match (SQL equality), are excluded from counts,
-and surface only through the outer-join unmatched paths."""
+and surface only through the outer-join unmatched paths.  A key the
+caller marks null-safe (`<=>`: INTERSECT and EXCEPT compare so) keeps
+the rank the sort gave it, where NULL already equals NULL, and bars no
+row."""
 
 from __future__ import annotations
 
@@ -69,8 +72,11 @@ def _concat_key_cols(build: list[AnyColumn], stream: list[AnyColumn]
 
 
 def compute_gids(build_keys: list[AnyColumn], stream_keys: list[AnyColumn],
-                 live_b: jax.Array, live_s: jax.Array):
-    """Dense rank over the union of both sides' keys.
+                 live_b: jax.Array, live_s: jax.Array,
+                 null_safe: Sequence[bool] = ()):
+    """Dense rank over the union of both sides' keys.  The rank is SQL
+    grouping equality (NULL equals NULL); `null_b` / `null_s` then bar
+    every row with a NULL in a key that is not `null_safe`.
 
     Returns (gid_b, gid_s, null_b, null_s, n_combined_capacity)."""
     cap_b = live_b.shape[0]
@@ -100,8 +106,9 @@ def compute_gids(build_keys: list[AnyColumn], stream_keys: list[AnyColumn],
     # invert permutation
     gid = jnp.zeros((capc,), jnp.int32).at[perm].set(gid_sorted)
     null_flags = jnp.zeros((capc,), bool)
-    for c in combined:
-        null_flags = null_flags | ~c.validity
+    for at, c in enumerate(combined):
+        if not (at < len(null_safe) and null_safe[at]):
+            null_flags = null_flags | ~c.validity
     return (gid[:cap_b], gid[cap_b:], null_flags[:cap_b],
             null_flags[cap_b:], capc)
 
@@ -125,11 +132,12 @@ class JoinState:
 def join_state(build: ColumnarBatch, stream: ColumnarBatch,
                build_key_cols: list[AnyColumn],
                stream_key_cols: list[AnyColumn],
-               join_type: str) -> JoinState:
+               join_type: str,
+               null_safe: Sequence[bool] = ()) -> JoinState:
     live_b = build.row_mask()
     live_s = stream.row_mask()
     gid_b, gid_s, null_b, null_s, capc = compute_gids(
-        build_key_cols, stream_key_cols, live_b, live_s)
+        build_key_cols, stream_key_cols, live_b, live_s, null_safe)
 
     joinable_b = live_b & ~null_b
     joinable_s = live_s & ~null_s

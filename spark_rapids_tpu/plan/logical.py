@@ -559,12 +559,20 @@ class Join(LogicalPlan):
     def __init__(self, left: LogicalPlan, right: LogicalPlan,
                  left_keys: Sequence[Expression],
                  right_keys: Sequence[Expression], join_type: str,
-                 condition: Optional[Expression] = None):
-        from spark_rapids_tpu.execs.join import JOIN_TYPES, _nullable_fields
+                 condition: Optional[Expression] = None,
+                 null_safe: Sequence[bool] = ()):
+        from spark_rapids_tpu.execs.join import (
+            JOIN_TYPES,
+            _nullable_fields,
+            normalize_null_safe,
+        )
 
         assert join_type in JOIN_TYPES, join_type
         self.children = [left, right]
         self.join_type = join_type
+        #: which key pairs compare with `<=>` (NULL equals NULL), one
+        #: bool a pair; `()` where none does
+        self.null_safe = normalize_null_safe(null_safe, len(left_keys))
         self.left_keys = [bind_references(k, left.schema) for k in left_keys]
         self.right_keys = [bind_references(k, right.schema)
                            for k in right_keys]
@@ -587,10 +595,53 @@ class Join(LogicalPlan):
         return self._schema
 
     def node_desc(self) -> str:
-        ks = ", ".join(f"{l.name}={r.name}" for l, r in
-                       zip(self.left_keys, self.right_keys))
+        from spark_rapids_tpu.execs.join import describe_keys
+
+        ks = describe_keys(self.left_keys, self.right_keys, self.null_safe)
         c = f" cond={self.condition!r}" if self.condition is not None else ""
         return f"Join {self.join_type} [{ks}]{c}"
+
+
+def _all_columns(plan: LogicalPlan) -> list[Expression]:
+    """The plan's output columns by position (a name may stand twice)."""
+    from spark_rapids_tpu.exprs.base import BoundReference
+
+    return [BoundReference(i, f.dtype, f.nullable, f.name)
+            for i, f in enumerate(plan.schema.fields)]
+
+
+def is_distinct(plan: LogicalPlan) -> bool:
+    """No two rows of the plan's output are equal, by its shape: an
+    aggregate grouped by exactly its output columns, or a semi or anti
+    join that keeps rows of such a side."""
+    if isinstance(plan, Aggregate):
+        return bool(plan.groups) and not plan.aggs
+    if isinstance(plan, Join) and plan.join_type in ("left_semi",
+                                                     "left_anti"):
+        return is_distinct(plan.children[0])
+    return False
+
+
+def distinct(plan: LogicalPlan) -> LogicalPlan:
+    """SELECT DISTINCT *: an aggregate of every column and no aggregate
+    function (NULL is a value of a group key), or the plan itself where
+    its shape already says so."""
+    if is_distinct(plan):
+        return plan
+    return Aggregate(_all_columns(plan), [], plan)
+
+
+def set_operation(left: LogicalPlan, right: LogicalPlan,
+                  join_type: str) -> LogicalPlan:
+    """INTERSECT (`left_semi`) or EXCEPT (`left_anti`), the DISTINCT
+    forms, as Spark's optimizer lowers them
+    (ReplaceIntersectWithSemiJoin, ReplaceExceptWithAntiJoin): a
+    distinct over a join of the sides' columns by position, every key
+    `<=>`.  The sides have as many columns, of the same types."""
+    assert join_type in ("left_semi", "left_anti"), join_type
+    keys = _all_columns(left)
+    return distinct(Join(left, right, keys, _all_columns(right), join_type,
+                         null_safe=(True,) * len(keys)))
 
 
 class Window(LogicalPlan):

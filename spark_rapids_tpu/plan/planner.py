@@ -938,7 +938,8 @@ def _plan_join(p: L.Join, kids: list[TpuExec]) -> TpuExec:
         side = min(candidates, key=lambda c: c[1])[0]
         return TpuBroadcastHashJoinExec(
             p.left_keys, p.right_keys, jt, kids[0], kids[1],
-            condition=p.condition, build_side=side)
+            condition=p.condition, build_side=side,
+            null_safe=p.null_safe)
 
     # partition-wise shuffled join: only for real equi-keys with equal
     # key dtypes on both sides (hash-parity requires identical physical
@@ -968,7 +969,8 @@ def _plan_join(p: L.Join, kids: list[TpuExec]) -> TpuExec:
             return TpuCollectiveHashJoinExec(
                 p.left_keys, p.right_keys, jt, kids[0], kids[1],
                 transport.mesh,
-                bucket_rounds=stage_bucket_rounds(conf))
+                bucket_rounds=stage_bucket_rounds(conf),
+                null_safe=p.null_safe)
     if key_dtypes_match and (kids[0].num_partitions > 1
                              or kids[1].num_partitions > 1):
         # EnsureRequirements: a child already hash-partitioned on these
@@ -1000,14 +1002,15 @@ def _plan_join(p: L.Join, kids: list[TpuExec]) -> TpuExec:
             # partitioning is fixed by the producing stage)
             return TpuAdaptiveJoinExec(
                 p.left_keys, p.right_keys, jt, lex, rex,
-                condition=p.condition)
+                condition=p.condition, null_safe=p.null_safe)
         return TpuShuffledHashJoinExec(
             p.left_keys, p.right_keys, jt, lex, rex,
-            condition=p.condition, partition_wise=True)
+            condition=p.condition, partition_wise=True,
+            null_safe=p.null_safe)
 
     return TpuShuffledHashJoinExec(
         p.left_keys, p.right_keys, jt, kids[0], kids[1],
-        condition=p.condition)
+        condition=p.condition, null_safe=p.null_safe)
 
 
 def _hash_satisfies(exec_: TpuExec, keys):

@@ -414,10 +414,12 @@ def _probe_scan_targets(node, ordinal: int):
     return out
 
 
-def _eligible_key_pairs(left_keys, right_keys, build_is_right: bool):
+def _eligible_key_pairs(left_keys, right_keys, build_is_right: bool,
+                        null_safe=()):
     """[(key_index, build_key_expr, probe_key_ordinal, dtype)] for key
     columns a filter can be built+pushed for: matching supported
-    dtypes, probe side a plain bound column."""
+    dtypes, probe side a plain bound column, and a plain `=` (a filter
+    drops the probe side's NULL keys, which a `<=>` key may match)."""
     from spark_rapids_tpu.exprs.base import BoundReference
 
     build_keys = right_keys if build_is_right else left_keys
@@ -425,6 +427,8 @@ def _eligible_key_pairs(left_keys, right_keys, build_is_right: bool):
     out = []
     for i, (bk, pk) in enumerate(zip(build_keys, probe_keys)):
         if not isinstance(pk, BoundReference):
+            continue
+        if i < len(null_safe) and null_safe[i]:
             continue
         try:
             bdt, pdt = bk.dtype, pk.dtype
@@ -476,7 +480,7 @@ def inject_runtime_filters(root, conf) -> list[RuntimeFilter]:
         if jt not in ELIGIBLE_JOIN_TYPES:
             continue
         pairs = _eligible_key_pairs(left_keys, right_keys,
-                                    build_is_right)
+                                    build_is_right, node.null_safe)
         if not pairs:
             continue
         build_child = node.children[build_idx]

@@ -900,7 +900,13 @@ class DataFrame:
              = None, how: str = "inner",
              left_on: Optional[Sequence[ExprLike]] = None,
              right_on: Optional[Sequence[ExprLike]] = None,
-             condition: Optional[Expression] = None) -> "DataFrame":
+             condition: Optional[Expression] = None,
+             null_safe: Union[bool, Sequence[bool]] = False
+             ) -> "DataFrame":
+        """`null_safe` says which key pairs compare with `<=>` (NULL
+        equals NULL and nothing else) where the others compare with
+        `=` (a NULL key matches nothing): one bool for all of them, or
+        one a pair."""
         if on is not None:
             names = [on] if isinstance(on, str) else list(on)
             lk = [ColumnReference(n) for n in names]
@@ -909,21 +915,18 @@ class DataFrame:
             lk = [_expr(e) for e in (left_on or [])]
             rk = [_expr(e) for e in (right_on or [])]
         return DataFrame(
-            L.Join(self._plan, other._plan, lk, rk, how, condition),
+            L.Join(self._plan, other._plan, lk, rk, how, condition,
+                   null_safe=null_safe),
             self._session)
 
-    def union(self, other: "DataFrame") -> "DataFrame":
-        """Spark's WidenSetOperationTypes, enforced at the engine layer
-        (every frontend funnels through here): members are coerced
-        per-column to a common type, or analysis fails.  Without this,
-        TpuUnionExec re-tags every member batch with the first member's
-        schema, silently truncating e.g. DOUBLE data shipped under an
-        INT tag.  The lint dtype-flow checker (DT001) remains the
-        backstop for hand-built L.Union plans that bypass this method."""
+    def _widened_members(self, other: "DataFrame", what: str) -> list:
+        """Both members of a set operation, columns matched by position
+        and coerced to a common type (Spark's WidenSetOperationTypes),
+        or analysis fails."""
         lf, rf = self.schema.fields, other.schema.fields
         if len(lf) != len(rf):
             raise AnalysisException(
-                f"UNION members must have the same column count "
+                f"{what} members must have the same column count "
                 f"({len(lf)} vs {len(rf)})")
         widened: list[Optional[T.DataType]] = []
         for i, (a, b) in enumerate(zip(lf, rf)):
@@ -933,14 +936,47 @@ class DataFrame:
             ct = T.common_type(a.dtype, b.dtype)
             if ct is None:
                 raise AnalysisException(
-                    f"UNION member column {i + 1} ({a.name!r}) has "
+                    f"{what} member column {i + 1} ({a.name!r}) has "
                     f"incompatible types {a.dtype.name} and "
                     f"{b.dtype.name}")
             widened.append(ct)
+        return [_coerce_union_member(self._plan, widened),
+                _coerce_union_member(other._plan, widened)]
+
+    def union(self, other: "DataFrame") -> "DataFrame":
+        """Spark's WidenSetOperationTypes, enforced at the engine layer
+        (every frontend funnels through here): members are coerced
+        per-column to a common type, or analysis fails.  Without this,
+        TpuUnionExec re-tags every member batch with the first member's
+        schema, silently truncating e.g. DOUBLE data shipped under an
+        INT tag.  The lint dtype-flow checker (DT001) remains the
+        backstop for hand-built L.Union plans that bypass this method."""
+        return DataFrame(L.Union(self._widened_members(other, "UNION")),
+                         self._session)
+
+    def distinct(self) -> "DataFrame":
+        """SELECT DISTINCT *: the rows without their duplicates (two
+        rows with a NULL in the same column and equal elsewhere are
+        duplicates)."""
+        return DataFrame(L.distinct(self._plan), self._session)
+
+    def intersect(self, other: "DataFrame") -> "DataFrame":
+        """INTERSECT DISTINCT: the distinct rows of this frame that
+        `other` holds too, columns matched by position and widened as
+        `union` widens them, NULL equal to NULL.  Lowered as Spark
+        lowers it: a distinct over a `left_semi` join whose every key
+        is `<=>`."""
         return DataFrame(
-            L.Union([_coerce_union_member(self._plan, widened),
-                     _coerce_union_member(other._plan, widened)]),
-            self._session)
+            L.set_operation(*self._widened_members(other, "INTERSECT"),
+                            "left_semi"), self._session)
+
+    def subtract(self, other: "DataFrame") -> "DataFrame":
+        """EXCEPT DISTINCT (pyspark's `subtract`): the distinct rows of
+        this frame that `other` does not hold, NULL equal to NULL: a
+        distinct over a `left_anti` join whose every key is `<=>`."""
+        return DataFrame(
+            L.set_operation(*self._widened_members(other, "EXCEPT"),
+                            "left_anti"), self._session)
 
     def order_by(self, *keys, desc: bool = False) -> "DataFrame":
         sks = []

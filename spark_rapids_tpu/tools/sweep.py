@@ -38,8 +38,6 @@ SWEEP_ROUND = 1
 
 #: failure-taxonomy buckets, matched in order against the error text
 _TAXONOMY = [
-    ("intersect", "set-op INTERSECT not supported"),
-    ("except", "set-op EXCEPT not supported"),
     ("cannot tokenize", "tokenizer"),
     ("not in (subquery)", "NOT IN (subquery)"),
     ("month/year interval", "month/year interval on date column"),
