@@ -156,26 +156,27 @@ class _MetricReaper:
             return cls._instance
 
     def submit(self, metric: TpuMetric, t0: int, observed) -> None:
-        # derive zero-row SENTINELS from the observed arrays on the
-        # producing thread: the sentinel's completion implies the
-        # producer program finished (data dependency + in-order device
-        # execution), and the reaper exclusively owns it — polling the
-        # observed arrays themselves would race the spill store's
-        # .delete() (is_ready on a deleted PJRT buffer segfaults).
-        # Per-leaf derivation (trace.ledger.derive_sentinels): a
-        # donated fused program's output can mix live and consumed
-        # leaves, and one dead leaf must not drop the whole sample
-        from spark_rapids_tpu.trace.ledger import derive_sentinels
+        # settle on the producing thread what can be settled here: at
+        # most one zero-row SENTINEL per device set that holds an
+        # unfinished leaf (its completion bounds everything the region
+        # queued there), and none where every leaf is complete — a
+        # cache hit of resident arrays — whose time is known now
+        # (trace.ledger.sentinels_of).  The reaper exclusively owns the
+        # sentinels: polling the observed arrays themselves would race
+        # the spill store's .delete() (is_ready on a deleted PJRT
+        # buffer segfaults)
+        from spark_rapids_tpu.trace.ledger import sentinels_of
 
-        # one eager slice per output leaf, dispatched by the operator's
-        # own thread after its `exec.<op>` span has closed: tens of ms
-        # for a wide batch, so the timeline names it
-        with _trace.span("exec.sentinels", metric=metric.name):
-            sentinels = derive_sentinels(observed)
-        # no live device leaves (host-only output, or every leaf
-        # already consumed): the worker records the elapsed wall with
-        # no readiness wait — the timer still ticks, like the
-        # non-observing MetricTimer branch
+        with _trace.span("exec.sentinels", metric=metric.name) as s:
+            sentinels, leaves = sentinels_of(observed)
+            s.note(leaves=leaves, sentinels=len(sentinels),
+                   ready=not sentinels)
+        if not sentinels:
+            # complete (or host-only, or every leaf consumed): the
+            # timer ticks without a wait, like the non-observing
+            # MetricTimer branch
+            metric.add(time.perf_counter_ns() - t0)
+            return
         # correlation context crosses to the reaper thread by capture
         ctx = _trace.current_context() if _trace.TRACER.enabled else None
         self._q.put((metric, t0, sentinels, ctx))
@@ -219,7 +220,8 @@ class MetricTimer:
     JAX dispatch is asynchronous; to make `totalTime` mean device time the
     timed region registers its output via `observe(batch)` and the elapsed
     time is recorded when the output's device work completes (measured on
-    a background thread so the pipeline keeps overlapping).  Disable via
+    a background thread so the pipeline keeps overlapping), or at once
+    when the output is already complete.  Disable via
     spark.rapids.tpu.sql.metrics.deviceSync to time dispatch only.
 
     With `op` set (the owning exec's name) and tracing enabled, the

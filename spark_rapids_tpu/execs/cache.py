@@ -66,10 +66,12 @@ class TpuCacheExec(TpuExec):
             for h in parts[p]:
                 with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
                     out = t.observe(h.get())
-                    # keep the entry spillable between queries: the
-                    # consumer's pipeline holds the device arrays it
-                    # needs; the store may re-spill afterwards
-                    h.unpin()
+                # keep the entry spillable between queries: the
+                # consumer's pipeline holds the device arrays it needs;
+                # the store may re-spill afterwards.  Unpinned after the
+                # timed region has read the arrays' readiness, so no
+                # spill deletes them under that read
+                h.unpin()
                 self.metrics["cacheHits"].add(1)
                 yield self._count_output(out)
             return

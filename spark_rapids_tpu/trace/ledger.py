@@ -255,37 +255,78 @@ def observe_occupancy(args: tuple) -> tuple[int, int]:
 
 
 def derive_sentinels(out: Any) -> list:
-    """Zero-row sentinel slices for every live device-array leaf of a
-    program output pytree (the sentinel's completion implies the
-    program finished — data dependency + in-order device execution —
-    and the settle worker exclusively owns it, so polling never races
-    the spill store's .delete()).
+    """The zero-row sentinel slices that settle a program output pytree:
+    at most ONE per distinct device set, and none for a device set
+    whose live leaves are all complete (see :func:`sentinels_of`)."""
+    return sentinels_of(out)[0]
+
+
+def sentinels_of(out: Any) -> tuple[list, int]:
+    """``(sentinels, live_leaves)`` for a program output pytree.
+
+    A sentinel is an eager ``x[:0]``: itself a program, enqueued on its
+    leaf's devices after everything dispatched there before it, so its
+    completion bounds ALL of that work (the device runs programs in
+    order) — one per device set is enough.  A device set whose live
+    leaves are all ready needs none: its work has retired and the
+    settle time is the host's, known now.  One that holds an unfinished
+    leaf is sliced at its LAST live leaf of rank one or more (a 0-d
+    leaf, the last only where it is alone, takes a reshape and a slice:
+    two programs), whichever leaf is unfinished: the slice's shape then
+    follows the output's structure and not the timing, so warm-up
+    compiles every slice a run needs (a slice of whichever leaf
+    happened to be unfinished compiled new shapes inside
+    `tpch-sf10.scan`'s measured window: PERF.md section 6, PR 39).  A
+    mesh array is one leaf over all its chips, so a stage output takes
+    one sentinel (its eager slice, as ever, lands on the first shard's
+    device).  The settle worker / metric reaper exclusively owns the
+    sentinels, so polling never races the spill store's .delete()
+    (``is_ready`` on a deleted buffer segfaults, which is why readiness
+    is read here, on the producing thread, after ``is_deleted``).
+
+    Fidelity: host-to-device transfers (an upload, a batch rebuilt from
+    a spill) run beside the programs, leaf by leaf, and need not finish
+    in order; such a region settles on its last leaf's transfer plus
+    everything queued before it, not on the last transfer to finish.
 
     PER-LEAF fault isolation: under buffer donation a fused program's
     output can mix live leaves with leaves the caller already consumed
     (donated into the next program, or passed through from a donated
-    input) — one dead leaf must not throw away every usable sentinel,
-    or the donated fused program silently settles \"as host\" and its
-    device-busy time vanishes from the ledger (the warm-roofline
-    number ROADMAP #2 is judged on).  The retained leaves still bound
-    the program's completion: the device runs programs in order, so
-    ANY output leaf's readiness implies the whole program retired."""
+    input) — one dead leaf is skipped, never fatal, or the donated
+    fused program silently settles \"as host\" and its device-busy
+    time vanishes from the ledger."""
     import jax
 
     try:
         leaves = jax.tree_util.tree_leaves(out)
     except Exception:
-        return []
-    sentinels = []
+        return [], 0
+    groups: dict = {}  # device set -> [the leaf to slice, any unfinished]
+    live = 0
     for x in leaves:
         if not isinstance(x, jax.Array):
+            continue
+        try:
+            if x.is_deleted():
+                continue
+            group = groups.setdefault(frozenset(x.sharding.device_set),
+                                      [x, False])
+            if x.ndim > 0 or group[0].ndim == 0:
+                group[0] = x
+            group[1] = group[1] or not x.is_ready()
+            live += 1
+        except Exception:
+            continue  # this leaf is gone; the survivors still settle
+    sentinels = []
+    for x, unfinished in groups.values():
+        if not unfinished:
             continue
         try:
             sentinels.append(x[:0] if x.ndim > 0
                              else x.reshape((1,))[:0])
         except Exception:
-            continue  # this leaf is gone; the survivors still settle
-    return sentinels
+            continue
+    return sentinels, live
 
 
 class _SettleWorker:

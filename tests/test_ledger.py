@@ -18,6 +18,7 @@ The acceptance surface:
 
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
@@ -191,6 +192,37 @@ def test_reset_rekeys_wrapper_cells():
 
 # -- donation-safe settlement ------------------------------------------- #
 
+@contextlib.contextmanager
+def _pending():
+    """``(sharded, one)``: a leaf over devices 0 and 1 and its shard on
+    device 0, neither complete until the block exits — the program
+    waits on a host callback.  Two devices, because XLA's CPU client
+    runs a one-device program with a callback inline, at dispatch."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    devices = jax.devices()
+    if len(devices) < 2:
+        pytest.skip("needs two devices")
+    gate = threading.Event()
+
+    def wait(x):
+        gate.wait(30.0)
+        return x
+
+    sharding = NamedSharding(Mesh(np.array(devices[:2]), ("d",)),
+                             PartitionSpec("d"))
+    held = jax.jit(lambda x: jax.pure_callback(
+        wait, jax.ShapeDtypeStruct(x.shape, x.dtype), x),
+        out_shardings=sharding)(jnp.arange(16))
+    try:
+        yield held, held.addressable_shards[0].data
+    finally:
+        gate.set()
+        held.block_until_ready()
+
+
 def test_derive_sentinels_retains_live_leaves():
     """THE donation-attribution regression (ISSUE 11 satellite): a
     program output mixing a dead (deleted/donated) leaf with live
@@ -199,18 +231,108 @@ def test_derive_sentinels_retains_live_leaves():
     silently dropping a donated fused program's device-busy time."""
     import jax.numpy as jnp
 
-    live = jnp.arange(16)
     dead = jnp.arange(8) + 1
     dead.block_until_ready()
     dead.delete()
-    sentinels = ledger.derive_sentinels({"a": dead, "b": live,
-                                         "n": 7})
-    assert len(sentinels) == 1  # the live leaf survives the dead one
-    assert sentinels[0].shape == (0,)
+    with _pending() as (_, live):
+        sentinels = ledger.derive_sentinels({"a": dead, "b": live,
+                                             "n": 7})
+        assert len(sentinels) == 1  # the live leaf survives the dead one
+        assert sentinels[0].shape == (0,)
     # all-dead (or host-only) outputs degrade to no sentinels, never
     # raise
     assert ledger.derive_sentinels({"a": dead}) == []
     assert ledger.derive_sentinels(42) == []
+
+
+def test_one_sentinel_settles_every_leaf_of_a_device():
+    """The sentinel is itself a program queued after everything the
+    region dispatched on its device: 20 unfinished leaves (and a
+    finished one beside them) take one."""
+    import jax
+    import jax.numpy as jnp
+
+    done = jax.block_until_ready(jnp.arange(4))
+    with _pending() as (_, one):
+        leaves = jax.jit(lambda a: [a + i for i in range(20)])(one)
+        assert not any(x.is_ready() for x in leaves)
+        sentinels, live = ledger.sentinels_of({"done": done,
+                                               "leaves": leaves})
+        assert live == 21 and len(sentinels) == 1
+        assert sentinels[0].sharding.device_set == one.sharding.device_set
+        assert not sentinels[0].is_ready()
+
+
+def test_the_sentinel_is_the_last_leaf_whichever_is_unfinished():
+    """The slice's shape follows the output's structure, not the
+    timing: an unfinished leaf before a finished one still takes the
+    finished one's slice, so warm-up has compiled it.  A 0-d leaf (a
+    batch's row count, last in its pytree) is passed over for one of
+    rank one or more, whose slice is one program where its is two."""
+    import jax
+    import jax.numpy as jnp
+
+    done = jax.block_until_ready(jnp.ones((4, 3), jnp.float32))
+    count = jax.block_until_ready(jnp.int32(4))
+    with _pending() as (_, one):
+        pending = one + 1
+        for out, sliced in (([pending, done], done),
+                            ([done, pending], pending),
+                            ([pending, done, count], done)):
+            (sentinel,) = ledger.derive_sentinels(out)
+            assert sentinel.shape == (0,) + sliced.shape[1:]
+            assert sentinel.dtype == sliced.dtype
+        (sentinel,) = ledger.derive_sentinels([one.sum()])
+        assert sentinel.shape == (0,)
+
+
+def test_a_complete_output_needs_no_sentinel():
+    import jax
+    import jax.numpy as jnp
+
+    out = jax.block_until_ready({"a": jnp.arange(8), "b": jnp.ones(4),
+                                 "n": 3})
+    assert ledger.derive_sentinels(out) == []
+    assert ledger.sentinels_of(out) == ([], 2)
+
+
+def test_each_device_set_takes_its_own_sentinel():
+    """A sharded leaf and a one-device leaf: two device sets, two
+    sentinels (a mesh array is ONE leaf over all its chips)."""
+    with _pending() as (sharded, one):
+        assert len(ledger.derive_sentinels([sharded, sharded * 2])) == 1
+        assert len(ledger.derive_sentinels([sharded, one,
+                                            sharded * 2])) == 2
+
+
+def test_a_timed_region_over_a_complete_batch_ticks_at_once():
+    """A cache hit of resident arrays: the region's time is known when
+    it closes, so the metric ticks on this thread and the reaper stays
+    idle; an unfinished batch still ticks only at completion."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.execs.base import (MetricTimer, TpuMetric,
+                                             _MetricReaper)
+
+    reaper = _MetricReaper.get()
+    reaper.flush()
+    batch = jax.block_until_ready([jnp.arange(64), jnp.ones(64)])
+    metric = TpuMetric("totalTime", "ESSENTIAL")
+    with MetricTimer(metric) as t:
+        t.observe(batch)
+    assert metric.value > 0
+    assert reaper._q.unfinished_tasks == 0
+    reaper.flush()
+
+    pending = TpuMetric("totalTime", "ESSENTIAL")
+    with _pending() as (_, one):
+        with MetricTimer(pending) as t:
+            t.observe([one + 1, one + 2])
+        assert reaper._q.unfinished_tasks == 1
+        assert pending.value == 0  # dispatch-to-completion, not dispatch
+    reaper.flush()
+    assert pending.value > 0
 
 
 def test_donated_program_settles_device_time():
@@ -224,20 +346,21 @@ def test_donated_program_settles_device_time():
 
     ledger.enable()
     entry = ledger.LEDGER.entry(("fusedenc", "t"), "T", donated=True)
-    live = jnp.arange(1 << 16) * 3
     dead = jnp.arange(8)
     dead.block_until_ready()
     dead.delete()
-    # THE regression contract: per-leaf fault isolation.  The old
-    # all-or-nothing derivation returned [] the moment any leaf was
-    # dead, so the settle worker stamped completion at submit time
-    # ("as host") and the fused program's busy time vanished.  The
-    # live sibling must survive as a sentinel.
-    sentinels = ledger.derive_sentinels({"a": dead, "b": live})
-    assert len(sentinels) == 1 and sentinels[0].shape == (0,)
-    t0 = _time.perf_counter_ns()
-    ledger.LEDGER._settle.submit(entry, t0, {"a": dead, "b": live},
-                                 None)
+    with _pending() as (_, held):
+        live = held * 3
+        # THE regression contract: per-leaf fault isolation.  The old
+        # all-or-nothing derivation returned [] the moment any leaf was
+        # dead, so the settle worker stamped completion at submit time
+        # ("as host") and the fused program's busy time vanished.  The
+        # live sibling must survive as a sentinel.
+        sentinels = ledger.derive_sentinels({"a": dead, "b": live})
+        assert len(sentinels) == 1 and sentinels[0].shape == (0,)
+        t0 = _time.perf_counter_ns()
+        ledger.LEDGER._settle.submit(entry, t0, {"a": dead, "b": live},
+                                     None)
     assert ledger.LEDGER.flush(timeout=30.0)
     snap = ledger.snapshot()
     e = snap[ledger.program_key_str(("fusedenc", "t"))]

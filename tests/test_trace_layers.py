@@ -147,17 +147,24 @@ def test_task_threads_are_named_and_carry_the_query(two_files):
 
 
 def test_deriving_sentinels_is_a_span_of_the_operators_thread(two_files):
-    """A timed region hands the reaper one zero-row slice per output
-    leaf, sliced on the operator's thread after `exec.<op>` closed."""
+    """A timed region hands the reaper at most one zero-row slice per
+    device set of its output, and none where every leaf is already
+    complete, read on the operator's thread after `exec.<op>` closed:
+    the span says the live leaves it saw, the slices it dispatched,
+    and whether none was needed."""
     events, session = _collect(two_files)
     query_id = session.history.events[-1].query_id
     derived = [e for e in events if e.name == "exec.sentinels"]
     plan_threads = {e.tid for e in events
                     if e.name.startswith("exec.") and "op" in e.attrs}
     assert derived
+    assert sum(e.attrs["leaves"] for e in derived) > 0
     for e in derived:
         assert e.tid in plan_threads and e.attrs["query_id"] == query_id
         assert e.attrs["metric"]
+        # one device: one slice bounds every leaf of the region
+        assert 0 <= e.attrs["sentinels"] <= min(1, e.attrs["leaves"])
+        assert e.attrs["ready"] is (e.attrs["sentinels"] == 0)
         timed = [x for x in events if x.tid == e.tid and "op" in x.attrs
                  and x.name.startswith("exec.") and x.end_ns <= e.ts_ns]
         assert timed, "no exec.<op> span closed before its sentinels"
