@@ -1947,6 +1947,8 @@ def _join_cpu(plan: L.Join) -> pa.Table:
             lkeys.append(ln)
             rkeys.append(rn)
         left, right = tmpl, tmpr
+    if plan.condition is not None and jt in ("left_semi", "left_anti"):
+        return _conditional_semi_cpu(plan, left, right, lkeys, rkeys)
     pa_type = {"inner": "inner", "left_outer": "left outer",
                "right_outer": "right outer", "full_outer": "full outer",
                "left_semi": "left semi", "left_anti": "left anti"}[jt]
@@ -1969,6 +1971,39 @@ def _join_cpu(plan: L.Join) -> pa.Table:
     if plan.condition is not None:
         mask = pc.fill_null(cpu_eval(plan.condition, out), False)
         out = out.filter(mask)
+    return out.cast(schema_to_arrow(plan.schema))
+
+
+def _conditional_semi_cpu(plan: L.Join, left: pa.Table, right: pa.Table,
+                          lkeys: list, rkeys: list) -> pa.Table:
+    """EXISTS / NOT EXISTS with a residual condition: the inner join,
+    filtered by the condition over both sides' columns, then the left
+    rows that have (semi) or have not (anti) a passing pair, by row
+    identity and in the left's order.  A NULL condition is not TRUE; a
+    left row with a NULL key has no pair, so the anti join keeps it."""
+    n_left = len(plan.children[0].schema.fields)
+    n_right = len(plan.children[1].schema.fields)
+    ids = pa.array(np.arange(left.num_rows, dtype=np.int64))
+    # positional names: the sides may share column names
+    lt = pa.Table.from_arrays(
+        [left.column(i) for i in range(n_left)]
+        + [left.column(k) for k in lkeys] + [ids],
+        names=[f"__l{i}" for i in range(n_left)] + lkeys + ["__row"])
+    rt = pa.Table.from_arrays(
+        [right.column(i) for i in range(n_right)]
+        + [right.column(k) for k in rkeys],
+        names=[f"__r{i}" for i in range(n_right)] + rkeys)
+    pairs = lt.join(rt, keys=lkeys, right_keys=rkeys, join_type="inner",
+                    coalesce_keys=False)
+    joined = pa.Table.from_arrays(
+        [pairs.column(f"__l{i}") for i in range(n_left)]
+        + [pairs.column(f"__r{i}") for i in range(n_right)],
+        names=[f"c{i}" for i in range(n_left + n_right)])
+    passed = pc.fill_null(cpu_eval(plan.condition, joined), False)
+    hit = np.zeros(left.num_rows, bool)
+    hit[pairs.column("__row").filter(passed).to_numpy()] = True
+    keep = hit if plan.join_type == "left_semi" else ~hit
+    out = left.select(list(range(n_left))).filter(pa.array(keep))
     return out.cast(schema_to_arrow(plan.schema))
 
 

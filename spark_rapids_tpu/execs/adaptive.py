@@ -313,8 +313,11 @@ class TpuAdaptiveJoinExec(TpuExec):
         return self._template.num_partitions
 
     def node_desc(self) -> str:
+        from spark_rapids_tpu.execs.join import describe_condition
+
         return (f"TpuAdaptiveJoinExec [{self.join_type}] "
-                f"strategy={self._decision}")
+                f"strategy={self._decision}"
+                + describe_condition(self.join_type, self.condition))
 
     def additional_metrics(self):
         return [("adaptiveBroadcasts", "ESSENTIAL"),

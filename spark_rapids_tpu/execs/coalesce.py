@@ -8,7 +8,8 @@ Three reference mechanisms meet here:
   joins and aggregates run dense programs over few large blocks instead
   of many starved ones — TpuCoalesceBatchesExec below, inserted by the
   planner under spark.rapids.tpu.sql.coalesce.enabled
-  (docs/occupancy.md);
+  (docs/occupancy.md), and on the stream side of every semi or anti
+  join, whatever the conf (execs/join.py);
 - the plan shape of CoalesceExec / a SinglePartitioning exchange feeding
   a grand aggregate (ref: GpuShuffleExchangeExec.scala:80 with
   GpuSinglePartitioning) — but without the shuffle-manager detour: a
@@ -97,19 +98,25 @@ class TpuCoalesceBatchesExec(TpuExec):
       midpoint, so an OOM inside a downstream program retries on the
       original input granularity instead of arbitrary halves;
     - the output is a regular prefix-compact batch: donation,
-      speculation and the spill store see nothing new.
+      speculation and the spill store see nothing new;
+    - `counts_vary=True` is for inputs whose row counts change with the
+      data, an exchange's blocks: the concat then takes no shape from a
+      count (`concat_batches`' cached placement programs, one a piece
+      and leaf), so a new count compiles nothing.
     """
 
     def __init__(self, child: TpuExec,
                  target_rows: Optional[int] = None,
                  target_bytes: Optional[int] = None,
-                 goal_rows: Optional[int] = None):
+                 goal_rows: Optional[int] = None,
+                 counts_vary: bool = False):
         super().__init__(child)
         # goal_rows: the pre-occupancy exec's parameter name, kept for
         # callers that built plans against it
         self._target_rows = target_rows if target_rows is not None \
             else goal_rows
         self._target_bytes = target_bytes
+        self._counts_vary = counts_vary
 
     @property
     def schema(self) -> T.Schema:
@@ -151,12 +158,16 @@ class TpuCoalesceBatchesExec(TpuExec):
 
         ns = tuple(b.num_rows for b in buf)
         caps = tuple(b.capacity for b in buf)
-        # the output bucket depends on the thread's capacity POLICY
-        # (pow2 vs pow2x3), which the traced pad_capacity call reads at
-        # trace time — fold the resolved capacity into the key so
-        # sessions under different policies never share this program
-        key = ("coalesce", caps, ns, pad_capacity(sum(ns)))
-        fn = cached_jit(key, lambda: concat_batches, op=self.name)
+        if self._counts_vary:
+            def fn(parts):
+                return concat_batches(parts, op=self.name)
+        else:
+            # the output bucket depends on the thread's capacity POLICY
+            # (pow2 vs pow2x3), which the traced pad_capacity call reads
+            # at trace time — fold the resolved capacity into the key so
+            # sessions under different policies never share this program
+            key = ("coalesce", caps, ns, pad_capacity(sum(ns)))
+            fn = cached_jit(key, lambda: concat_batches, op=self.name)
         with MetricTimer(self.metrics["concatTime"], op=self.name) as t:
             out = t.observe(fn(buf))
         self.metrics["numConcats"].add(1)

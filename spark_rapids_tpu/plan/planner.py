@@ -377,9 +377,11 @@ class PlanMeta:
                 self._forbid_partition_aware(e, "join key")
                 self._forbid_ansi_risky(e, "join key")
             if p.condition is not None:
-                if p.join_type != "inner":
+                from spark_rapids_tpu.execs.join import CONDITIONAL_TYPES
+
+                if p.join_type not in CONDITIONAL_TYPES:
                     self.will_not_work(
-                        "non-inner join with residual condition")
+                        f"{p.join_type} join with residual condition")
                 else:
                     _check_expr(p.condition, conf, self.reasons)
                     self._forbid_ansi_risky(p.condition,

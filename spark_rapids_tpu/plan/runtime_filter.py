@@ -470,7 +470,10 @@ def inject_runtime_filters(root, conf) -> list[RuntimeFilter]:
             build_idx = 1
             left_keys, right_keys = node.left_keys, node.right_keys
             build_is_right = True
-        elif isinstance(node, _HashJoinBase) and node.condition is None:
+        elif isinstance(node, _HashJoinBase):
+            # a residual condition only takes pairs away: a probe row
+            # with no key match has no pair to pass it, so the key
+            # filter stays sound for the eligible types
             jt = node.join_type
             build_is_right = node.build_is_right
             build_idx = 1 if build_is_right else 0

@@ -726,33 +726,80 @@ class GroupedData:
         """count(DISTINCT x) as a two-level aggregate: group by
         (keys, x) to dedupe, then count x per key group (the
         single-distinct specialization of Spark's
-        RewriteDistinctAggregates)."""
-        from spark_rapids_tpu.exprs.aggregates import Count, CountDistinct
+        RewriteDistinctAggregates).
+
+        Beside it, aggregates whose partials merge (`sum`, `count`,
+        `count(*)`, `min`, `max`, and `avg` as a sum and a count) ride
+        the same two levels, as Spark plans one distinct with others:
+        the inner aggregate computes their partials per (keys, x) and
+        the outer merges them per keys.  A sum is then added in another
+        order than a one-level aggregate adds it, so a DOUBLE sum may
+        differ in its last bits.  Other mixes raise, by name."""
+        from spark_rapids_tpu.exprs import aggregates as AG
+        from spark_rapids_tpu.exprs.arithmetic import Divide
+        from spark_rapids_tpu.exprs.base import Literal
+        from spark_rapids_tpu.exprs.cast import Cast
+        from spark_rapids_tpu.exprs.predicates import Coalesce
         from spark_rapids_tpu.execs.jit_cache import expr_key
 
         if self._sets is not None:
             raise ValueError(
                 "count_distinct over rollup/cube is not supported yet")
-        dist = [na for na in named if isinstance(na.fn, CountDistinct)]
-        others = [na for na in named if not isinstance(na.fn, CountDistinct)]
-        if others:
-            raise ValueError(
-                "mixing count_distinct with other aggregates is not "
-                "supported yet")
+        dist = [na for na in named if isinstance(na.fn, AG.CountDistinct)]
         key0 = expr_key(dist[0].fn.child)
         if any(expr_key(na.fn.child) != key0 for na in dist[1:]):
             raise ValueError(
                 "multiple count_distinct over different expressions are "
                 "not supported yet")
+        mergeable = (AG.Sum, AG.Count, AG.CountStar, AG.Min, AG.Max,
+                     AG.Average)
+        for na in named:
+            if not isinstance(na.fn, (AG.CountDistinct,) + mergeable):
+                raise ValueError(
+                    f"count_distinct beside {na.fn.name} is not "
+                    "supported yet")
+        partials: list[NamedAgg] = []  # the inner level, per (keys, x)
+        merged: list[NamedAgg] = []  # the outer level, per keys
+        finals: list[Expression] = []  # the outputs, in the asked order
+        ref = ColumnReference
+        for i, na in enumerate(named):
+            fn = na.fn
+            if isinstance(fn, AG.CountDistinct):
+                merged.append(NamedAgg(AG.Count(ref("__dist")),
+                                       na.out_name))
+                finals.append(ref(na.out_name))
+            elif isinstance(fn, AG.Average):
+                partials += [
+                    NamedAgg(AG.Sum(Cast(fn.child, T.DOUBLE)), f"__ps{i}"),
+                    NamedAgg(AG.Count(fn.child), f"__pc{i}")]
+                merged += [NamedAgg(AG.Sum(ref(f"__ps{i}")), f"__ms{i}"),
+                           NamedAgg(AG.Sum(ref(f"__pc{i}")), f"__mc{i}")]
+                finals.append(Divide(ref(f"__ms{i}"), ref(f"__mc{i}"))
+                              .alias(na.out_name))
+            elif isinstance(fn, (AG.Count, AG.CountStar)):
+                partials.append(NamedAgg(fn, f"__p{i}"))
+                merged.append(NamedAgg(AG.Sum(ref(f"__p{i}")), f"__m{i}"))
+                # a sum of counts is NULL over an empty global input
+                finals.append(Coalesce(ref(f"__m{i}"), Literal.of(0))
+                              .alias(na.out_name))
+            else:  # sum, min, max: the partial merges by itself
+                partials.append(NamedAgg(fn, f"__p{i}"))
+                merged.append(NamedAgg(type(fn)(ref(f"__p{i}")),
+                                       na.out_name))
+                finals.append(ref(na.out_name))
         inner_x = dist[0].fn.child.alias("__dist")
-        inner = L.Aggregate(self._keys + [inner_x], [], self._df._plan)
+        inner = L.Aggregate(self._keys + [inner_x], partials,
+                            self._df._plan)
         key_names = [f.name for f in inner.schema.fields[:len(self._keys)]]
-        outer = L.Aggregate(
-            [ColumnReference(n) for n in key_names],
-            [NamedAgg(Count(ColumnReference("__dist")), na.out_name)
-             for na in dist],
-            inner)
-        return DataFrame(outer, self._df._session)
+        outer = L.Aggregate([ColumnReference(n) for n in key_names],
+                            merged, inner)
+        if all(isinstance(na.fn, (AG.CountDistinct, AG.Sum, AG.Min,
+                                  AG.Max)) for na in named):
+            # every output is the outer level's own column
+            return DataFrame(outer, self._df._session)
+        return DataFrame(
+            L.Project([ColumnReference(n) for n in key_names] + finals,
+                      outer), self._df._session)
 
     def _agg_grouping_sets(self, named: list[NamedAgg]) -> "DataFrame":
         from spark_rapids_tpu.exprs import base as B

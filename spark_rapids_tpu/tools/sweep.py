@@ -58,7 +58,7 @@ _TAXONOMY = [
     ("must appear in group by", "group-by binding"),
     ("expected", "grammar (unexpected token)"),
     ("unexpected trailing", "grammar (trailing tokens)"),
-    ("mixing count_distinct", "count(distinct) mix"),
+    ("count_distinct beside", "count(distinct) mix"),
     ("distinct unsupported", "DISTINCT aggregate"),
     ("unsupported cast type", "cast type"),
     ("unsupported interval unit", "interval unit"),

@@ -98,10 +98,17 @@ def test_count_distinct_grand(session):
 
 
 def test_count_distinct_mixed_rejected(session):
-    t = pa.table({"x": pa.array([1], pa.int64())})
-    with pytest.raises(ValueError, match="mixing count_distinct"):
-        session.create_dataframe(t).agg(
-            (count_distinct(col("x")), "d"), (sum_(col("x")), "s"))
+    """Beside a sum the distinct count lowers as Spark's single-distinct
+    rewrite; beside an aggregate whose partials do not merge it is
+    refused by name."""
+    from spark_rapids_tpu.session import first
+
+    t = pa.table({"x": pa.array([1, 1, 2], pa.int64())})
+    df = session.create_dataframe(t)
+    mixed = df.agg((count_distinct(col("x")), "d"), (sum_(col("x")), "s"))
+    assert mixed.collect().to_pylist() == [{"d": 2, "s": 4}]
+    with pytest.raises(ValueError, match="count_distinct beside first"):
+        df.agg((count_distinct(col("x")), "d"), (first(col("x")), "f"))
 
 
 def test_rollup_multi_partition(session, tmp_path):
