@@ -205,7 +205,7 @@ class ColumnarBatch:
         bucket without this."""
         if not self.columns or new_cap >= self.capacity:
             return self
-        cols = [_shrink_col(c, new_cap) for c in self.columns]
+        cols = [map_rows(c, lambda a: a[:new_cap]) for c in self.columns]
         return ColumnarBatch(cols, self.num_rows, self.schema)
 
     def slice_prefix(self, n: RowCount) -> "ColumnarBatch":
@@ -262,28 +262,26 @@ def _col_to_pylist(col, dtype: T.DataType, n: int) -> list:
     return [(vals[i].item() if valid[i] else None) for i in range(n)]
 
 
-def _shrink_col(c: AnyColumn, new_cap: int) -> AnyColumn:
-    """Slice a column to a smaller capacity (recursive for nesting)."""
+def map_rows(c: AnyColumn, fn) -> AnyColumn:
+    """`c` with `fn` applied to every array that holds a row per row
+    (data, validity, lengths, codes, nested children; recursive).  A
+    dictionary holds no rows and passes as it is."""
     if isinstance(c, StringColumn):
         return StringColumn(
-            c.chars[:new_cap], c.lengths[:new_cap], c.validity[:new_cap],
-            c.dtype,
-            c.codes[:new_cap] if c.codes is not None else None,
+            fn(c.chars), fn(c.lengths), fn(c.validity), c.dtype,
+            fn(c.codes) if c.codes is not None else None,
             c.dict_chars, c.dict_lens, c.dict_len)
     if isinstance(c, ListColumn):
-        return ListColumn(c.values[:new_cap], c.lengths[:new_cap],
-                          c.elem_validity[:new_cap],
-                          c.validity[:new_cap], c.dtype)
+        return ListColumn(fn(c.values), fn(c.lengths),
+                          fn(c.elem_validity), fn(c.validity), c.dtype)
     if isinstance(c, StructColumn):
-        return StructColumn(
-            tuple(_shrink_col(k, new_cap) for k in c.children),
-            c.validity[:new_cap], c.dtype)
+        return StructColumn(tuple(map_rows(k, fn) for k in c.children),
+                            fn(c.validity), c.dtype)
     if isinstance(c, MapColumn):
-        return MapColumn(c.keys[:new_cap], c.values[:new_cap],
-                         c.entry_validity[:new_cap], c.lengths[:new_cap],
-                         c.validity[:new_cap], c.dtype)
-    return Column(c.data[:new_cap], c.validity[:new_cap], c.dtype,
-                  c.codes[:new_cap] if c.codes is not None else None,
+        return MapColumn(fn(c.keys), fn(c.values), fn(c.entry_validity),
+                         fn(c.lengths), fn(c.validity), c.dtype)
+    return Column(fn(c.data), fn(c.validity), c.dtype,
+                  fn(c.codes) if c.codes is not None else None,
                   c.dict_values, c.dict_len)
 
 

@@ -149,9 +149,9 @@ class TpuShuffleExchangeExec(TpuExec):
             return split_batch_dispatch(batch, pid_fn(batch), n)
 
         def register_slices(subs) -> None:
-            """Host half: register the non-empty reduce slices once the
-            per-partition counts are host-side."""
-            for rid, (sub, rows) in enumerate(subs):
+            """Host half: register the non-empty reduce slices, given
+            as (partition, batch, rows) once the counts are host-side."""
+            for rid, sub, rows in subs:
                 if rows:
                     sub = sub.shrink_to_capacity(pad_capacity(rows))
                     h = store.register(
@@ -175,10 +175,10 @@ class TpuShuffleExchangeExec(TpuExec):
                     v = fut.result()
                     if has_counts:
                         register_slices(
-                            (sub, sub.num_rows) for sub in
-                            split_batch_finish(grouped, v, n))
+                            (rid, sub, sub.num_rows) for rid, sub in
+                            split_batch_finish(grouped, v, self.name))
                     else:
-                        register_slices([(grouped, int(v))])
+                        register_slices([(0, grouped, int(v))])
                 except BaseException:
                     for _rid, h, _b, _r in pending[n0:]:
                         h.close()
@@ -212,13 +212,13 @@ class TpuShuffleExchangeExec(TpuExec):
                 elif counts is None:
                     rows = P.device_read_int(grouped.num_rows,
                                              tag="exchange.split")
-                    register_slices([(grouped, rows)])
+                    register_slices([(0, grouped, rows)])
                 else:
                     counts_np = P.device_read(counts,
                                               tag="exchange.split")
                     register_slices(
-                        (sub, sub.num_rows) for sub in
-                        split_batch_finish(grouped, counts_np, n))
+                        (rid, sub, sub.num_rows) for rid, sub in
+                        split_batch_finish(grouped, counts_np, self.name))
             except BaseException:
                 if own is not None:
                     try:
@@ -396,8 +396,9 @@ class TpuShuffleExchangeExec(TpuExec):
                         lambda: lambda b, bd:
                             part.partition_ids_with_bounds(b, bd),
                         op=self.name)
-                    subs = split_batch(batch, pid_fn(batch, bounds), n)
-                    for rid, sub in enumerate(subs):
+                    subs = split_batch(batch, pid_fn(batch, bounds), n,
+                                       self.name)
+                    for rid, sub in subs:
                         rows = sub.concrete_num_rows()
                         if rows:
                             sub = sub.shrink_to_capacity(

@@ -8,7 +8,8 @@ in GpuPartitioning.scala:45-73 — cudf Table.partition + contiguousSplit).
 Here a partitioner produces per-row partition ids on device; the split
 into per-partition sub-batches reuses the stable-argsort compaction: one
 sort by pid groups rows, a sizing sync reads the per-partition counts,
-and each sub-batch is a sliced gather of the grouped batch.  Hash
+and one cached program cuts each non-empty partition out of the grouped
+batch at the capacity bucket of its count.  Hash
 partitioning is murmur3-pmod, bit-for-bit Spark-compatible (the parity
 requirement the reference calls out), so a row lands on the same
 partition index as it would under Spark CPU."""
@@ -22,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spark_rapids_tpu.columnar.batch import ColumnarBatch
+from spark_rapids_tpu.columnar.batch import ColumnarBatch, map_rows
+from spark_rapids_tpu.columnar.column import pad_capacity
 from spark_rapids_tpu.exprs.base import EvalContext, Expression, bind_references
 from spark_rapids_tpu.exprs.hashing import partition_ids
 from spark_rapids_tpu.ops.sort import stable_argsort
@@ -158,39 +160,76 @@ def split_batch_dispatch(batch: ColumnarBatch, pids: jax.Array,
     return grouped, counts
 
 
+def _cut(cap: int):
+    """The program that cuts one partition out of a grouped batch of
+    capacity C at capacity `cap`: every leaf's rows `clip(off +
+    arange(cap), 0, C - 1)`, padding rows included, the validity masked
+    from `cnt` on.  `off` and `cnt` are arguments, so one program
+    serves every partition whose count pads to `cap`."""
+    def cut(columns, off, cnt):
+        c_rows = columns[0].capacity
+        # a dynamic slice's start is clamped to C - cap; what the clamp
+        # took off shifts the rows, and those past the last row repeat it
+        shift = jnp.maximum(off - (c_rows - cap), 0)
+
+        def rows(a):
+            head = jax.lax.dynamic_slice_in_dim(a, off, cap)
+            tail = jnp.broadcast_to(head[-1:], head.shape)
+            return jax.lax.dynamic_slice_in_dim(
+                jnp.concatenate([head, tail]), shift, cap)
+
+        live = jnp.arange(cap, dtype=jnp.int32) < cnt
+        out = [map_rows(c, rows) for c in columns]
+        return [c.with_validity(c.validity & live) for c in out]
+    return cut
+
+
 def split_batch_finish(grouped: ColumnarBatch, counts_np,
-                       n_parts: int) -> list[ColumnarBatch]:
-    """Slice the per-partition batches once the counts are host-side.
-    `counts_np` is any host array-like — typically the harvested value
-    of a `device_read`/`device_read_async` on split_batch_dispatch's
-    counts (already host memory; the asarray below is a view, not a
-    device sync)."""
-    counts_np = np.asarray(counts_np)
-    offsets = np.concatenate([[0], np.cumsum(counts_np)])
-    out = []
+                       op: str | None = None
+                       ) -> list[tuple[int, ColumnarBatch]]:
+    """Cut the non-empty partitions out of a grouped batch once the
+    counts are host-side: `(partition, batch)` pairs, each batch at
+    `pad_capacity` of its count (never above the grouped capacity),
+    by one cached program a partition; an empty partition launches
+    nothing.  `counts_np` is any host array-like, typically the
+    harvested value of a `device_read`/`device_read_async` on
+    split_batch_dispatch's counts; `op` names the programs."""
+    from spark_rapids_tpu import trace as _trace
+    from spark_rapids_tpu.execs.jit_cache import cached_jit
+
+    counts = [int(c) for c in counts_np]
     cap = grouped.capacity
-    idx = jnp.arange(cap, dtype=jnp.int32)
-    for p in range(n_parts):
-        off, cnt = int(offsets[p]), int(counts_np[p])
-        take = jnp.clip(idx + off, 0, cap - 1)
-        sub = grouped.gather(take, cnt)
-        live_p = idx < cnt
-        cols = [c.with_validity(c.validity & live_p) for c in sub.columns]
-        out.append(ColumnarBatch(cols, cnt, grouped.schema))
+    leaves, tree = jax.tree_util.tree_flatten(grouped.columns)
+    key = ("cut", str(tree),
+           tuple((a.shape, a.dtype.name) for a in leaves), cap)
+    out = []
+    with _trace.span("exchange.cut", op=op, capacity=cap) as sp:
+        off = 0
+        for p, cnt in enumerate(counts):
+            if cnt:
+                cut_cap = min(pad_capacity(cnt), cap)
+                cut = cached_jit(key + (cut_cap,), lambda: _cut(cut_cap),
+                                 op=op)
+                cols = cut(grouped.columns, np.int32(off), np.int32(cnt))
+                out.append((p, ColumnarBatch(cols, cnt, grouped.schema)))
+            off += cnt
+        sp.note(parts=len(out), rows=off,
+                cut_capacity=sum(b.capacity for _, b in out))
     return out
 
 
-def split_batch(batch: ColumnarBatch, pids: jax.Array, n_parts: int
-                ) -> list[ColumnarBatch]:
-    """Group rows by partition id and slice out per-partition batches.
-    One device sort + one sizing sync per input batch (the analog of
-    cudf's Table.partition returning parts + offsets)."""
+def split_batch(batch: ColumnarBatch, pids: jax.Array, n_parts: int,
+                op: str | None = None) -> list[tuple[int, ColumnarBatch]]:
+    """Group rows by partition id and cut out the non-empty partitions
+    as `(partition, batch)` pairs.  One device sort + one sizing sync
+    per input batch (the analog of cudf's Table.partition returning
+    parts + offsets)."""
     if n_parts == 1:
         # single destination: the batch IS the slice (grand-aggregate
         # exchanges hit this constantly)
-        return [batch]
+        return [(0, batch)]
     from spark_rapids_tpu.parallel.pipeline import device_read
 
     grouped, counts = split_batch_dispatch(batch, pids, n_parts)
     counts_np = device_read(counts, tag="exchange.split")
-    return split_batch_finish(grouped, counts_np, n_parts)
+    return split_batch_finish(grouped, counts_np, op)
