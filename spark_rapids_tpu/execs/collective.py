@@ -335,6 +335,16 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
         cols = [e.eval(ctx) for e in self._agg.final_exprs]
         return ColumnarBatch(cols, merged.num_rows, self._schema)
 
+    def _key_ordinals(self) -> tuple:
+        """The child columns the grouping keys are, where every key is
+        a bare reference to one; else none."""
+        from spark_rapids_tpu.exprs.base import BoundReference
+
+        groups = self._agg.groups
+        if not all(isinstance(g, BoundReference) for g in groups):
+            return ()
+        return tuple(g.ordinal for g in groups)
+
     def _take_rollup(self):
         """How to take the grouping-set Expand directly under this
         stage as the levels of one sort, as the one-chip aggregate
@@ -389,11 +399,17 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
         def counted_partials(bucket):
             """The update program over `bucket`, its stacked partials
             cut to their counted rows, and the slot the exchange
-            leaves at: their capacity."""
+            leaves at: their capacity.  The stage entry may keep the
+            grouping keys' dictionary sidecars, and the update then
+            groups by code as the one-chip update does; the sidecars'
+            shapes key the program."""
+            xs = S.shard_stack_rounds(bucket, self.mesh,
+                                      self._key_ordinals())
+            sig = S.coded_signature(xs)
             update = S.make_update_scan_stage(
-                self.mesh, akey, self._pre, len(bucket),
-                op=self.name, donate=True)
-            partials = update(S.shard_stack_rounds(bucket, self.mesh))
+                self.mesh, akey + (("coded", sig),) if sig else akey,
+                self._pre, len(bucket), op=self.name, donate=True)
+            partials = update(xs)
             counts = S.stage_counts(partials)
             xs = self._restage((partials, counts))
             return xs, counts, S.stacked_capacity(xs), None

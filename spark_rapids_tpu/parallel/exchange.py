@@ -77,13 +77,13 @@ def take_piece(arr: jax.Array, idx: tuple):
 
 
 def _squeeze0(batch: ColumnarBatch) -> ColumnarBatch:
-    cols: list[AnyColumn] = []
-    for c in batch.columns:
-        if isinstance(c, StringColumn):
-            cols.append(StringColumn(c.chars[0], c.lengths[0], c.validity[0]))
-        else:
-            cols.append(Column(c.data[0], c.validity[0], c.dtype))
-    return ColumnarBatch(cols, batch.num_rows[0], batch.schema)
+    """One shard's piece of a per-shard batch, the shard axis dropped
+    from every array: a dictionary sidecar the stage entry carried
+    (a map-side update's grouping keys) comes along."""
+    return ColumnarBatch(
+        [jax.tree_util.tree_map(lambda x: x[0], c)
+         for c in batch.columns],
+        batch.num_rows[0], batch.schema)
 
 
 def _unsqueeze0(batch: ColumnarBatch) -> ColumnarBatch:

@@ -53,6 +53,7 @@ bench/analyze (docs/spmd.md).
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable, Optional, Sequence
 
 import jax
@@ -195,83 +196,170 @@ def _with_leaves(like: ColumnarBatch, leaves, num_rows) -> ColumnarBatch:
 
 
 def shard_stack_rounds(rounds: Sequence[Sequence[ColumnarBatch]],
-                       mesh) -> ColumnarBatch:
+                       mesh, keys: Sequence[int] = ()) -> ColumnarBatch:
     """`_stack_rounds` under ONE ``mesh.stack`` span a call, which
     says what the host did for the stage's input: the batches
-    `unify_batches` had to pad up to the common capacity, and what
+    `unify_batches` had to pad up to the common capacity, what
     `place_piece` found on its device, moved chip to chip (with the
     bytes) or uploaded (the difference of `placement.stats()` over
-    the call)."""
+    the call), and `coded_keys`, the columns whose dictionary sidecar
+    the stacked input carries.  `keys`: the ordinals of a group-by's
+    grouping keys, whose sidecars `coded_key_shapes` may keep; every
+    other entry passes none, and every other column loses its."""
+    flat = [b for shards in rounds for b in shards]
+    coded = coded_key_shapes(flat, keys)
     if not _trace.TRACER.enabled:
-        return _stack_rounds(rounds, mesh)
+        return _stack_rounds(rounds, mesh, coded)
     from spark_rapids_tpu.parallel import placement as _placement
 
-    flat = [b for shards in rounds for b in shards]
     before = _placement.stats()
     with _trace.span("mesh.stack", rounds=len(rounds),
                      shards=len(rounds[0])) as sp:
-        out = _stack_rounds(rounds, mesh)
+        out = _stack_rounds(rounds, mesh, coded)
         after = _placement.stats()
         stacked = [x.shape[2:] for x in _leaves(out)]
-        sp.note(capacity=stacked[0][0], leaves=len(stacked),
+        sp.note(capacity=stacked[0][0],
+                leaves=len(jax.tree_util.tree_leaves(out.columns)),
                 repadded=sum([x.shape for x in _leaves(b)] != stacked
                              for b in flat),
+                coded_keys=len(coded),
                 **{k: after[k] - before[k]
                    for k in ("host_uploads", "device_born",
                              "d2d_transfers", "d2d_bytes")})
     return out
 
 
-def _stack_rounds(rounds: Sequence[Sequence[ColumnarBatch]],
-                  mesh) -> ColumnarBatch:
+def _dictionary(c: AnyColumn):
+    """The dictionary array of a column's sidecar: a string's
+    characters, a number's values."""
+    return c.dict_chars if isinstance(c, StringColumn) else c.dict_values
+
+
+def coded_key_shapes(flat: Sequence[ColumnarBatch], keys: Sequence[int]
+                     ) -> dict[int, tuple]:
+    """Which grouping keys of a map-side update keep their dictionary
+    sidecar through the stage entry, so the update program groups by
+    code as the one-chip update does: ordinal -> `(k, w, dict_len)`,
+    the largest dictionary capacity among the batches, its string
+    width padded by `pad_width` (0 for a number) and the largest
+    `dict_len`.  Empty unless every non-empty batch carries a sidecar
+    on every key and `_coded_key_domains`, the rule the update program
+    applies, takes the keys at those `dict_len`s."""
+    from spark_rapids_tpu.ops.groupby import _coded_key_domains
+
+    live = [b for b in flat if b.concrete_num_rows()]
+    if not keys or not live or any(
+            getattr(b.columns[o], "codes", None) is None
+            for b in live for o in keys):
+        return {}
+    shapes: dict[int, tuple] = {}
+    probe = []
+    for o in keys:
+        cols = [b.columns[o] for b in live]
+        dicts = [_dictionary(c) for c in cols]
+        bound = max(int(d.shape[0]) if c.dict_len is None else c.dict_len
+                    for c, d in zip(cols, dicts))
+        width = pad_width(max(int(d.shape[1]) for d in dicts)) \
+            if isinstance(cols[0], StringColumn) else 0
+        shapes[o] = (max(int(d.shape[0]) for d in dicts), width, bound)
+        probe.append(dataclasses.replace(cols[0], dict_len=bound))
+    return shapes if _coded_key_domains(probe) is not None else {}
+
+
+def coded_signature(batch: ColumnarBatch) -> tuple:
+    """The static shapes of the sidecars a stacked input carries, for
+    the key of the program it enters: `(ordinal, k, w, dict_len)` a
+    carried column (`w` 0 for a number); empty where none is."""
+    return tuple(
+        (ci, int(_dictionary(c).shape[2]),
+         int(c.dict_chars.shape[3]) if isinstance(c, StringColumn) else 0,
+         c.dict_len)
+        for ci, c in enumerate(batch.columns)
+        if getattr(c, "codes", None) is not None)
+
+
+def _fit(x, shape: tuple):
+    """`x` padded with zeros up to `shape`, axis by axis."""
+    if tuple(x.shape) == shape:
+        return x
+    return jnp.pad(x, tuple((0, s - have)
+                            for s, have in zip(shape, x.shape)))
+
+
+def _stack_sidecar(rounds, mesh, ci: int, shape: tuple,
+                   cap: int) -> dict:
+    """Column `ci`'s dictionary sidecar over R rounds of n per-shard
+    batches, as `_stack_rounds` stacks the other leaves: codes int32
+    `(R, n, cap)`, the dictionary `(R, n, k, w)` (a number's
+    `(R, n, k)`), each (round, shard) with its own dictionary, which
+    the update program indexes inside its scan (the update moves no
+    row between shards).  An empty batch gets zero codes and a zero
+    dictionary; its rows are dead, so they are never read.  Returns
+    the fields to replace on the stacked column."""
+    k, w, bound = shape
+    like = next(b.columns[ci] for shards in rounds for b in shards
+                if b.concrete_num_rows())
+    if isinstance(like, StringColumn):
+        names = ("codes", "dict_chars", "dict_lens")
+        shapes = ((cap,), (k, w), (k,))
+        dtypes = (jnp.int32, jnp.uint8, jnp.int32)
+    else:
+        names = ("codes", "dict_values")
+        shapes = ((cap,), (k,))
+        dtypes = (jnp.int32, like.dict_values.dtype)
+    r_count = len(rounds)
+    per_dev = []
+    for d, dev in enumerate(mesh.devices.flat):
+        shard = [shards[d] for shards in rounds]
+        if not any(b.concrete_num_rows() for b in shard):
+            # born on its own chip: nothing to move
+            per_dev.append([jnp.zeros((r_count,) + s, t, device=dev)
+                            for s, t in zip(shapes, dtypes)])
+            continue
+        pieces = [[_fit(jnp.asarray(getattr(b.columns[ci], nm), t), s)
+                   for nm, s, t in zip(names, shapes, dtypes)]
+                  if b.concrete_num_rows() else
+                  [jnp.zeros(s, t) for s, t in zip(shapes, dtypes)]
+                  for b in shard]
+        per_dev.append([jnp.stack(x) for x in zip(*pieces)])
+    return dict(zip(names, (_assemble(mesh, list(x))
+                            for x in zip(*per_dev))), dict_len=bound)
+
+
+def _stack_rounds(rounds: Sequence[Sequence[ColumnarBatch]], mesh,
+                  coded: Optional[dict] = None) -> ColumnarBatch:
     """Assemble R rounds of n per-shard batches into ONE global sharded
     batch: every leaf becomes a (R, n, capacity, ...) jax Array under
     ``NamedSharding(mesh, P(None, "data"))``, with shard d's slice
     resident on mesh device d.  num_rows becomes an int32 (R, n)
     global array.  This is the stage INPUT contract of every SPMD
-    stage program."""
+    stage program.  The columns of `coded` (`coded_key_shapes`) keep
+    their dictionary sidecar (`_stack_sidecar`); every other loses
+    its."""
     n = int(mesh.shape[DATA_AXIS])
     flat = [b for shards in rounds for b in shards]
     assert flat and len(flat) == len(rounds) * n
     unified = unify_batches(flat)
     r_count = len(rounds)
-
-    def at(r: int, d: int) -> ColumnarBatch:
-        return unified[r * n + d]
-
-    schema = flat[0].schema
-    cols: list[AnyColumn] = []
-    for ci, c0 in enumerate(unified[0].columns):
-        if isinstance(c0, StringColumn):
-            cols.append(StringColumn(
-                _assemble(mesh, [
-                    jnp.stack([at(r, d).columns[ci].chars
-                               for r in range(r_count)])
-                    for d in range(n)]),
-                _assemble(mesh, [
-                    jnp.stack([at(r, d).columns[ci].lengths
-                               for r in range(r_count)])
-                    for d in range(n)]),
-                _assemble(mesh, [
-                    jnp.stack([at(r, d).columns[ci].validity
-                               for r in range(r_count)])
-                    for d in range(n)])))
-        else:
-            cols.append(Column(
-                _assemble(mesh, [
-                    jnp.stack([at(r, d).columns[ci].data
-                               for r in range(r_count)])
-                    for d in range(n)]),
-                _assemble(mesh, [
-                    jnp.stack([at(r, d).columns[ci].validity
-                               for r in range(r_count)])
-                    for d in range(n)]),
-                c0.dtype))
+    leaves = [_leaves(b) for b in unified]
+    stacked = [
+        _assemble(mesh, [jnp.stack([leaves[r * n + d][li]
+                                    for r in range(r_count)])
+                         for d in range(n)])
+        for li in range(len(leaves[0]))]
     num_rows = _assemble(mesh, [
-        np.asarray([at(r, d).concrete_num_rows()
+        np.asarray([flat[r * n + d].concrete_num_rows()
                     for r in range(r_count)], np.int32)
         for d in range(n)], control=True)
-    return ColumnarBatch(cols, num_rows, schema)
+    out = _with_leaves(unified[0], stacked, num_rows)
+    if coded:
+        cap = unified[0].capacity
+        cols = list(out.columns)
+        for ci, shape in coded.items():
+            cols[ci] = dataclasses.replace(
+                cols[ci], **_stack_sidecar(rounds, mesh, ci, shape, cap))
+        out = ColumnarBatch(cols, num_rows, out.schema)
+    return out
 
 
 def _pow2(rounds: int) -> int:

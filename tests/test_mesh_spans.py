@@ -143,6 +143,8 @@ def test_a_stage_names_its_host_work_one_span_a_call(session, name):
     for a in stacks:
         assert (a["rounds"], a["shards"]) == (1, N_DEV)
         assert a["leaves"] >= 4 and 0 <= a["repadded"] <= N_DEV
+        # no key of these stages arrives dictionary coded
+        assert a["coded_keys"] == 0
         assert a["capacity"] >= 1
         # every leaf of every shard was placed once, one way or another
         assert a["host_uploads"] + a["device_born"] + a["d2d_transfers"] \
@@ -183,6 +185,36 @@ def test_a_stage_names_its_host_work_one_span_a_call(session, name):
     # the older spans of the stage stand, and say whose they are too
     if name != "sort":
         assert named(f"collective.{name}.exchange")
+
+
+def _q1_shaped(s):
+    # two one-letter string keys, as q1's l_returnflag and l_linestatus:
+    # the wire ships them dictionary coded
+    rng = np.random.default_rng(17)
+    t = pa.table({"f": pa.array(rng.choice(list("RAN"), 1500)),
+                  "g": pa.array(rng.choice(list("OF"), 1500)),
+                  "v": rng.integers(0, 9, 1500).astype(np.int64)})
+    return s.create_dataframe(t).group_by(col("f"), col("g")).agg(
+        (sum_(col("v")), "q"))
+
+
+@pytest.mark.parametrize("query, coded", [(_q1_shaped, 2), (_join, 0)],
+                         ids=["q1-shaped update", "join"])
+def test_a_stack_counts_the_keys_that_keep_their_codes(session, query,
+                                                       coded):
+    """`mesh.stack` says how many grouping keys kept their dictionary
+    sidecar through the stage entry: both of a q1-shaped update's, none
+    at either side of a join, whose entries carry none."""
+    df = query(session)
+    trace.enable()
+    assert df.collect(engine="tpu").num_rows
+    stacks = [e.attrs for e in trace.snapshot() if e.name == "mesh.stack"]
+    trace.disable()
+    assert stacks and [a["coded_keys"] for a in stacks] \
+        == [coded] * len(stacks)
+    for a in stacks:
+        assert a["host_uploads"] + a["device_born"] + a["d2d_transfers"] \
+            == a["leaves"] * N_DEV
 
 
 @pytest.mark.parametrize("name", sorted(STAGES))

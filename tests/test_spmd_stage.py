@@ -724,6 +724,187 @@ def test_spmd_agg_string_keys_with_nulls_digest(collective_session,
 
 
 # ------------------------------------------------------------------ #
+# The map-side update groups by dictionary code, as on one chip
+# ------------------------------------------------------------------ #
+
+#: rows of one batch, and of a round where one shard is loaded
+CODED_ROWS = 256
+_RAN, _OF = tuple("RAN"), tuple("OF")
+#: twenty-two words, in another order than any other batch's
+_MANY = tuple(f"w{i:02d}" for i in range(20))[::-1] + ("a", "c")
+
+
+@dataclasses.dataclass(frozen=True)
+class CodedAgg:
+    """A group-by over keys that arrive dictionary coded: per batch of
+    `CODED_ROWS`, the values each key column draws from (numbers make
+    an int64 dictionary column).  One batch is one round, one loaded
+    shard beside three empty ones, unless `spread`: then the four
+    batches of a round land one a shard.  `ks`: the `dict_len` of each
+    key the update program must group by, the bucket's largest."""
+
+    batches: tuple
+    ks: tuple
+    nulls: float = 0.0
+    spread: bool = False
+    bucket: int = 8
+
+    def table(self) -> pa.Table:
+        rng = np.random.default_rng(53)
+        parts = []
+        for draw in self.batches:
+            cols = {}
+            for name, values in draw.items():
+                picked = [None if rng.random() < self.nulls else
+                          values[i] for i in rng.integers(
+                              0, len(values), CODED_ROWS)]
+                if isinstance(values[0], int):
+                    cols[name] = pa.array(picked,
+                                          pa.int64()).dictionary_encode()
+                else:
+                    cols[name] = pa.array(picked, pa.string())
+            cols["v"] = pa.array(rng.random(CODED_ROWS) * 1e3)
+            parts.append(pa.table(cols))
+        return pa.concat_tables(parts)
+
+
+CODED_AGGS = {
+    "two string keys": CodedAgg(({"a": _RAN, "b": _OF},) * 4, (8, 8),
+                                spread=True),
+    "one numeric dictionary key": CodedAgg(
+        ({"n": (7, -3, 1 << 40, 12, 0)},) * 2, (8,)),
+    "NULL keys": CodedAgg(({"a": _RAN, "b": _OF},) * 4, (8, 8),
+                          nulls=0.2, spread=True),
+    "three of four shards empty": CodedAgg(({"a": _RAN, "b": _OF},),
+                                           (8, 8)),
+    "two rounds whose dictionaries differ in size and order": CodedAgg(
+        ({"a": ("c", "a", "b"), "b": _OF},
+         {"a": _MANY, "b": ("O", "F")}), (32, 8), bucket=2),
+    "an empty round pad_rounds_pow2 adds": CodedAgg(
+        ({"a": _RAN, "b": _OF},) * 3, (8, 8)),
+}
+
+
+def _coded_conf(conf, rows: int, spread: bool, bucket: int) -> None:
+    conf.set(BATCH_KEY, rows)
+    conf.set(ROUND_KEY, 4 * rows if spread else rows)
+    conf.set(BUCKET_KEY, bucket)
+
+
+def _stacks_and_codes(df, monkeypatch):
+    """Collect `df` on the mesh with every program traced anew: (the
+    answer, the attrs of its `mesh.stack` spans, the `ks` of each
+    coded group-by the traces took, the columns of every stage input
+    `_stack_rounds` built)."""
+    from spark_rapids_tpu import trace
+    from spark_rapids_tpu.execs import jit_cache
+    from spark_rapids_tpu.ops import groupby as G
+    from spark_rapids_tpu.parallel import spmd as S
+
+    coded, stacked = [], []
+    coded_groupby, stack_rounds = G._coded_groupby, S._stack_rounds
+
+    def noting_coded(batch, key_ordinals, ks, *args):
+        coded.append(tuple(ks))
+        return coded_groupby(batch, key_ordinals, ks, *args)
+
+    def noting_stacks(*args):
+        out = stack_rounds(*args)
+        stacked.append(out.columns)
+        return out
+
+    monkeypatch.setattr(G, "_coded_groupby", noting_coded)
+    monkeypatch.setattr(S, "_stack_rounds", noting_stacks)
+    jit_cache.clear()
+    trace.enable()
+    trace.clear()
+    try:
+        got = df.collect(engine="tpu")
+        stacks = [e.attrs for e in trace.snapshot()
+                  if e.name == "mesh.stack"]
+    finally:
+        trace.disable()
+        trace.clear()
+    return got, stacks, coded, stacked
+
+
+@pytest.mark.parametrize("name", sorted(CODED_AGGS))
+def test_spmd_update_groups_by_dictionary_code(mesh4_session,
+                                               conf_sandbox,
+                                               monkeypatch, name):
+    """The stage entry keeps the grouping keys' dictionary sidecars and
+    the update program takes the coded group-by, at the bucket's
+    largest `dict_len` of each key, with every (round, shard) indexing
+    its own dictionary: the CPU engine's answer, one `mesh.stack`
+    whose `coded_keys` counts the keys, every sidecar leaf placed."""
+    from tests.differential import assert_tables_equal
+
+    case = CODED_AGGS[name]
+    _coded_conf(conf_sandbox, CODED_ROWS, case.spread, case.bucket)
+    keys = [k for k in case.batches[0]]
+    df = (mesh4_session.create_dataframe(case.table())
+          .group_by(*[col(k) for k in keys])
+          .agg((sum_(col("v")), "s"), (count(col("v")), "c")))
+    got, stacks, coded, _ = _stacks_and_codes(df, monkeypatch)
+    assert coded == [case.ks]  # the update's trace; merges sort
+    assert len(stacks) == 1
+    (stack,) = stacks
+    assert stack["coded_keys"] == len(keys)
+    assert stack["host_uploads"] + stack["device_born"] \
+        + stack["d2d_transfers"] == stack["leaves"] * JOIN_DEV
+    assert_tables_equal(got, df.collect(engine="cpu"), approx_float=True)
+
+
+def _many_words(prefix: str) -> tuple:
+    return tuple(f"{prefix}{i}" for i in range(60))
+
+
+def _rollup(session, t):
+    return session.create_dataframe(t).rollup("a", "b").agg(
+        (sum_(col("v")), "s"))
+
+
+def _past_the_domain(session, t):
+    return session.create_dataframe(t).group_by(
+        col("a"), col("b"), col("c")).agg((sum_(col("v")), "s"))
+
+
+#: name -> (the query, the batches of its table)
+CODED_BYPASSES = {
+    # three keys of 60 words: (64 + 1)^3 groups past MAX_CODED_DOMAIN
+    "a domain past MAX_CODED_DOMAIN": (_past_the_domain, (
+        {"a": _many_words("a"), "b": _many_words("b"),
+         "c": _many_words("c")},)),
+    "a rollup": (_rollup, ({"a": _RAN, "b": _OF},) * 2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CODED_BYPASSES))
+def test_spmd_coded_update_bypassed(mesh4_session, conf_sandbox,
+                                    monkeypatch, name):
+    """Where the update could not group by code, the stage entry stacks
+    what it always did: no column keeps a sidecar, every `mesh.stack`
+    reads `coded_keys` 0 and counts only the data leaves, and no
+    program takes the coded group-by."""
+    from spark_rapids_tpu.parallel import spmd as S
+    from tests.differential import assert_tables_equal
+
+    query, batches = CODED_BYPASSES[name]
+    t = CodedAgg(batches, ()).table()
+    _coded_conf(conf_sandbox, CODED_ROWS, False, 8)
+    df = query(mesh4_session, t)
+    got, stacks, coded, stacked = _stacks_and_codes(df, monkeypatch)
+    assert coded == []
+    assert stacks and [s["coded_keys"] for s in stacks] == [0] * len(stacks)
+    assert len(stacked) == len(stacks)
+    for s, cols in zip(stacks, stacked):
+        assert all(getattr(c, "codes", None) is None for c in cols)
+        leaves = S._leaves(ColumnarBatch(cols, 0, None))
+        assert s["leaves"] == len(leaves)
+    assert_tables_equal(got, df.collect(engine="cpu"), approx_float=True)
+
+
+# ------------------------------------------------------------------ #
 # The join stage: both sides leave through counted send slots
 # ------------------------------------------------------------------ #
 
