@@ -2,7 +2,7 @@
 
 `python chip_smoke.py` answers TPC-H q6, q1, a q3-shaped join and a
 TPC-DS q67-shaped window query at SF10 scale (BASELINE.md config #1's
-deployment, with the columns bench.py's generators draw) through the
+deployment, with the columns this file's generators draw) through the
 entry points a user calls — `TpuSession.read_parquet(...)...collect()`,
 the planner, `execs/` — under the shipped default conf, on one TPU.
 Every result is compared with the CPU engine's at the same full size.
@@ -57,8 +57,7 @@ FOUR_CHIP_LINEITEM_FILES = 16
 #: the record
 REDUCED = [{
     "query": "q3",
-    "ran": "TPC-H SF1: lineitem 6 x 2^20 rows joined to 1.5M orders "
-           "(bench.py's default fixture is 2 x 2^20 and 2^20)",
+    "ran": "TPC-H SF1: lineitem 6 x 2^20 rows joined to 1.5M orders",
     "full": "SF10: lineitem 62.9M rows joined to 15M orders",
     "why": "at SF10 on a v5e q3 answered equal to the CPU engine but "
            "took 638 s cold (5,151 XLA compiles) and 290 s warm: the "
@@ -71,7 +70,7 @@ CACHED_COLUMNS = ["l_quantity", "l_extendedprice", "l_discount", "l_tax",
 #: the smoke has 1200 s in all, compilation included: a child still
 #: running this long after the start is stopped and the run fails
 DEADLINE_S = 1150
-#: relative tolerance for double aggregates: bench._check_rows' and the
+#: relative tolerance for double aggregates: the
 #: q6 gate's.  Keys, counts and integer columns compare exactly.
 REL_TOL = 1e-6
 
@@ -258,45 +257,183 @@ def _assert_equal(name: str, got, want, ordered: bool) -> None:
                 _fail(f"{name}.{f.name} row {i}: {a!r} vs CPU {b!r}")
 
 
-def _make_data(work: str, four_chips: bool) -> dict:
-    """TPC-H/TPC-DS-shaped tables from bench.py's seeded generators."""
-    import bench
+#: rows in one generated lineitem file (and its one row group)
+ROWS_PER_FILE = 1 << 20
 
+
+def _make_lineitem(dirpath: str, n_files: int, n_orders: int,
+                   with_q1_cols: bool = False) -> list:
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    rng = np.random.default_rng(42)
+    n = ROWS_PER_FILE
+    paths = []
+    for i in range(n_files):
+        cols = {
+            "l_quantity": rng.integers(1, 51, n).astype(np.float64),
+            # TPC-H spec: l_extendedprice is a 2-decimal money value
+            "l_extendedprice": np.round(rng.uniform(900, 105000, n), 2),
+            "l_discount": rng.integers(0, 11, n) / 100.0,
+            "l_shipdate": rng.integers(8766, 10957, n).astype(np.int32),
+        }
+        if with_q1_cols:
+            cols["l_tax"] = rng.integers(0, 9, n) / 100.0
+            cols["l_returnflag"] = np.array(["A", "N", "R"])[
+                rng.integers(0, 3, n)]
+            cols["l_linestatus"] = np.array(["F", "O"])[
+                rng.integers(0, 2, n)]
+        cols["l_orderkey"] = rng.integers(0, n_orders, n).astype(np.int64)
+        p = os.path.join(dirpath, f"lineitem-{i}.parquet")
+        pq.write_table(pa.table(cols), p, row_group_size=n)
+        paths.append(p)
+    return paths
+
+
+def _make_orders(dirpath: str, n_orders: int) -> str:
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    rng = np.random.default_rng(7)
+    t = pa.table({
+        "o_orderkey": np.arange(n_orders, dtype=np.int64),
+        "o_orderdate": rng.integers(8766, 10957, n_orders).astype(
+            np.int32),
+        "o_shippriority": rng.integers(0, 5, n_orders).astype(np.int32),
+    })
+    p = os.path.join(dirpath, "orders.parquet")
+    pq.write_table(t, p, row_group_size=n_orders)
+    return p
+
+
+def _make_store_sales(dirpath: str, n_rows: int, n_files: int) -> list:
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    rng = np.random.default_rng(67)
+    per = n_rows // n_files
+    paths = []
+    for i in range(n_files):
+        t = pa.table({
+            "ss_store_sk": rng.integers(1, 9, per),
+            "ss_item_sk": rng.integers(1, 2000, per),
+            "ss_quantity": rng.integers(1, 20, per).astype(np.float64),
+            "ss_sales_price": np.round(rng.uniform(1, 300, per), 2),
+        })
+        p = os.path.join(dirpath, f"ss-{i}.parquet")
+        pq.write_table(t, p, row_group_size=per)
+        paths.append(p)
+    return paths
+
+
+def _make_data(work: str, four_chips: bool) -> dict:
+    """TPC-H/TPC-DS-shaped tables from seeded generators."""
     dirs = {n: os.path.join(work, n)
             for n in ("lineitem", "store_sales", "q3", "orders")}
     for d in dirs.values():
         os.makedirs(d)
     data = {
-        "lineitem": bench.make_lineitem(
-            dirs["lineitem"], n_files=LINEITEM_FILES, with_q1_cols=True,
-            with_orderkey=True, n_orders=ORDERS_ROWS),
-        "store_sales": bench.make_store_sales(
-            dirs["store_sales"], n_rows=STORE_SALES_ROWS,
-            n_files=STORE_SALES_FILES),
-        "q3_lineitem": bench.make_lineitem(
-            dirs["q3"], n_files=Q3_LINEITEM_FILES, with_orderkey=True,
-            n_orders=Q3_ORDERS_ROWS),
-        "q3_orders": bench.make_orders(dirs["q3"],
-                                       n_orders=Q3_ORDERS_ROWS),
+        "lineitem": _make_lineitem(
+            dirs["lineitem"], LINEITEM_FILES, ORDERS_ROWS,
+            with_q1_cols=True),
+        "store_sales": _make_store_sales(
+            dirs["store_sales"], STORE_SALES_ROWS, STORE_SALES_FILES),
+        "q3_lineitem": _make_lineitem(
+            dirs["q3"], Q3_LINEITEM_FILES, Q3_ORDERS_ROWS),
+        "q3_orders": _make_orders(dirs["q3"], Q3_ORDERS_ROWS),
     }
     if four_chips:
-        data["orders"] = bench.make_orders(dirs["orders"],
-                                           n_orders=ORDERS_ROWS)
+        data["orders"] = _make_orders(dirs["orders"], ORDERS_ROWS)
     return data
+
+
+def _q6_over(lineitem):
+    """TPC-H q6 over any lineitem frame (a scan, or a cache()d one)."""
+    from spark_rapids_tpu.exprs.base import lit
+    from spark_rapids_tpu.session import col, sum_
+
+    ship, disc, qty = col("l_shipdate"), col("l_discount"), col("l_quantity")
+    cond = ((ship >= lit(8766)) & (ship < lit(9131))
+            & (disc >= lit(0.05)) & (disc <= lit(0.07))
+            & (qty < lit(24.0)))
+    return lineitem.where(cond).agg(
+        (sum_(col("l_extendedprice") * disc), "revenue"))
+
+
+def _q1_over(lineitem):
+    """TPC-H q1 over any lineitem frame (a scan, or a cache()d one)."""
+    from spark_rapids_tpu.exprs.base import lit
+    from spark_rapids_tpu.session import avg, col, count_star, sum_
+
+    qty, price = col("l_quantity"), col("l_extendedprice")
+    disc, tax = col("l_discount"), col("l_tax")
+    return (lineitem
+            .where(col("l_shipdate") <= lit(10471))
+            .group_by(col("l_returnflag"), col("l_linestatus"))
+            .agg((sum_(qty), "sum_qty"),
+                 (sum_(price), "sum_base_price"),
+                 (sum_(price * (lit(1.0) - disc)), "sum_disc_price"),
+                 (sum_(price * (lit(1.0) - disc) * (lit(1.0) + tax)),
+                  "sum_charge"),
+                 (avg(qty), "avg_qty"),
+                 (avg(price), "avg_price"),
+                 (avg(disc), "avg_disc"),
+                 (count_star(), "count_order")))
+
+
+def _q3(session, li_paths, orders_path):
+    """TPC-H q3 shape on two tables: lineitem JOIN orders on orderkey,
+    date filters on both sides, revenue per order, top-10 by revenue."""
+    from spark_rapids_tpu.exprs.base import lit
+    from spark_rapids_tpu.session import col, sum_
+
+    li = (session.read_parquet(*li_paths)
+          .where(col("l_shipdate") > lit(9500)))
+    orders = (session.read_parquet(orders_path)
+              .where(col("o_orderdate") < lit(9500)))
+    joined = li.join(orders, left_on=[col("l_orderkey")],
+                     right_on=[col("o_orderkey")])
+    rev = col("l_extendedprice") * (lit(1.0) - col("l_discount"))
+    return (joined
+            .group_by(col("l_orderkey"), col("o_orderdate"),
+                      col("o_shippriority"))
+            .agg((sum_(rev), "revenue"))
+            .order_by(col("revenue"), desc=True)
+            .limit(10))
+
+
+def _q67(session, paths):
+    """TPC-DS q67 shape: grouped aggregate -> rank window partitioned
+    by store -> rank filter -> ordered output."""
+    from spark_rapids_tpu.exprs.base import lit
+    from spark_rapids_tpu.exprs.window import Window, rank
+    from spark_rapids_tpu.session import col, sum_
+
+    agg = (session.read_parquet(*paths)
+           .group_by(col("ss_store_sk"), col("ss_item_sk"))
+           .agg((sum_(col("ss_sales_price") * col("ss_quantity")),
+                 "sumsales")))
+    spec = Window.partition_by("ss_store_sk").order_by(
+        "sumsales", desc=True)
+    ranked = agg.select(col("ss_store_sk"), col("ss_item_sk"),
+                        col("sumsales"),
+                        rank().over(spec).alias("rk"))
+    return (ranked.where(col("rk") <= lit(10))
+            .order_by(col("ss_store_sk"), col("rk"), col("ss_item_sk")))
 
 
 def _queries(session, data: dict) -> dict:
     """name -> (DataFrame, input paths, result is ordered)."""
-    import bench
-
     li, ss = data["lineitem"], data["store_sales"]
     q3_li, q3_orders = data["q3_lineitem"], data["q3_orders"]
     return {
-        "q6": (bench.q6_dataframe(session, li), li, False),
-        "q1": (bench.q1_dataframe(session, li), li, False),
-        "q3": (bench.q3_dataframe(session, q3_li, q3_orders),
-               q3_li + [q3_orders], True),
-        "q67": (bench.q67_dataframe(session, ss), ss, True),
+        "q6": (_q6_over(session.read_parquet(*li)), li, False),
+        "q1": (_q1_over(session.read_parquet(*li)), li, False),
+        "q3": (_q3(session, q3_li, q3_orders), q3_li + [q3_orders], True),
+        "q67": (_q67(session, ss), ss, True),
     }
 
 
@@ -344,14 +481,13 @@ def _run_queries(session, data: dict, work: str, which: int,
 def _run_cached(session, data: dict, work: str, device) -> dict:
     """q6 and q1 once more over read_parquet(...).cache(): the q1/q6
     columns of all 62.9M lineitem rows live in HBM."""
-    import bench
     import pyarrow.parquet as pq
 
     from spark_rapids_tpu.memory import get_store
 
     lineitem = session.read_parquet(
         *data["lineitem"], columns=CACHED_COLUMNS).cache()
-    q6, q1 = bench.q6_over(lineitem), bench.q1_over(lineitem)
+    q6, q1 = _q6_over(lineitem), _q1_over(lineitem)
     out: dict = {}
     try:
         _assert_on_device("q6 (cached)", q6.explain())

@@ -58,8 +58,7 @@ from spark_rapids_tpu.columnar.column import (
 
 #: tapped H2D accounting: bytes actually crossing the wire through
 #: THE batched upload below (compressed components count their packed
-#: size) — the counter the wire-codec acceptance gate and bench.py's
-#: q*_upload_bytes_wire / q*_upload_ratio fields read.
+#: size) — the counter the wire-codec acceptance gate reads.
 _upload_lock = threading.Lock()
 _UPLOAD_STATS = {"batches": 0, "wire_bytes": 0}
 

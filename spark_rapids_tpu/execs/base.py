@@ -416,9 +416,9 @@ def _budget_conf():
             "spark.rapids.tpu.sql.fusion.warmDispatchBudget", 256,
             "Per-query WARM dispatch budget: the maximum ledger "
             "program-launch count a warm (compile-cache-hot) "
-            "milestone query may pay per collect before the bench "
-            "dispatch-budget gate and run_fusion_smoke fail the "
-            "round.  Turns ROADMAP #2's dispatch-soup diagnosis "
+            "milestone query may pay per collect before "
+            "run_fusion_smoke's dispatch-budget gate fails.  Turns "
+            "ROADMAP #2's dispatch-soup diagnosis "
             "(HC010) into a regression GATE instead of a diagnostic: "
             "un-fusing a chain or destabilizing a jit key shows up as "
             "a hard assertion, not a slow drift.  0 disables the "
@@ -430,14 +430,14 @@ def warm_dispatch_budget() -> int:
     return int(get_conf().get(_budget_conf()))
 
 
-#: process-global fusion activity counters (reset per bench query like
-#: the pipeline/speculation/ledger stats): `chains` = fused chain
+#: process-global fusion activity counters (`reset_fusion_stats`
+#: clears them): `chains` = fused chain
 #: programs BUILT (>= 2 execs, or 1 exec + in-program wire decode);
 #: `fused_dispatches` = executions of such programs;
 #: `saved_dispatches` = program launches those executions did NOT pay
 #: vs the unfused engine (chain length - 1, +1 when the wire decode
-#: rode inside) — bench.py's q*_fusion_chains /
-#: q*_fused_dispatch_savings fields.
+#: rode inside) — run_fusion_smoke's fusion_chains /
+#: fusion_saved_dispatches fields.
 _FUSION_LOCK = threading.Lock()
 _FUSION_STATS = {"chains": 0, "fused_dispatches": 0,
                  "saved_dispatches": 0}

@@ -467,8 +467,7 @@ class TpuCollectiveHashAggregateExec(_CollectiveBase):
             final = t.observe(tail(xs2))
         counts = S.stage_counts(final)
         out = []
-        for d, b in enumerate(S.unstack_stage(final, counts,
-                                              mesh=self.mesh)):
+        for d, b in enumerate(S.unstack_stage(final, counts)):
             self.metrics["collectiveRows"].add(int(counts[d]))
             out.append([b])
         return out
@@ -697,8 +696,7 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
                 # the padded round capacity or the whole build side:
                 # pad_capacity honors the pow2x3 bucket policy, so a
                 # 5/8-full shard stops forcing expand_pairs to compute
-                # on a worst-case pad (MULTICHIP_r06 measured the old
-                # max(cap, build_rows) guess at 0.505x per device).
+                # on a worst-case pad.
                 # An undershoot is safe: the totals check below
                 # re-buckets and re-dispatches at the true capacity.
                 live_max = int(counts2.max())
@@ -723,7 +721,7 @@ class TpuCollectiveHashJoinExec(_CollectiveBase):
                     # capacity the data actually needs
                     cap_guess = pad_capacity(worst)
                 outs = t.observe(outs)
-                per = S.unstack_round_stage(outs, mesh=self.mesh)
+                per = S.unstack_round_stage(outs)
                 for d in range(n):
                     chunks[d].extend(per[d])
 
@@ -747,10 +745,7 @@ class TpuCollectiveSortExec(_CollectiveBase):
     The route program samples sort keys over every parked round, pools
     the samples with an all_gather, derives range bounds in-program and
     routes every round through a range-bisect all_to_all; each shard
-    then sorts locally — shard index order IS the total order.  (Under
-    mesh serving a long input takes two bucketed passes instead, the
-    bounds riding as a REPLICATED program argument so one compiled
-    program serves every bounds value: `_spmd_sort_bucketed`.)"""
+    then sorts locally — shard index order IS the total order."""
 
     SAMPLE_PER_SHARD = 256
 
@@ -815,101 +810,25 @@ class TpuCollectiveSortExec(_CollectiveBase):
                                     part.key_orders())
             return b.gather(perm, b.num_rows)
 
-        from spark_rapids_tpu.serving import mesh_serving_enabled
-
         with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
-            raw = list(self._shard_rounds(child, size_to_rows=True))
-            if (len(raw) > self.bucket_rounds
-                    and mesh_serving_enabled()):
-                out = self._spmd_sort_bucketed(raw, local_sort, t)
-            else:
-                rounds = S.pad_rounds_pow2(raw, child.schema, n)
-                xs = S.shard_stack_rounds(rounds, self.mesh)
-                self._tick_exchange(xs, _grid_capacity(rounds),
-                                    _grid_rows(rounds))
-                fracs = S.sample_fracs(self.mesh, len(rounds),
-                                       self.SAMPLE_PER_SHARD)
-                rprog = S.make_sort_route_stage(
-                    self.mesh, skey, part, len(rounds),
-                    self.SAMPLE_PER_SHARD, op=self.name, donate=True)
-                routed = rprog(xs, fracs)
-                xs2 = self._restage((routed, S.stage_counts(routed)))
-                tail = S.make_stage_tail(self.mesh, skey, local_sort,
-                                         S.stacked_rounds(xs2),
-                                         op=self.name, donate=True)
-                out = t.observe(tail(xs2))
-        counts = S.stage_counts(out)
-        return [[b]
-                for b in S.unstack_stage(out, counts, mesh=self.mesh)]
-
-    def _spmd_sort_bucketed(self, raw: list, local_sort, t):
-        """Bounded-residency sort (mesh serving, docs/pod_serving.md):
-        instead of assembling EVERY round into one resident global
-        array (the single-program path's footprint is R x n x roundRows
-        for the whole stage), sample bucket by bucket (pass 1, one
-        bucket stacked at a time), choose bounds once from the pooled
-        tiny samples, then range-route bucket by bucket (pass 2, bounds
-        as a replicated program argument).  Row placement may differ
-        from the single-program path (bounds come from the same
-        fraction scheme but bucket-local pooling); the TOTAL order —
-        sorted shards concatenated by shard index — is identical by
-        construction, because any bounds partition sorts correctly."""
-        from spark_rapids_tpu.execs.jit_cache import cached_jit
-        from spark_rapids_tpu.ops.range_partition import choose_bounds
-        from spark_rapids_tpu.parallel import spmd as S
-
-        child = self.children[0]
-        part = self._part
-        n = self.num_partitions
-        skey = self._sort_key()
-        B = self.bucket_rounds
-        buckets = [S.pad_rounds_pow2(raw[i:i + B], child.schema, n)
-                   for i in range(0, len(raw), B)]
-
-        # pass 1: per-bucket sample programs; only the tiny per-shard
-        # key samples stay resident between passes
-        samples: list[ColumnarBatch] = []
-        for bucket in buckets:
-            xs = S.shard_stack_rounds(bucket, self.mesh)
-            fracs = S.sample_fracs(self.mesh, len(bucket),
+            rounds = S.pad_rounds_pow2(
+                list(self._shard_rounds(child, size_to_rows=True)),
+                child.schema, n)
+            xs = S.shard_stack_rounds(rounds, self.mesh)
+            self._tick_exchange(xs, _grid_capacity(rounds),
+                                _grid_rows(rounds))
+            fracs = S.sample_fracs(self.mesh, len(rounds),
                                    self.SAMPLE_PER_SHARD)
-            sprog = S.make_sort_sample_stage(
-                self.mesh, skey, part, len(bucket),
-                self.SAMPLE_PER_SHARD, op=self.name)
-            per = S.unstack_round_stage(sprog(xs, fracs),
-                                        mesh=self.mesh)
-            for shard_list in per:
-                samples.extend(shard_list)
-        if not samples:
-            samples = [part.key_batch(ColumnarBatch.empty(child.schema))]
-        n_live = sum(s.concrete_num_rows() for s in samples)
-        jit_bounds = cached_jit(
-            ("csortbounds", skey, n_live, n,
-             tuple(s.capacity for s in samples)),
-            op=self.name,
-            make_fn=lambda: lambda ss: choose_bounds(
-                concat_batches(ss), part.key_orders(), n, n_live))
-        bounds = jit_bounds(samples)
-
-        # pass 2: per-bucket range routing against the shared bounds
-        # (as the aggregate's buckets: each cut as it arrives, then
-        # end to end)
-        shrunk: list[tuple] = []
-        for bucket in buckets:
-            xs = S.shard_stack_rounds(bucket, self.mesh)
-            self._tick_exchange(xs, _grid_capacity(bucket),
-                                _grid_rows(bucket))
-            rprog = S.make_bounds_route_stage(
-                self.mesh, skey, part, len(bucket), op=self.name,
-                donate=True)
-            routed = rprog(xs, bounds)
-            counts = S.stage_counts(routed)
-            shrunk.append((self._restage((routed, counts)), counts))
-        xs2 = self._restage(*shrunk)
-        tail = S.make_stage_tail(self.mesh, skey, local_sort,
-                                 S.stacked_rounds(xs2), op=self.name,
-                                 donate=True)
-        return t.observe(tail(xs2))
+            rprog = S.make_sort_route_stage(
+                self.mesh, skey, part, len(rounds),
+                self.SAMPLE_PER_SHARD, op=self.name, donate=True)
+            routed = rprog(xs, fracs)
+            xs2 = self._restage((routed, S.stage_counts(routed)))
+            tail = S.make_stage_tail(self.mesh, skey, local_sort,
+                                     S.stacked_rounds(xs2),
+                                     op=self.name, donate=True)
+            out = t.observe(tail(xs2))
+        return [[b] for b in S.unstack_stage(out, S.stage_counts(out))]
 
 
 class TpuCollectiveWindowExec(_CollectiveBase):
@@ -995,5 +914,4 @@ class TpuCollectiveWindowExec(_CollectiveBase):
                 S.stacked_rounds(xs2), op=self.name, donate=True)
             out = t.observe(tail(xs2))
         # a window emits the rows it was handed
-        return [[b] for b in S.unstack_stage(
-            out, received.sum(axis=0), mesh=self.mesh)]
+        return [[b] for b in S.unstack_stage(out, received.sum(axis=0))]

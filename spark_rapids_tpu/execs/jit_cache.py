@@ -209,9 +209,9 @@ class _CompileLatch:
 #: queues B-then-A, each program's rendezvous waits on participants
 #: parked BEHIND the other program, and both stall forever (the
 #: `collective_ops_utils` "waiting for all participants" deadlock).
-#: Pod-scale serving's concurrent sessions are exactly this shape
-#: (docs/pod_serving.md).  Holding the lock across the (async) call
-#: makes every device see the same program order — sufficient, IF
+#: Concurrent sessions on one mesh are exactly this shape.  Holding
+#: the lock across the (async) call makes every device see the same
+#: program order — sufficient, IF
 #: every multi-device launch goes through the gate: cached_jit is the
 #: only place one is compiled (parallel/spmd.py's stage builders all
 #: end in it), and the eager side doors (a sharded array's
@@ -358,29 +358,15 @@ def cached_jit(key: tuple, make_fn: Callable[[], Callable],
             # with persistence on, a structural-key miss first asks the
             # disk store for jax.export artifacts under (key x conf
             # fingerprint); a hit dispatches restored executables and
-            # compiles nothing.  Sharded programs are excluded (their
+            # compiles nothing.  Sharded programs are excluded: their
             # sharding specs bind live device objects that don't
-            # round-trip a serialize) — EXCEPT under mesh serving
-            # (docs/pod_serving.md): a partitioned stage program's key
-            # already folds parallel/mesh.mesh_key, so a warm pod
-            # restart on the same mesh shape redeploys the exported
-            # partitioned executables; an export that cannot serialize
-            # degrades to the honest compile through AutoSave's
-            # swallowed-error path (persist.errors), never a wrong
-            # program.  Off = one conf read in active(), then the
-            # identical compile path as ever.
+            # round-trip a serialize.  Off = one conf read in active(),
+            # then the identical compile path as ever.
             from spark_rapids_tpu import persist as _persist
 
             sharded = (in_shardings is not None
                        or out_shardings is not None)
-            if sharded:
-                from spark_rapids_tpu.serving import (
-                    mesh_serving_enabled,
-                )
-                store = _persist.active() \
-                    if mesh_serving_enabled() else None
-            else:
-                store = _persist.active()
+            store = None if sharded else _persist.active()
             def make_named() -> Callable:
                 return named_program(make_fn(), op, _ledger.key_tag(key))
 

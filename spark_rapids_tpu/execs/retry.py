@@ -136,7 +136,7 @@ def _bump(key: str, n: int = 1) -> None:
 
 def retry_stats() -> dict:
     """Process-global recovery counters: {splits, spill_retries,
-    task_retries, cpu_fallbacks} — bench.py resets per query."""
+    task_retries, cpu_fallbacks}."""
     with _STATS_LOCK:
         return dict(_STATS)
 
@@ -294,8 +294,7 @@ def _desharded(batch):
     gathers: bisection slices leaf-by-leaf with plain `gather`/`slice`
     ops that assume fully-addressable single-device arrays, and a
     multi-device leaf would either fail the trace or silently gather a
-    single shard's rows.  Under mesh serving (the only producer of
-    sharded stage leaves) the move routes through
+    single shard's rows.  The move routes through
     parallel/placement.adopt_batch — the single device_put choke point
     (SRC016) — so it shows up in the placement counters instead of
     vanishing into an untracked transfer."""

@@ -162,12 +162,11 @@ Rules
   of scope.
 - SRC016 (error): raw ``jax.device_put`` in execs/ and parallel/
   outside parallel/placement.py.  Stage-input placement has ONE choke
-  point (docs/pod_serving.md): placement.place_piece /
+  point (docs/spmd.md): placement.place_piece /
   placement.adopt_batch classify every move (host upload vs
   device-born vs device-to-device) into the ``placement.*`` counters
-  that back the pod-serving zero-host-upload gate — a raw
-  ``device_put`` elsewhere is an untracked transfer that silently
-  re-opens the host round-trip the device-born contract closed.
+  — a raw ``device_put`` elsewhere is an untracked transfer that
+  moves bytes past the meter.
   Syntactic and module-wide: any ``jax.device_put(...)`` call (or
   bare ``device_put`` imported from jax) in scope.  placement.py IS
   the choke point — exempt by construction.
@@ -1413,11 +1412,9 @@ class _RawDevicePutChecker(ast.NodeVisitor):
 
     Scope is syntactic and module-wide like SRC009: a raw device_put
     anywhere in these layers moves a stage-input leaf without
-    classifying it into the ``placement.*`` counters, so the
-    pod-serving steady-state-zero-host-uploads gate (and the
-    device-born evidence it rests on) silently stops covering that
-    transfer.  parallel/placement.py IS the choke point — exempt by
-    construction."""
+    classifying it into the ``placement.*`` counters, so they
+    silently stop covering that transfer.  parallel/placement.py IS
+    the choke point — exempt by construction."""
 
     def __init__(self, path: str, out: list[Diagnostic]):
         self.path = path
@@ -1446,8 +1443,7 @@ class _RawDevicePutChecker(ast.NodeVisitor):
                 "SRC016", "error", f"{self.path}::{qual}",
                 "raw `jax.device_put` bypasses the stage-input "
                 "placement choke point — the transfer is unclassified "
-                "(no placement.* counter), so the pod-serving "
-                "zero-host-upload gate no longer covers it",
+                "(no placement.* counter)",
                 hint="route the move through parallel/placement."
                      "place_piece (per-shard pieces) or "
                      "placement.adopt_batch (whole batches)",

@@ -205,22 +205,6 @@ def _batch_to_host(batch: ColumnarBatch,
     host = jax.device_get([a for _, a in leaves])
     arrays: dict[str, np.ndarray] = {
         name: np.asarray(h) for (name, _), h in zip(leaves, host)}
-    # per-leaf device commitment, in leaf order (-1 = uncommitted or
-    # multi-device): a per-shard batch adopted onto its mesh device
-    # (parallel/placement.py) restores THERE, not onto the default
-    # device — spill must not silently undo stage-input locality
-    dev_ids = []
-    for _, a in leaves:
-        did = -1
-        if isinstance(a, jax.Array):
-            try:
-                ds = a.devices()
-                if len(ds) == 1:
-                    did = next(iter(ds)).id
-            except Exception:
-                pass
-        dev_ids.append(did)
-    arrays["__leaf_devices"] = np.asarray(dev_ids, np.int64)
     if delete:
         for name, a in leaves:
             if not name.endswith(_SHARED_SIDECAR_SUFFIXES):
@@ -292,29 +276,7 @@ def _host_to_batch(arrays: dict, schema: T.Schema) -> ColumnarBatch:
         _host_to_col(arrays, f"c{i}", f.dtype)
         for i, f in enumerate(schema.fields)]
     n = int(np.asarray(arrays["__num_rows"]).reshape(-1)[0])
-    batch = ColumnarBatch(cols, n, schema)
-    # restore stage-input locality (mesh serving only — the default
-    # path stays byte-identical: everything lands on the default
-    # device as ever): a batch whose leaves were all committed to one
-    # mesh device re-adopts that device
-    devs = arrays.get("__leaf_devices")
-    if devs is not None:
-        ids = {int(x) for x in np.asarray(devs).reshape(-1)
-               if int(x) >= 0}
-        if len(ids) == 1:
-            from spark_rapids_tpu.serving import mesh_serving_enabled
-
-            if mesh_serving_enabled():
-                want = ids.pop()
-                target = next((d for d in jax.devices()
-                               if d.id == want), None)
-                if target is not None:
-                    from spark_rapids_tpu.parallel import (
-                        placement as _placement,
-                    )
-
-                    batch = _placement.adopt_batch(batch, target)
-    return batch
+    return ColumnarBatch(cols, n, schema)
 
 
 class _HostFrame:

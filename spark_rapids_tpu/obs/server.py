@@ -15,8 +15,8 @@ Endpoints (all GET, JSON unless noted):
 
 The handler serves STRICTLY from in-process snapshots — it never
 touches the device, takes no engine locks beyond the registry's own,
-and a scrape concurrent with a measured bench window must not perturb
-results (asserted by the bench.py --sessions scrape-under-storm arm).
+and a scrape concurrent with running queries must not perturb results
+(tests/test_obs.py holds both).
 Docs: ``docs/ops_plane.md``.
 """
 

@@ -9,6 +9,7 @@ names.
 
 from __future__ import annotations
 
+import hashlib
 from typing import Optional, Sequence
 
 import jax
@@ -42,6 +43,19 @@ def mesh_key(mesh: Mesh) -> tuple:
     return (tuple(int(d.id) for d in mesh.devices.flat),
             tuple(mesh.axis_names),
             tuple(int(mesh.shape[a]) for a in mesh.axis_names))
+
+
+def mesh_cache_suffix() -> str:
+    """The mesh-identity component of a serving-tier cache key: a short
+    digest of ``mesh_key(active_mesh())``, or '' when no mesh is
+    active.  A plan or result minted on one mesh must never serve
+    another (an 8-device entry must not answer on 4 devices), so the
+    plan, prepared-statement and result caches fold this in."""
+    m = active_mesh()
+    if m is None:
+        return ""
+    return "|mesh:" + hashlib.sha256(
+        repr(mesh_key(m)).encode()).hexdigest()[:12]
 
 
 def make_mesh(n_devices: Optional[int] = None,

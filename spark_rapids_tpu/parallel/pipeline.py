@@ -37,8 +37,8 @@ Two primitives fix both, shared by every exec:
   one batch later is free in steady state, so even the deferred
   readback leaves the critical path.
 
-Per-stage occupancy and wait counters feed bench.py's
-``pipeline_occupancy`` metric and the docs/pipeline.md tuning guide.
+Per-stage occupancy and wait counters feed the event log, the
+telemetry sampler and the docs/pipeline.md tuning guide.
 """
 
 from __future__ import annotations
@@ -142,10 +142,10 @@ def _stage_metrics(name: str) -> StageMetrics:
 
 
 def stage_snapshot(prefix: Optional[str] = None) -> dict[str, dict]:
-    """Point-in-time counters for every stage seen so far (bench.py's
-    pipeline_occupancy source).  `prefix` filters to one stage family —
-    e.g. ``stage_snapshot("serve.stream")`` isolates the serving tier's
-    streaming-fetch backpressure counters from the scan stages."""
+    """Point-in-time counters for every stage seen so far.  `prefix`
+    filters to one stage family — e.g. ``stage_snapshot("serve.stream")``
+    isolates the serving tier's streaming-fetch backpressure counters
+    from the scan stages."""
     with _STAGES_LOCK:
         stages = list(_STAGES.values())
     return {m.name: m.snapshot() for m in stages
@@ -153,9 +153,8 @@ def stage_snapshot(prefix: Optional[str] = None) -> dict[str, dict]:
 
 
 def reset_stage_counters() -> None:
-    """Clear every stage's counters — bench.py calls this between
-    benchmark queries so pipeline_occupancy reports PER QUERY instead
-    of accumulating across configs."""
+    """Clear every stage's counters, so occupancy read after it
+    reports one query instead of accumulating across queries."""
     with _STAGES_LOCK:
         _STAGES.clear()
 

@@ -1,19 +1,12 @@
-"""Per-shard stage-input placement: the device-born data contract of
-pod-scale serving (docs/pod_serving.md).
+"""Per-shard stage-input placement: the one choke point for moving a
+stage-input leaf onto its mesh device (docs/spmd.md).
 
 The reference system's shuffle story is LOCALITY: RapidsShuffleManager
 moves blocks device-to-device over UCX so a child task's inputs are
 already resident where they are consumed (PAPER.md 2.10), and the TPU
 mapping of that story is ICI collectives plus per-shard placement
-(PAPER.md 5.8).  Before this module the SPMD tier broke that contract
-at every stage boundary: ``spmd._assemble`` called a raw
-``jax.device_put`` per shard piece, so even a shard that a previous
-stage had just produced ON its mesh device round-tripped through the
-default device on re-assembly.
-
-This module is the single choke point for moving a stage-input leaf
-onto its mesh device (tpulint SRC016 forbids raw ``jax.device_put`` of
-stage inputs anywhere else in execs// parallel/):
+(PAPER.md 5.8).  tpulint SRC016 forbids raw ``jax.device_put`` of
+stage inputs anywhere else in execs// parallel/:
 
 - :func:`place_piece` classifies and performs the move — a host-born
   source (numpy / python) counts ``host_uploads``; a jax Array already
@@ -22,15 +15,9 @@ stage inputs anywhere else in execs// parallel/):
   ``d2d_transfers`` device-to-device move, whose bytes add to
   ``d2d_bytes`` (what crosses chips through ``device_put``, outside
   any collective);
-- :func:`adopt_batch` is the PRODUCER-side half: stage outputs adopt
-  their shard's device as a stage's exit cuts them (spmd.unstack_*),
-  so the next stage's assembly finds every piece device-born (between
-  two programs of one stage `spmd.restage` is a partitioned program:
-  its outputs are born on their shards' devices);
-- the counters surface as ``placement.*`` event-log counters and the
-  ``placement_host_uploads`` bench field — steady state under mesh
-  serving is ZERO host uploads (the smoke gate
-  tools/bench_smoke.run_mesh_serving_smoke asserts it).
+- :func:`adopt_batch` commits a whole batch onto one device (the
+  retry ladder de-shards through it before bisecting);
+- the counters surface as ``placement.*`` event-log counters.
 
 Control-plane leaves (the tiny int32 row-count arrays assembled from
 host ``concrete_num_rows`` values) are tallied separately as
@@ -78,10 +65,8 @@ def place_piece(x, device, control: bool = False):
 
 
 def adopt_batch(batch, device):
-    """Producer-side adoption: commit every column leaf of a per-shard
-    batch onto ITS mesh device, so the consuming stage's assembly finds
-    the pieces device-born instead of paying a transfer per leaf.
-    Leaves already resident on ``device`` are untouched (adoption is
+    """Commit every column leaf of a batch onto ``device``.  Leaves
+    already resident on ``device`` are untouched (adoption is
     idempotent and free in steady state).  Columns move as pytrees, so
     every column kind (string dictionaries, list/struct/map children)
     adopts uniformly; ``num_rows`` is deliberately left alone — host
@@ -111,7 +96,7 @@ def stats() -> dict[str, int]:
 
 
 def reset_stats() -> None:
-    """Test/bench isolation (the reset_stage_counters discipline)."""
+    """Test isolation (the reset_stage_counters discipline)."""
     with _LOCK:
         for k in _STATS:
             _STATS[k] = 0

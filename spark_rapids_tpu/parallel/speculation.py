@@ -28,8 +28,7 @@ at, and a bucket guessed at 1.5 x the count was one too large for every
 count over two thirds of its bucket: 0.4-1.3 s a join on a v5e against
 some 2 ms a readback.
 
-Hit/overflow counters feed ``bench.py``'s
-``q*_speculation_hit_rate`` fields and the aggregate's
+Hit/overflow counters feed the aggregate's
 ``specHits``/``specOverflows`` metrics shown by
 ``df.explain("analyze")``; ``speculation.hit``/``speculation.overflow``
 instants land on the structured trace timeline.  Docs:
@@ -85,10 +84,8 @@ SPECULATION_ADAPTIVE_MIN_HIT_RATE = register(
     "speculation.adaptive.window outcomes falls below this is "
     "auto-DISABLED for the rest of the process (or until "
     "reset_stats) — its execs revert to the conservative blocking "
-    "sizing sync.  BISECT_q3_r07's conviction: a workload whose output "
-    "counts the predictor cannot track pays for every miss, and turning "
-    "speculation off recovered 1.294x on q3 (through the join, which "
-    "has since stopped guessing).  The "
+    "sizing sync: a workload whose output counts the predictor cannot "
+    "track pays for every miss.  The "
     "disable lands as a speculation.disabled event-log counter and a "
     "speculation.disabled trace instant; 0.0 = never disable.",
     check=lambda v: 0.0 <= v <= 1.0)
@@ -189,7 +186,7 @@ def reset_predictors() -> None:
 
 
 # ------------------------------------------------------------------ #
-# Hit/overflow accounting (bench.py + explain("analyze") source)
+# Hit/overflow accounting (explain("analyze") source)
 # ------------------------------------------------------------------ #
 
 _STATS: dict[str, dict] = {}
@@ -281,8 +278,7 @@ def tag_enabled(tag: str) -> bool:
 
 
 def disabled_tags() -> list[str]:
-    """Tags the adaptive kill-switch has disabled, sorted (bench.py's
-    ``q*_speculation_disabled`` field)."""
+    """Tags the adaptive kill-switch has disabled, sorted."""
     with _STATS_LOCK:
         return sorted(_DISABLED)
 
@@ -305,8 +301,8 @@ def stats() -> dict[str, dict]:
 
 
 def reset_stats() -> None:
-    """bench.py resets between benchmark queries so hit rates report
-    PER QUERY (the reset_stage_counters discipline).  Also re-arms the
+    """Clear the hit/overflow counters so hit rates report PER QUERY
+    (the reset_stage_counters discipline).  Also re-arms the
     adaptive kill-switch (windows + convicted tags) so one query's
     conviction does not bleed into the next query's measurement; the
     cumulative ``disabled_total`` survives so event-log deltas stay

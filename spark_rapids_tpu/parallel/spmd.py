@@ -423,20 +423,16 @@ def row_bytes(batch: ColumnarBatch) -> int:
                for leaf in _leaves(batch))
 
 
-def _slice_shard(batch: ColumnarBatch, idx: tuple, rows: int,
-                 device=None) -> ColumnarBatch:
+def _slice_shard(batch: ColumnarBatch, idx: tuple,
+                 rows: int) -> ColumnarBatch:
     # take_piece, not plain getitem: the (round, shard) piece of a
     # partitioned stage output is wholly resident on one device, and
     # an eager getitem on the sharded array would launch an unguarded
     # cross-device gather (exchange.take_piece documents the hazard)
     out = _with_leaves(
         batch, [take_piece(x, idx) for x in _leaves(batch)], rows)
-    out = out.shrink_to_capacity(max(MIN_CAPACITY,
-                                     pad_capacity(rows)))
-    if device is not None:
-        from spark_rapids_tpu.parallel import placement as _placement
-        out = _placement.adopt_batch(out, device)
-    return out
+    return out.shrink_to_capacity(max(MIN_CAPACITY,
+                                      pad_capacity(rows)))
 
 
 def _shrink_span(batch: ColumnarBatch, counts: np.ndarray,
@@ -455,51 +451,33 @@ def _shrink_span(batch: ColumnarBatch, counts: np.ndarray,
                        leaves=len(leaves))
 
 
-def _adoption_devices(mesh) -> Optional[list]:
-    """Mesh device list when producer-side adoption is on (mesh
-    serving), else None — the default keeps shrink outputs wherever
-    slicing left them, bit-for-bit the pre-placement behavior."""
-    if mesh is None:
-        return None
-    from spark_rapids_tpu.serving import mesh_serving_enabled
-    if not mesh_serving_enabled():
-        return None
-    return list(mesh.devices.flat)
-
-
 def unstack_stage(batch: ColumnarBatch,
-                  counts: Optional[np.ndarray] = None,
-                  mesh=None) -> list[ColumnarBatch]:
+                  counts: Optional[np.ndarray] = None
+                  ) -> list[ColumnarBatch]:
     """Split a (n, capacity, ...) stage output into n shrunk per-shard
-    batches using the stage-exit counts (fetched once if not given).
-    Under mesh serving (pass the mesh) shard d's batch adopts mesh
-    device d at this producer boundary."""
+    batches using the stage-exit counts (fetched once if not given)."""
     if counts is None:
         counts = stage_counts(batch)
-    devs = _adoption_devices(mesh)
     with _shrink_span(batch, counts, counts.shape[0]):
-        return [_slice_shard(batch, (d,), int(counts[d]),
-                             devs[d] if devs else None)
+        return [_slice_shard(batch, (d,), int(counts[d]))
                 for d in range(counts.shape[0])]
 
 
 def unstack_round_stage(batch: ColumnarBatch,
-                        counts: Optional[np.ndarray] = None,
-                        mesh=None) -> list[list[ColumnarBatch]]:
+                        counts: Optional[np.ndarray] = None
+                        ) -> list[list[ColumnarBatch]]:
     """Split a (R, n, capacity, ...) stage output into per-shard lists
     of per-round shrunk batches (empty rounds dropped)."""
     if counts is None:
         counts = stage_counts(batch)
     r_count, n = counts.shape
-    devs = _adoption_devices(mesh)
     out: list[list[ColumnarBatch]] = [[] for _ in range(n)]
     with _shrink_span(batch, counts, int(np.count_nonzero(counts))):
         for d in range(n):
             for r in range(r_count):
                 rows = int(counts[r, d])
                 if rows:
-                    out[d].append(_slice_shard(
-                        batch, (r, d), rows, devs[d] if devs else None))
+                    out[d].append(_slice_shard(batch, (r, d), rows))
     return out
 
 
@@ -560,8 +538,8 @@ def restage(stacked: Sequence[tuple], mesh,
     Cutting before the next program is what keeps its merge, sort or
     join work proportional to the LIVE rows: an exchange program's
     outputs carry the receive capacity per shard, n x the send
-    slot's.  Under mesh serving the output is born on its shards'
-    devices, so the next stage finds every piece in place.
+    slot's.  The output is born on its shards' devices, so the next
+    stage finds every piece in place.
 
     ONE ``mesh.shrink`` span a call, ``path="program"``, with `pieces`,
     `rows`, `capacity` (the input's), `to_capacity`, `leaves` and
@@ -836,76 +814,6 @@ def _all_gather_concat(b: ColumnarBatch, n: int,
     idx = jnp.arange(n * cap, dtype=jnp.int32)
     live = (idx % cap) < jnp.take(rows_all, idx // cap)
     return ColumnarBatch(cols, n * cap, b.schema).compact(live)
-
-
-def make_sort_sample_stage(mesh, key: tuple, part, n_rounds: int,
-                           sample_k: int, op: Optional[str] = None):
-    """Pass 1 of the BUCKETED distributed ORDER BY (mesh serving,
-    docs/pod_serving.md): scan one bucket's rounds gathering per-shard
-    sort-key samples at host-chosen fractional positions — the sample
-    half of `make_sort_route_stage`, emitted as a round-stacked stage
-    OUTPUT instead of being consumed in-program.  A million-round sort
-    samples bucket by bucket (one bucket stacked at a time) instead of
-    assembling every round into one resident global array.  Inputs are
-    NOT donated: the same rounds re-stack for the route pass."""
-    axis = DATA_AXIS
-
-    def make():
-        def shard_fn(xs: ColumnarBatch, fracs: jax.Array):
-            def sample_body(carry, xf):
-                x, frac = xf
-                b = _squeeze0(x)
-                kb = part.key_batch(b)
-                rows = jnp.asarray(b.num_rows, jnp.int32)
-                cap = b.capacity
-                pos = jnp.clip(
-                    (frac[0] * rows.astype(jnp.float32)).astype(
-                        jnp.int32),
-                    0, jnp.maximum(rows - 1, 0))
-                n_valid = (sample_k * rows + cap - 1) // cap
-                return carry, _unsqueeze0(kb.gather(pos, n_valid))
-            _, samples = jax.lax.scan(sample_body, jnp.int32(0),
-                                      (xs, fracs))
-            return samples
-
-        return _shard_map(
-            shard_fn, mesh, (P(None, axis), P(None, axis)),
-            P(None, axis))
-
-    return _stage_jit(
-        ("spmdsortsample", key, n_rounds, sample_k), make, mesh, op,
-        (rounds_sharding(mesh), rounds_sharding(mesh)),
-        rounds_sharding(mesh), None, n_rounds)
-
-
-def make_bounds_route_stage(mesh, key: tuple, part, n_rounds: int,
-                            op: Optional[str] = None,
-                            donate: bool = False):
-    """Pass 2 of the bucketed distributed ORDER BY: scan one bucket's
-    rounds through the range-routed all_to_all, with the bounds batch
-    riding as a REPLICATED program argument — one compiled program
-    serves every bounds value, so the bucket count never mints
-    executables."""
-    n = int(mesh.shape[DATA_AXIS])
-    axis = DATA_AXIS
-
-    def make():
-        def shard_fn(xs: ColumnarBatch, bounds: ColumnarBatch):
-            def route_body(carry, x):
-                b = _squeeze0(x)
-                pid = part.partition_ids_with_bounds(b, bounds)
-                return carry, _unsqueeze0(
-                    route_shard(b, pid, n, axis))
-            _, routed = jax.lax.scan(route_body, jnp.int32(0), xs)
-            return routed
-
-        return _shard_map(
-            shard_fn, mesh, (P(None, axis), P()), P(None, axis))
-
-    return _stage_jit(
-        ("spmdboundsroute", key, n_rounds), make, mesh, op,
-        (rounds_sharding(mesh), NamedSharding(mesh, P())),
-        rounds_sharding(mesh), (0,) if donate else None, n_rounds)
 
 
 def make_sort_route_stage(mesh, key: tuple, part, n_rounds: int,

@@ -70,8 +70,7 @@ PERSIST_ENABLED = register(
     "result-cache frames, plus the XLA persistent compilation cache "
     "pointed at <persist.dir>/xla.  Off (the default) = one conf "
     "read per probe site, dispatch pattern and results bit-identical "
-    "to the non-persisting engine.  bench.py --cold-start N measures "
-    "the warm-vs-empty restart cost this cache removes.")
+    "to the non-persisting engine.")
 
 PERSIST_DIR = register(
     "spark.rapids.tpu.persist.dir", "",

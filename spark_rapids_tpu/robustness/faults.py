@@ -7,7 +7,7 @@ OOM on the Nth allocation); nothing equivalent existed here — the
 recovery paths (spill-on-pressure, shuffle refetch, task retry, CPU
 degrade) only ran when real hardware happened to misbehave.  This
 module makes every one of them exercisable *deterministically*, in
-tier-1 and under ``bench.py --chaos``.
+tier-1.
 
 Sites (each planted at exactly one seam):
 
@@ -32,7 +32,7 @@ Policies are conf-driven (``spark.rapids.tpu.robustness.faults.spec``)
 and fully deterministic: fail-the-Nth-call (optionally N consecutive
 calls), fail-every-Nth, seeded per-site probability, injected latency.
 Counters per site (calls / injected / recovered) feed the chaos parity
-tests and the ``bench.py --chaos`` ``*_recovered_faults`` fields;
+tests;
 ``fault.inject`` / ``fault.recovered`` trace events land on the
 correlated timeline (docs/observability.md).
 
@@ -323,9 +323,8 @@ def injected_total() -> int:
 
 
 def reset_stats() -> None:
-    """Zero every site's counters (the schedule itself stays armed) —
-    bench.py resets per query so nth-call policies re-fire and the
-    recovery fields attribute per query."""
+    """Zero every site's counters (the schedule itself stays armed),
+    so nth-call policies re-fire."""
     with _LOCK:
         states = list(_SITES_STATE.values())
     for st in states:

@@ -24,10 +24,7 @@ north star asks for (open item #4), built from four pieces:
   (:meth:`~spark_rapids_tpu.serving.prepared.PreparedQuery.execute_stream`):
   Arrow record batches yielded incrementally off the pipelined collect
   path, with backpressure tied to the prefetch stage depth
-  (parallel/pipeline.py);
-- a **concurrency bench** (``bench.py --sessions N --tenants K``)
-  emitting ``serving_qps`` / ``serving_p50_ms`` / ``serving_p99_ms`` /
-  ``admission_wait_p99_ms`` / ``plan_cache_hit_rate``.
+  (parallel/pipeline.py).
 
 Cost discipline: with ``spark.rapids.tpu.serving.maxConcurrent`` at its
 default of 0 the whole tier is dormant — a collect performs one conf
@@ -93,59 +90,6 @@ ADMIT_WAIT_BUDGET_MS = register(
     "(tools/history): a recorded query whose serve.admit_wait_ms "
     "counter exceeds this is flagged — the serving tier is saturated "
     "for its traffic (docs/serving.md).")
-
-MESH_ENABLED = register(
-    "spark.rapids.tpu.serving.mesh.enabled", False,
-    "Pod-scale serving (docs/pod_serving.md): fuse the serving tier "
-    "with the SPMD tier.  Admission grants MESH residency (the "
-    "concurrency budget scales per device and batching groups by "
-    "mesh_key x template), the prepared-plan / result / persisted-AOT "
-    "caches fold parallel/mesh.mesh_key into their keys so same-mesh "
-    "tenants share one compiled partitioned program set, exchange and "
-    "scan output partitions adopt per-shard device placement at the "
-    "producer (stage inputs are born on their mesh device instead of "
-    "host device_put round-trips — the reference's UCX shuffle "
-    "locality, PAPER.md 2.10/5.8), and SPMD sort runs its bounded-"
-    "residency bucketed sampling.  Default off = the single-device "
-    "serving tier, bit-for-bit.")
-
-MESH_DEVICE_BUDGET = register(
-    "spark.rapids.tpu.serving.mesh.deviceBudget", 1,
-    "Admitted queries per mesh device under mesh serving: the WFQ "
-    "pump's concurrency limit becomes "
-    "min(maxConcurrent, semaphore permits) x n_devices x this.  A pod "
-    "slice admits proportionally to its width — N tenants cost one "
-    "mesh-resident program set, not N serialized turns "
-    "(docs/pod_serving.md).",
-    check=lambda v: v >= 1)
-
-
-def mesh_serving_enabled(conf=None) -> bool:
-    """One conf read; the whole pod-serving tier is dormant when off."""
-    from spark_rapids_tpu.config import get_conf
-    conf = conf or get_conf()
-    return bool(conf.get(MESH_ENABLED))
-
-
-def mesh_cache_suffix(conf=None) -> str:
-    """The mesh-identity component of every serving-tier cache key
-    under mesh serving: a short digest of ``mesh_key(active_mesh())``,
-    or '' when mesh serving is off / no mesh is active.  Folding this
-    into template / result / prepared keys is what makes a cache entry
-    safe to share between tenants (same mesh => same partitioned
-    executables) and what re-keys everything when the mesh SHAPE
-    changes (an 8-device entry must never serve a 4-device pod)."""
-    if not mesh_serving_enabled(conf):
-        return ""
-    from spark_rapids_tpu.parallel import mesh as _mesh
-    m = _mesh.active_mesh()
-    if m is None:
-        return ""
-    import hashlib
-    digest = hashlib.sha256(
-        repr(_mesh.mesh_key(m)).encode()).hexdigest()[:12]
-    return "|mesh:" + digest
-
 
 # ------------------------------------------------------------------ #
 # Per-query serving context (thread-local)

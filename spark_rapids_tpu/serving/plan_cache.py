@@ -150,14 +150,13 @@ def plan_structural_key(plan, seen: Optional[dict] = None) -> str:
 
 def template_key(plan, conf) -> str:
     """The cache key for a native (DataFrame) template: structural plan
-    key x conf fingerprint (x the active mesh identity under mesh
-    serving — a plan lowered against an 8-device mesh must re-key, not
-    rehit, when the pod reshapes to 4; docs/pod_serving.md), hashed."""
+    key x conf fingerprint x the active mesh identity (a plan lowered
+    against an 8-device mesh must re-key, not rehit, on 4), hashed."""
     from spark_rapids_tpu.eventlog import conf_fingerprint
-    from spark_rapids_tpu.serving import mesh_cache_suffix
+    from spark_rapids_tpu.parallel.mesh import mesh_cache_suffix
 
     payload = (plan_structural_key(plan) + "|" + conf_fingerprint(conf)
-               + mesh_cache_suffix(conf))
+               + mesh_cache_suffix())
     return hashlib.sha256(payload.encode()).hexdigest()[:24]
 
 
@@ -191,12 +190,12 @@ def sql_template_key(text: str, conf,
     """The cache key for a SQL template: normalized text x conf
     fingerprint x the parameter BINDING (values are burned into the
     lowered programs, so each binding is its own entry) x the active
-    mesh identity under mesh serving."""
+    mesh identity."""
     from spark_rapids_tpu.eventlog import conf_fingerprint
-    from spark_rapids_tpu.serving import mesh_cache_suffix
+    from spark_rapids_tpu.parallel.mesh import mesh_cache_suffix
 
     payload = (_normalize_sql(text) + "|" + conf_fingerprint(conf)
-               + "|" + binding_key(params) + mesh_cache_suffix(conf))
+               + "|" + binding_key(params) + mesh_cache_suffix())
     return hashlib.sha256(payload.encode()).hexdigest()[:24]
 
 

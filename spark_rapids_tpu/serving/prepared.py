@@ -67,13 +67,13 @@ class PreparedQuery:
 
     def _key(self, conf, params: Optional[dict]) -> str:
         from spark_rapids_tpu.eventlog import conf_fingerprint
-        from spark_rapids_tpu.serving import mesh_cache_suffix
+        from spark_rapids_tpu.parallel.mesh import mesh_cache_suffix
 
         # the mesh suffix is part of the memo key, not just the hashed
-        # payload: a pod reshape changes the template key under an
+        # payload: a new mesh changes the template key under an
         # UNCHANGED conf fingerprint, and a memo keyed on fp alone
         # would keep serving the old mesh's entry
-        fp = conf_fingerprint(conf) + mesh_cache_suffix(conf)
+        fp = conf_fingerprint(conf) + mesh_cache_suffix()
         binding = binding_key(params)
         memo = self._key_memo.get((fp, binding))
         if memo is not None:
@@ -96,14 +96,12 @@ class PreparedQuery:
         normalized text x conf (bindings excluded — 'same template,
         different bindings' is exactly the compatible-plan class);
         DataFrame templates on their structural plan key x conf.
-        Under mesh serving the group folds the mesh identity too
-        (mesh_key x template — the ISSUE's batching contract): tenants
-        batch together only when they would share the same
-        mesh-resident program set."""
+        The group folds the mesh identity too: tenants batch together
+        only when they would share the same mesh's program set."""
         from spark_rapids_tpu.eventlog import conf_fingerprint
-        from spark_rapids_tpu.serving import mesh_cache_suffix
+        from spark_rapids_tpu.parallel.mesh import mesh_cache_suffix
 
-        fp = conf_fingerprint(conf) + mesh_cache_suffix(conf)
+        fp = conf_fingerprint(conf) + mesh_cache_suffix()
         memo = self._group_memo.get(fp)
         if memo is not None:
             return memo

@@ -136,33 +136,11 @@ class QueryScheduler:
         """Effective concurrency: serving.maxConcurrent clamped to the
         device semaphore's permit count — admission control rides the
         same budget that caps device batch residency, so resizing the
-        semaphore (its sync_conf) re-sizes admission too.
-
-        Under mesh serving (spark.rapids.tpu.serving.mesh.enabled with
-        an active mesh) the semaphore budget generalizes to PER-DEVICE
-        budgets: the pump grants mesh residency, and a pod slice of n
-        devices admits n x serving.mesh.deviceBudget times the
-        single-device clamp — N compatible tenants share one
-        mesh-resident partitioned program set instead of serializing
-        behind a single-device limit (docs/pod_serving.md)."""
+        semaphore (its sync_conf) re-sizes admission too."""
         from spark_rapids_tpu.memory.semaphore import TpuSemaphore
 
-        base = max(1, min(self.max_concurrent,
+        return max(1, min(self.max_concurrent,
                           TpuSemaphore.get().permits))
-        from spark_rapids_tpu.serving import (
-            MESH_DEVICE_BUDGET,
-            mesh_serving_enabled,
-        )
-        if mesh_serving_enabled():
-            from spark_rapids_tpu.config import get_conf
-            from spark_rapids_tpu.parallel.mesh import active_mesh
-
-            mesh = active_mesh()
-            if mesh is not None:
-                n = int(mesh.devices.size)
-                per_dev = int(get_conf().get(MESH_DEVICE_BUDGET))
-                base = base * max(1, n) * max(1, per_dev)
-        return base
 
     # -- core -------------------------------------------------------- #
 

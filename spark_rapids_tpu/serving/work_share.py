@@ -64,8 +64,7 @@ SHARING_ENABLED = register(
     "Master switch for cross-tenant work sharing (docs/"
     "work_sharing.md): the process-wide result cache and shared-scan "
     "dedup.  Off (default) = one conf read per query, no cache "
-    "exists.  bench.py --sessions rounds turn it on (--no-sharing "
-    "opts out).")
+    "exists.")
 
 RESULT_CACHE_BUDGET = register(
     "spark.rapids.tpu.serving.resultCache.budgetBytes", 256 << 20,
@@ -373,14 +372,14 @@ _KEY_MEMO_LOCK = threading.Lock()
 
 def _plan_key(plan, conf) -> Optional[str]:
     from spark_rapids_tpu.eventlog import conf_fingerprint
+    from spark_rapids_tpu.parallel.mesh import mesh_cache_suffix
     from spark_rapids_tpu.plan.share_key import plan_share_key
-    from spark_rapids_tpu.serving import mesh_cache_suffix
 
     # mesh suffix in BOTH the memo key and the result key: a cached
     # result's row ORDER is execution-shaped (mesh width changes
     # partition interleaving), so a result minted on one mesh must not
-    # serve another (docs/pod_serving.md)
-    mesh_sfx = mesh_cache_suffix(conf)
+    # serve another
+    mesh_sfx = mesh_cache_suffix()
     fp = conf_fingerprint(conf) + mesh_sfx
     pid = id(plan)
     with _KEY_MEMO_LOCK:

@@ -108,8 +108,8 @@ def _assert_rows_match(name: str, on, off) -> None:
 def count_upload_rows(df) -> int:
     """One TPU collect with ParquetScanExec._upload tapped: total rows
     actually crossing the host->device wire — the number runtime join
-    filters exist to shrink.  Shared by bench.py's q3_upload_rows
-    fields and the test-suite acceptance assertions."""
+    filters exist to shrink (the test-suite acceptance assertions
+    read it)."""
     import spark_rapids_tpu.io.scan as scan_mod
 
     counted = [0]
@@ -133,8 +133,7 @@ def count_upload_bytes(df) -> int:
     (columnar/transfer.upload_stats): total bytes actually crossing
     the H2D wire — compressed components count their packed size, so
     the wire-codec on/off delta IS the bytes the codec kept off the
-    slow link.  Shared by bench.py's q*_upload_bytes_wire /
-    q*_upload_ratio fields and the wire-codec acceptance tests."""
+    slow link (the wire-codec acceptance tests read it)."""
     from spark_rapids_tpu.columnar import transfer
 
     transfer.reset_upload_stats()
@@ -776,8 +775,7 @@ def run_fusion_smoke() -> dict:
             session = TpuSession()
             cold_digest, cold_sum, _ = collect_counted(session)
             # isolate the warm window: chains/saved_dispatches below
-            # describe ONE collect, same semantics as bench.py's
-            # per-query q*_fusion_chains fields
+            # describe ONE collect
             reset_fusion_stats()
             warm_digest, warm_sum, warm_misses = \
                 collect_counted(session)
@@ -1308,134 +1306,6 @@ def run_smoke() -> dict:
     return out
 
 
-def run_mesh_serving_smoke() -> dict:
-    """Pod-scale serving acceptance contract, cheap CI form (tier-1
-    via tests/test_pod_serving.py; docs/pod_serving.md): two sessions
-    on a virtual 4-device mesh with mesh-resident serving enabled —
-
-    - SHARED PROGRAM SET: the second session's executions mint zero
-      new partitioned programs (the jit-key census is flat between
-      sessions: same templates, same conf fingerprint, same mesh key
-      — one mesh-resident program set serves every tenant);
-    - DEVICE-BORN steady state: the second session's window performs
-      zero data-plane host uploads (tapped ``placement.host_uploads``
-      counter; control-plane row-count uploads tallied separately);
-    - a digest gate: every mesh-resident result hashes identical to
-      the serial single-device reference.
-    """
-    import numpy as np
-    import pyarrow as pa
-
-    from spark_rapids_tpu.config import TpuConf, get_conf, set_conf
-    from spark_rapids_tpu.eventlog import table_digest
-    from spark_rapids_tpu.execs.jit_cache import program_census
-    from spark_rapids_tpu.parallel import make_mesh
-    from spark_rapids_tpu.parallel import placement as placement_mod
-    from spark_rapids_tpu.parallel.mesh import (
-        active_mesh,
-        set_active_mesh,
-    )
-    from spark_rapids_tpu.session import TpuSession, col, sum_
-    from spark_rapids_tpu.shuffle.transport import SHUFFLE_TRANSPORT
-
-    import jax
-
-    if len(jax.devices()) < 4:
-        raise AssertionError(
-            "mesh serving smoke needs >= 4 virtual devices "
-            "(tests/conftest.py pins 8)")
-    rng = np.random.default_rng(0x90D)
-    n = 4096
-    t = pa.table({
-        "k": rng.integers(0, 64, n).astype(np.int64),
-        "v": rng.integers(0, 1000, n).astype(np.int64),
-    })
-
-    def templates(s):
-        return [
-            ("agg", s.create_dataframe(t)
-             .group_by(col("k")).agg((sum_(col("v")), "sv"))),
-            ("sort", s.create_dataframe(t).order_by(col("k"))),
-        ]
-    def canon(tbl) -> str:
-        # row-order-insensitive: the collective exchange legitimately
-        # lands agg groups in shard order, not the serial engine's —
-        # canonical row sort first, THEN the content digest
-        return table_digest(
-            tbl.sort_by([(c, "ascending") for c in tbl.column_names]))
-
-
-    def mesh_conf(base: dict) -> TpuConf:
-        over = dict(base)
-        over.update({
-            SHUFFLE_TRANSPORT.key: "collective",
-            "spark.rapids.tpu.shuffle.collective.roundRows": 512,
-            "spark.rapids.tpu.sql.batchSizeRows": 512,
-            "spark.rapids.tpu.serving.mesh.enabled": True,
-        })
-        return TpuConf(over)
-
-    out: dict = {}
-    base = dict(get_conf()._values)
-    prev_mesh = active_mesh()
-    mesh = make_mesh(4)
-    set_active_mesh(mesh)
-    try:
-        # serial single-device reference (mesh serving off, local
-        # transport): the ground truth digests
-        serial_conf = TpuConf(base)
-        serial_conf.set(SHUFFLE_TRANSPORT.key, "local")
-        set_conf(serial_conf)
-        s0 = TpuSession(serial_conf)
-        digests = {name: canon(df.collect(engine="tpu"))
-                   for name, df in templates(s0)}
-
-        # session 1 on the mesh: mints the partitioned program set
-        conf1 = mesh_conf(base)
-        set_conf(conf1)
-        s1 = TpuSession(conf1, tenant="t0")
-        pqs1 = {name: s1.prepare(df) for name, df in templates(s1)}
-        for name, pq in pqs1.items():
-            assert canon(pq.execute()) == digests[name], \
-                f"mesh-resident {name} diverged from serial reference"
-        census1 = program_census()
-
-        # session 2, same templates: must REUSE session 1's programs
-        # (flat census) and move zero data-plane bytes host->device
-        # in its executions (device-born stage inputs)
-        conf2 = mesh_conf(base)
-        set_conf(conf2)
-        s2 = TpuSession(conf2, tenant="t1")
-        pqs2 = {name: s2.prepare(df) for name, df in templates(s2)}
-        placement_mod.reset_stats()
-        for name, pq in pqs2.items():
-            assert canon(pq.execute()) == digests[name], \
-                f"second session's {name} diverged"
-        census2 = program_census()
-        pl = placement_mod.stats()
-        grew = {tag: (census1.get(tag, 0), cnt)
-                for tag, cnt in census2.items()
-                if cnt > census1.get(tag, 0)}
-        assert not grew, (
-            f"second session minted new programs (census grew): {grew}")
-        assert pl["host_uploads"] == 0, (
-            f"mesh-resident steady state moved data-plane bytes "
-            f"host->device: {pl}")
-        out["mesh_serving_programs"] = sum(
-            cnt for tag, cnt in census2.items()
-            if tag.startswith("spmd"))
-        out["mesh_serving_host_uploads"] = pl["host_uploads"]
-        out["mesh_serving_device_born"] = pl["device_born"]
-        out["mesh_serving_adoptions"] = pl["adoptions"]
-    finally:
-        set_active_mesh(prev_mesh)
-        conf = get_conf()
-        conf._values.clear()
-        conf._values.update(base)
-        set_conf(conf)
-    return out
-
-
 def main() -> int:
     import json
 
@@ -1456,7 +1326,6 @@ def main() -> int:
     results.update(run_coalesce_smoke())
     results.update(run_connect_smoke())
     results.update(run_ops_smoke())
-    results.update(run_mesh_serving_smoke())
     print(json.dumps({"bench_smoke": results, "ok": True}))
     return 0
 

@@ -11,15 +11,9 @@ directory, reporting wall time, result digest, jit miss/compile
 counts, ledger dispatch count and the persist.* counter snapshot as
 one JSON line on stdout.
 
-Drivers fork it:
-
-- ``bench.py --cold-start N``: N children against a WARM persist dir
-  vs N against EMPTY dirs -> cold_p50_ms / cold_p99_ms /
-  cold_jit_misses / persist_hit_rate both ways (the rollout-cost
-  artifact).
-- ``tools/bench_smoke.run_warm_start_smoke`` (tier-1): one
-  populate-and-prime pass, then a measured child asserting ZERO
-  compiles and a digest bit-identical to the in-process run.
+``tools/bench_smoke.run_warm_start_smoke`` (tier-1) forks it: one
+populate-and-prime pass, then a measured child asserting ZERO compiles
+and a digest bit-identical to the in-process run.
 
 Run: python -m spark_rapids_tpu.tools.cold_start --data DIR \\
          [--persist DIR]

@@ -9,9 +9,9 @@ four analyses operate on one or many of them:
   per-operator deltas across runs, with a configurable regression
   threshold.  Queries match across runs by *plan fingerprint*
   (normalized-plan hash), so the same query template lines up even
-  when query ids and temp paths differ.  Committed ``BENCH_r0*.json``
-  and ``SWEEP_r0*.json`` round artifacts load as pseudo-applications,
-  so the whole perf trajectory is diffable with one command.
+  when query ids and temp paths differ.  Bench-round JSON lines and
+  ``SWEEP_r0*.json`` round artifacts load as pseudo-applications, so
+  rounds are diffable with one command.
 - ``health``   — HealthCheck: a rule registry flagging unhealthy runs
   (CPU fallbacks, retry storms, spill thrash, jit-cache miss-budget
   blowouts, steady-state blocking readbacks, starved pipelines,
@@ -141,7 +141,7 @@ class QueryRecord:
         return (self.programs or {}).get("totals") or {}
 
     def occupancy(self) -> Optional[float]:
-        """Item-weighted pipeline occupancy (bench.py's formula), or
+        """Item-weighted pipeline occupancy, or
         None when the record carries no pipeline surface."""
         if not self.pipeline:
             return None
@@ -215,10 +215,10 @@ def _query_from_record(rec: dict) -> QueryRecord:
 
 
 # ------------------------------------------------------------------ #
-# Loading (event logs + committed bench rounds)
+# Loading (event logs + bench rounds)
 # ------------------------------------------------------------------ #
 
-#: bench queries a BENCH_r0*.json round reports, with their wall field
+#: bench queries a bench round reports, with their wall field
 _BENCH_QUERIES = (("q6", "tpu_s_per_query"),
                   ("q1", "q1_tpu_s_per_query"),
                   ("q3", "q3_tpu_s_per_query"),
@@ -226,14 +226,14 @@ _BENCH_QUERIES = (("q6", "tpu_s_per_query"),
 
 
 def load_bench_round(path: str) -> ApplicationInfo:
-    """Adapt one committed BENCH_rNN.json round artifact into a
+    """Adapt one bench-round JSON artifact into a
     pseudo-application: one QueryRecord per benchmark query (q6/q1/
     q3/q67) keyed ``bench:<q>`` so rounds line up with each other (and
     never accidentally with real event-log queries)."""
     with open(path) as f:
         data = json.load(f)
     # rounds are stored as the driver's wrapper {"tail": "...json..."}
-    # OR as the bare bench.py output line
+    # OR as the bare output line
     if "metric" not in data and isinstance(data.get("tail"), str):
         for line in reversed(data["tail"].splitlines()):
             line = line.strip()
@@ -604,8 +604,7 @@ def _hc_admission_wait(q: QueryRecord) -> Optional[str]:
     the serving tier is saturated for its traffic.  Fed from the
     serve.admit_wait_ms event-log counter the scheduler deposits per
     query; queries that never passed admission carry no counter and
-    stay silent.  bench.py --sessions reports the fleet-level
-    admission_wait_p99_ms next to this per-query flag."""
+    stay silent."""
     w = q.counter("serve.admit_wait_ms")
     if w <= 0:
         return None

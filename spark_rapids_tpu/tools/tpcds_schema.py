@@ -5,8 +5,8 @@ with small, seeded, referentially-consistent data (FKs land inside
 their dimension's key range; date_dim is a REAL calendar).  The sweep
 harness (tools/sweep.py) registers these with the SQL frontend and
 classifies every query's fate against the CPU oracle — the point is
-grammar/operator coverage and correctness, not scale (bench.py owns
-scale).
+grammar/operator coverage and correctness, not scale (benchmarks/
+owns scale).
 
 Conventions (aligned with the spec where queries depend on it):
 

@@ -2,8 +2,8 @@
 the chip's time, and how close each runs to its roofline.
 
 ROADMAP open item #2 is judged on ``hbm_roofline_fraction``, but until
-this module that number was a single coarse quotient in bench.py
-(wall-clock rows/s x row bytes / HBM bandwidth) — nothing could say
+this module that number was a single coarse quotient (wall-clock
+rows/s x row bytes / HBM bandwidth) — nothing could say
 WHICH XLA program the time went to, how much of a query was dispatch
 overhead versus device compute, or what a program's achieved bytes/s
 and FLOPs/s are against the chip peaks.  The reference stack leans on
@@ -27,8 +27,7 @@ Qualification tools); this is the XLA analog:
   ATTRIBUTED roofline per program — achieved bytes/s and flops/s
   against the chip peaks — plus dispatch-overhead ratios, surfaced in
   ``explain("analyze")`` (per-operator roofline column + top-program
-  footer), bench.py (``q*_device_busy_ms`` / ``q*_roofline_attributed``
-  / top-program fields), the event log (the per-query ``programs``
+  footer), the event log (the per-query ``programs``
   section) and ``tools/history`` (per-program compare deltas, health
   rules HC010/HC011).
 
@@ -58,7 +57,7 @@ LEDGER_ENABLED = register(
     "device wall time (settled off the critical path) and XLA's own "
     "cost model (flops, bytes accessed), from which per-program and "
     "per-operator ATTRIBUTED roofline fractions are computed — "
-    "surfaced in explain('analyze'), bench.py and the event log's "
+    "surfaced in explain('analyze') and the event log's "
     "per-query `programs` section (docs/device_ledger.md).  Off (the "
     "default) the only per-dispatch cost is one attribute read.")
 
@@ -98,21 +97,6 @@ def device_peaks() -> tuple[Optional[float], Optional[float]]:
     from spark_rapids_tpu.memory.device_manager import select_device
 
     return DEVICE_PEAKS.get(select_device().device_kind, (None, None))
-
-
-def roofline_fraction(bytes_per_s: float,
-                      hbm_bytes_per_s: Optional[float] = None
-                      ) -> Optional[float]:
-    """THE roofline formula: achieved bytes/s over the chip's HBM
-    bandwidth (this device's, from DEVICE_PEAKS, unless given).  One
-    definition shared by bench.py's coarse cold/warm quotients and the
-    ledger's per-program attribution.  None on a device without a
-    published peak."""
-    if hbm_bytes_per_s is None:
-        hbm_bytes_per_s = device_peaks()[0]
-    if hbm_bytes_per_s is None:
-        return None
-    return bytes_per_s / hbm_bytes_per_s
 
 
 def key_tag(key: Any) -> str:

@@ -12,7 +12,7 @@ stages sit full or starved.  This module is that view:
 - :func:`sample_now` — one consistent gauge snapshot: device-store
   bytes by tier (device/host/disk), device-semaphore permits in use,
   serving admission queue occupancy (running/waiting), and pipeline
-  stage occupancy (item-weighted, bench.py's formula);
+  stage occupancy (item-weighted);
 - :class:`TelemetrySampler` — a daemon thread sampling at
   ``spark.rapids.tpu.telemetry.hz``; each sample is recorded as
   Chrome-trace COUNTER events (``ph="C"``) when the tracer is on —

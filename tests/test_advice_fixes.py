@@ -1,4 +1,4 @@
-"""Regression tests for round-1 advisor findings (ADVICE.md)."""
+"""Regression tests for the round-1 advisor findings."""
 
 import threading
 

@@ -1,12 +1,20 @@
-"""The bench pipeline is a real query: keep it covered by CI (tiny scale)
-and assert the compile cache makes repeat collects trace-free."""
+"""TPC-H q6 is a real query: keep it covered by CI (tiny scale) and
+assert the compile cache makes repeat collects trace-free."""
 
 import pyarrow as pa
 import pyarrow.parquet as pq
 
-import bench
-from spark_rapids_tpu.session import TpuSession
+from spark_rapids_tpu.exprs.base import lit
+from spark_rapids_tpu.session import TpuSession, col, sum_
 from tests.differential import assert_tpu_cpu_equal
+
+
+def _q6(session, paths):
+    ship, disc = col("l_shipdate"), col("l_discount")
+    return session.read_parquet(*paths).where(
+        (ship >= lit(8766)) & (ship < lit(9131)) & (disc >= lit(0.05))
+        & (disc <= lit(0.07)) & (col("l_quantity") < lit(24.0))).agg(
+        (sum_(col("l_extendedprice") * disc), "revenue"))
 
 
 def _tiny_lineitem(tmp_path, n=1000, files=2):
@@ -29,36 +37,8 @@ def _tiny_lineitem(tmp_path, n=1000, files=2):
 
 def test_bench_q6_differential(tmp_path):
     paths = _tiny_lineitem(tmp_path)
-    df = bench.q6_dataframe(TpuSession(), paths)
+    df = _q6(TpuSession(), paths)
     assert_tpu_cpu_equal(df, approx_float=True)
-
-
-def test_bench_chaos_mode_records_recovery(tmp_path):
-    """bench.py --chaos: the per-query reset re-arms the schedule, the
-    query answers correctly under it, and the q*_retry_splits /
-    _spills_under_pressure / _recovered_faults fields attribute the
-    recovery work (recovered > 0 under chaos, all-zero off)."""
-    from spark_rapids_tpu.config import get_conf
-    from spark_rapids_tpu.execs.retry import RETRY_BACKOFF_S
-    from spark_rapids_tpu.robustness import faults
-
-    get_conf().set(RETRY_BACKOFF_S.key, 0.0)
-    paths = _tiny_lineitem(tmp_path)
-    df = bench.q6_dataframe(TpuSession(), paths)
-    try:
-        bench._CHAOS = True
-        bench.reset_all_counters()  # arms CHAOS_SPEC
-        sp0 = bench._spilled_now()
-        assert_tpu_cpu_equal(df, approx_float=True)
-        fields = bench._robustness_fields("q6", sp0)
-        assert fields["q6_recovered_faults"] > 0, fields
-    finally:
-        bench._CHAOS = False
-        faults.disarm()
-    bench.reset_all_counters()
-    clean = bench._robustness_fields("q6", bench._spilled_now())
-    assert clean["q6_retry_splits"] == 0
-    assert clean["q6_recovered_faults"] == 0
 
 
 def test_repeat_collect_reuses_compiled_programs(tmp_path):
@@ -66,10 +46,10 @@ def test_repeat_collect_reuses_compiled_programs(tmp_path):
 
     paths = _tiny_lineitem(tmp_path)
     session = TpuSession()
-    df = bench.q6_dataframe(session, paths)
+    df = _q6(session, paths)
     df.collect(engine="tpu")
     size_after_first = jit_cache.cache_size()
-    df2 = bench.q6_dataframe(session, paths)  # fresh plan, same structure
+    df2 = _q6(session, paths)  # fresh plan, same structure
     df2.collect(engine="tpu")
     assert jit_cache.cache_size() == size_after_first, (
         "second identical query created new jit wrappers — the global "

@@ -345,8 +345,8 @@ def test_dot_from_event_log(tmp_path, capsys):
 
 
 def test_bench_round_ingest(tmp_path):
-    """Committed BENCH_r0*.json artifacts load as pseudo-apps and
-    compare against each other (the perf-trajectory use case)."""
+    """Bench-round JSON lines load as pseudo-apps and compare against
+    each other (the perf-trajectory use case)."""
     from spark_rapids_tpu.tools.history import (
         compare_applications,
         load_application,

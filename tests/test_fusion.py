@@ -487,22 +487,15 @@ def test_warm_dispatch_budget_gate_trips():
 
 def test_warm_budget_zero_disables_gate():
     """warmDispatchBudget=0 is the documented escape hatch ('0
-    disables the gate'): BOTH halves of the bench gate — the dispatch
-    count AND the warm jit-miss assert — are off, so environments
-    where warm recompiles are expected (backend bring-up) can still
-    run rounds."""
-    import bench
-
+    disables the gate'): the smoke's warm pass pays at least one
+    launch, which a literal budget of 0 would refuse, and the smoke
+    passes."""
     conf = get_conf()
-    bad = {"q1_jit_misses": 3, "q1_dispatches": 10_000}
     conf.set("spark.rapids.tpu.sql.fusion.warmDispatchBudget", 0)
-    bench._assert_warm_budget("q1", bad)  # disabled: no assert
-    conf.set("spark.rapids.tpu.sql.fusion.warmDispatchBudget", 8)
-    with pytest.raises(AssertionError, match="re-compiled"):
-        bench._assert_warm_budget("q1", bad)
-    with pytest.raises(AssertionError, match="warmDispatchBudget"):
-        bench._assert_warm_budget("q1", {"q1_jit_misses": 0,
-                                         "q1_dispatches": 10_000})
+    from spark_rapids_tpu.tools.bench_smoke import run_fusion_smoke
+
+    out = run_fusion_smoke()
+    assert out["fusion_warm_dispatches"] >= 1
 
 
 # ------------------------------------------------------------------ #

@@ -694,8 +694,7 @@ def test_source_lint_flags_raw_device_put():
     """SRC016: a raw `jax.device_put` (or bare imported `device_put`)
     in execs//parallel/ is an ERROR — the transfer bypasses the
     placement choke point's host-upload/device-born/d2d classification
-    and so escapes the pod-serving zero-host-upload gate
-    (docs/pod_serving.md)."""
+    and so moves bytes past its counters (docs/spmd.md)."""
     for path in ("spark_rapids_tpu/execs/fake.py",
                  "spark_rapids_tpu/parallel/fake.py"):
         diags = lint_source_text(_RAW_DEVICE_PUT_FIXTURE, path)
@@ -1323,3 +1322,43 @@ def test_baseline_diff_added_is_informational(tmp_path, capsys):
     # the repo's intentional (normally-baselined) findings surface
     assert payload["added"], "expected the SRC* intentional findings"
     assert all("::" in k for k in payload["added"])
+
+
+# -- layering ----------------------------------------------------------- #
+
+#: modules below the serving tier: the compile chokepoint, the memory
+#: tier, the SPMD stage layer and the collective execs
+_BELOW_SERVING = ("execs/jit_cache.py", "memory", "parallel/spmd.py",
+                  "execs/collective.py")
+
+
+def test_lower_layers_do_not_import_serving():
+    """The layer map's arrows point down: nothing in `_BELOW_SERVING`
+    imports spark_rapids_tpu.serving, at module level or inside a
+    function."""
+    import ast
+    import pathlib
+
+    import spark_rapids_tpu
+
+    root = pathlib.Path(spark_rapids_tpu.__file__).parent
+    files = []
+    for rel in _BELOW_SERVING:
+        p = root / rel
+        files += sorted(p.rglob("*.py")) if p.is_dir() else [p]
+    assert len(files) > len(_BELOW_SERVING)
+    hits = []
+    for f in files:
+        for node in ast.walk(ast.parse(f.read_text())):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                mod = node.module or ""
+                names = [mod] + [f"{mod}.{a.name}" for a in node.names]
+            else:
+                continue
+            if any(n == "spark_rapids_tpu.serving"
+                   or n.startswith("spark_rapids_tpu.serving.")
+                   for n in names):
+                hits.append(f"{f.relative_to(root)}:{node.lineno}")
+    assert hits == [], hits

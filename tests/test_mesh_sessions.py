@@ -1,25 +1,16 @@
-"""Pod-scale serving (docs/pod_serving.md): mesh-resident multi-tenant
-execution with device-born stage inputs.
+"""Serving sessions on a mesh, under the default conf.
 
-- THE tier-1 hook for tools/bench_smoke.run_mesh_serving_smoke (two
-  sessions on a virtual 4-device mesh: shared partitioned program set
-  via the jit-key census, zero steady-state data-plane host uploads
-  via the tapped placement counter, digest gate vs the serial
-  single-device reference);
 - the SPMD x serving digest-identity storm: four concurrent sessions
   x three templates (agg / join / sort) on the virtual 8-device mesh,
   every result bit-identical (canonical row-sorted digest) to the
   serial single-device run;
 - a cancellation storm ON the mesh whose unwinds leave every process
   residency gauge exactly at baseline (conftest.leak_check);
-- mesh re-keying: a pod reshape (mesh shape change) changes
-  mesh_cache_suffix and therefore every prepared-plan template key
-  under an UNCHANGED conf fingerprint — and the default-off posture
-  keeps the suffix empty (flag-off keying bit-identical to the
-  pre-mesh engine);
-- placement classification unit coverage (place_piece /adopt_batch:
-  host vs control vs device-born vs d2d) and the scheduler's
-  mesh-admission budget multiplier.
+- mesh re-keying: a new mesh changes parallel/mesh.mesh_cache_suffix
+  and therefore every prepared-plan template key under an UNCHANGED
+  conf fingerprint, and no mesh keeps the suffix empty;
+- placement classification unit coverage (place_piece / adopt_batch:
+  host vs control vs device-born vs d2d) and the SRC016 choke point.
 """
 
 from __future__ import annotations
@@ -35,18 +26,14 @@ from spark_rapids_tpu.parallel import make_mesh
 from spark_rapids_tpu.parallel import placement
 from spark_rapids_tpu.parallel.mesh import (
     active_mesh,
+    mesh_cache_suffix,
     mesh_key,
     set_active_mesh,
 )
-from spark_rapids_tpu.serving import (
-    mesh_cache_suffix,
-    mesh_serving_enabled,
-    scheduler as scheduler_mod,
-)
+from spark_rapids_tpu.serving import scheduler as scheduler_mod
 from spark_rapids_tpu.session import TpuSession, col, count_star, sum_
 from spark_rapids_tpu.shuffle.transport import SHUFFLE_TRANSPORT
 
-MESH_ENABLED = "spark.rapids.tpu.serving.mesh.enabled"
 ROUND_ROWS = "spark.rapids.tpu.shuffle.collective.roundRows"
 
 
@@ -100,14 +87,13 @@ def _templates(session, fact, dim, sort_t):
     ]
 
 
-def _mesh_conf(rows: int, mesh_serving: bool = True) -> TpuConf:
+def _mesh_conf(rows: int) -> TpuConf:
     over = dict(get_conf()._values)
     over.update({
         SHUFFLE_TRANSPORT.key: "collective",
         ROUND_ROWS: max(256, rows // 8),
         "spark.rapids.tpu.sql.batchSizeRows": max(256, rows // 8),
         "spark.rapids.tpu.sql.autoBroadcastJoinThresholdBytes": -1,
-        MESH_ENABLED: mesh_serving,
     })
     return TpuConf(over)
 
@@ -115,7 +101,6 @@ def _mesh_conf(rows: int, mesh_serving: bool = True) -> TpuConf:
 def _serial_digests(fact, dim, sort_t) -> dict:
     conf = TpuConf(dict(get_conf()._values))
     conf.set(SHUFFLE_TRANSPORT.key, "local")
-    conf.set(MESH_ENABLED, False)
     conf.set("spark.rapids.tpu.sql.autoBroadcastJoinThresholdBytes",
              -1)
     set_conf(conf)
@@ -193,42 +178,34 @@ def test_src016_choke_point_is_clean():
 
 
 def test_mesh_cache_suffix_keys_on_mesh_shape():
-    """A pod reshape changes mesh_cache_suffix (and so every
-    mesh-keyed cache key) under an UNCHANGED conf fingerprint; the
-    default-off posture and the no-mesh posture keep the suffix empty
-    — flag-off cache keying is bit-identical to the pre-mesh
-    engine."""
-    conf = get_conf()
-    assert not mesh_serving_enabled(conf)
-    assert mesh_cache_suffix(conf) == ""
-    conf.set(MESH_ENABLED, True)
+    """A new mesh changes mesh_cache_suffix (and so every mesh-keyed
+    cache key); no active mesh keeps the suffix empty."""
     set_active_mesh(None)
-    assert mesh_cache_suffix(conf) == ""  # enabled but no mesh yet
+    assert mesh_cache_suffix() == ""
     m8 = make_mesh(8)
     set_active_mesh(m8)
-    sfx8 = mesh_cache_suffix(conf)
+    sfx8 = mesh_cache_suffix()
     assert sfx8.startswith("|mesh:") and len(sfx8) == len("|mesh:") + 12
     m4 = make_mesh(4)
     set_active_mesh(m4)
-    sfx4 = mesh_cache_suffix(conf)
+    sfx4 = mesh_cache_suffix()
     assert sfx4.startswith("|mesh:") and sfx4 != sfx8
     assert mesh_key(m4) != mesh_key(m8)
     # back to 8: the suffix is a pure function of the mesh identity
     set_active_mesh(m8)
-    assert mesh_cache_suffix(conf) == sfx8
-    conf.set(MESH_ENABLED, False)
-    assert mesh_cache_suffix(conf) == ""
+    assert mesh_cache_suffix() == sfx8
+    set_active_mesh(None)
+    assert mesh_cache_suffix() == ""
 
 
 def test_template_key_rekeys_on_mesh_shape_change():
-    """The prepared-plan template key folds the mesh identity under
-    mesh serving: same plan, same conf -> different key after a pod
-    reshape (stale partitioned entries can never serve the new mesh),
-    and the same key again when the original shape returns."""
+    """The prepared-plan template key folds the mesh identity: same
+    plan, same conf -> different key on another mesh (stale
+    partitioned entries can never serve it), the same key again when
+    the original mesh returns, and a third key with no mesh."""
     from spark_rapids_tpu.serving.plan_cache import template_key
 
     conf = get_conf()
-    conf.set(MESH_ENABLED, True)
     session = TpuSession(conf)
     fact, _dim, _sort = _tables(rows=64)
     df = (session.create_dataframe(fact)
@@ -240,56 +217,10 @@ def test_template_key_rekeys_on_mesh_shape_change():
     assert k8 != k4
     set_active_mesh(make_mesh(8))
     assert template_key(df._plan, conf) == k8
-    # flag off: mesh identity leaves the key entirely
-    conf.set(MESH_ENABLED, False)
-    koff = template_key(df._plan, conf)
-    set_active_mesh(make_mesh(4))
-    assert template_key(df._plan, conf) == koff
-
-
-def test_scheduler_mesh_admission_budget():
-    """Mesh admission: with an active mesh and mesh serving on, the
-    admission limit scales by n_devices x deviceBudget (the whole pod
-    serves); off — or with no mesh — the limit is the plain clamp."""
-    from spark_rapids_tpu.memory.semaphore import TpuSemaphore
-
-    conf = get_conf()
-    conf.set("spark.rapids.tpu.sql.concurrentTpuTasks", 2)
-    TpuSemaphore.reset()
-    sched = scheduler_mod.QueryScheduler(max_concurrent=2,
-                                         queue_depth=8)
     set_active_mesh(None)
-    base = sched._limit()
-    assert base == 2
-    conf.set(MESH_ENABLED, True)
-    assert sched._limit() == base  # enabled but no mesh
-    set_active_mesh(make_mesh(4))
-    assert sched._limit() == base * 4
-    conf.set("spark.rapids.tpu.serving.mesh.deviceBudget", 2)
-    assert sched._limit() == base * 8
-    conf.set(MESH_ENABLED, False)
-    assert sched._limit() == base
-    TpuSemaphore.reset()
-
-
-# ------------------------------------------------------------------ #
-# The tier-1 smoke hook
-# ------------------------------------------------------------------ #
-
-
-def test_bench_smoke_mesh_serving():
-    """tools/bench_smoke.run_mesh_serving_smoke: two sessions on a
-    virtual 4-device mesh share one partitioned program set (flat
-    census), move zero steady-state data-plane bytes host->device,
-    and hash identical to the serial single-device reference."""
-    from spark_rapids_tpu.tools.bench_smoke import (
-        run_mesh_serving_smoke,
-    )
-
-    out = run_mesh_serving_smoke()
-    assert out["mesh_serving_host_uploads"] == 0
-    assert out["mesh_serving_programs"] >= 1
-    assert out["mesh_serving_device_born"] >= 1
+    koff = template_key(df._plan, conf)
+    assert koff not in (k8, k4)
+    assert template_key(df._plan, conf) == koff
 
 
 # ------------------------------------------------------------------ #
@@ -299,9 +230,8 @@ def test_bench_smoke_mesh_serving():
 
 def test_spmd_serving_digest_identity_four_sessions():
     """Four concurrent sessions x three templates on the virtual
-    8-device mesh with mesh-resident serving: every result (warm and
-    repeat) hashes bit-identical to the serial single-device
-    reference, and the measured repeats compile nothing new."""
+    8-device mesh: every result (warm and repeat) hashes bit-identical
+    to the serial single-device reference, and the measured repeats compile nothing new."""
     from spark_rapids_tpu.execs.jit_cache import cache_stats
 
     fact, dim, sort_t = _tables(rows=2048)
@@ -345,7 +275,7 @@ def test_spmd_serving_digest_identity_four_sessions():
                 errors.append((i, repr(e)))
 
     threads = [threading.Thread(target=run, args=(i,),
-                                name=f"pod-serve-{i}")
+                                name=f"mesh-serve-{i}")
                for i in range(n_sessions)]
     for t in threads:
         t.start()
@@ -366,8 +296,8 @@ def test_spmd_serving_digest_identity_four_sessions():
 
 
 def test_mesh_cancellation_storm_leaves_no_residency(leak_check):
-    """session.cancel() fired mid-flight against mesh-resident
-    executions: every surviving result stays digest-gated, cancelled
+    """session.cancel() fired mid-flight against executions on the
+    mesh: every surviving result stays digest-gated, cancelled
     ones unwind cleanly, and the process residency gauges (permits,
     store bytes, stage threads, scan shares — conftest.leak_check)
     return EXACTLY to baseline."""

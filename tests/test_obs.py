@@ -143,9 +143,9 @@ def test_live_registry_visible_mid_stream():
 
 
 def test_scrape_under_storm_monotone_and_zero_impact():
-    """The bench.py --sessions scrape arm's contract in the fast
-    tier: /metrics scraped concurrently with running queries never
-    shows a monotone counter stepping backwards, and the scraped
+    """The scrape-under-storm contract: /metrics scraped concurrently
+    with running queries never shows a monotone counter stepping
+    backwards, and the scraped
     queries' digests stay bit-identical to the obs-off reference."""
     from spark_rapids_tpu.eventlog import MONOTONIC_COUNTERS, \
         table_digest

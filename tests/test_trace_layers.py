@@ -268,37 +268,3 @@ def test_a_restored_programs_fallback_compile_gets_the_name(monkeypatch):
 
     text = jax.jit(fn).lower(jnp.arange(4)).as_text()
     assert "module @jit_tpu__TpuProjectExec__restored " in text
-
-
-# -- bench._stage_breakdown --------------------------------------------- #
-
-def test_stage_breakdown_reads_spans_and_patches_nothing(two_files):
-    import bench
-    import spark_rapids_tpu.io.scan as scan_mod
-    import spark_rapids_tpu.plan.planner as planner_mod
-    from spark_rapids_tpu.io import fastpar
-
-    before = (fastpar.read_file, scan_mod.ParquetScanExec._upload,
-              planner_mod.to_arrow)
-    seen = []
-    df = TpuSession().read_parquet(*two_files).group_by(col("k")).agg(
-        (sum_(col("v")), "s"))
-    real = df.collect
-
-    def collect(**kw):
-        # while the collect runs the engine's functions are themselves
-        seen.append((fastpar.read_file, scan_mod.ParquetScanExec._upload,
-                     planner_mod.to_arrow))
-        return real(**kw)
-
-    df.collect = collect
-    out = bench._stage_breakdown(df, "q")
-    assert seen == [before]
-    assert set(out) == {"q_stage_host_decode_s", "q_stage_wire_upload_s",
-                        "q_stage_final_fetch_s", "q_stage_other_s"}
-    assert out["q_stage_host_decode_s"] > 0
-    assert out["q_stage_wire_upload_s"] > 0
-    assert out["q_stage_final_fetch_s"] > 0
-    # the tracer is left as it was found
-    assert not trace.is_enabled()
-    assert not hasattr(bench, "_StageTaps")
