@@ -53,13 +53,14 @@ def _fixed_host(arr: pa.Array, dtype: T.DataType, cap: int
     n = len(arr)
     phys = T.to_numpy_dtype(dtype)
     if isinstance(dtype, T.DecimalType):
-        np_vals = np.zeros(n, np.int64)
-        pylist = arr.to_pylist()
-        scale = dtype.scale
-        for i, v in enumerate(pylist):
-            if v is not None:
-                np_vals[i] = int(v.scaleb(scale))
-        validity = np.array([v is not None for v in pylist], np.bool_)
+        validity = np.asarray(arr.is_valid()) if arr.null_count else None
+        np_vals = decimal_words(arr)
+        if not dtype.wide:
+            np_vals = np_vals[:, 0]  # |v| < 10^18: the low word is v
+        if validity is not None:
+            np_vals = np.where(
+                validity.reshape((-1,) + (1,) * (np_vals.ndim - 1)),
+                np_vals, 0)
     else:
         # zero-copy-ish: fill nulls then view as numpy
         if arr.null_count:
@@ -79,7 +80,7 @@ def _fixed_host(arr: pa.Array, dtype: T.DataType, cap: int
         # full batch)
         data = np.ascontiguousarray(np_vals.astype(phys, copy=False))
     else:
-        data = np.zeros(cap, phys)
+        data = np.zeros((cap,) + np_vals.shape[1:], phys)
         data[:n] = np_vals.astype(phys, copy=False)
     if validity is None and n == cap:
         vhost = None  # fully valid: the device-shared mask stands in
@@ -87,6 +88,34 @@ def _fixed_host(arr: pa.Array, dtype: T.DataType, cap: int
         vhost = np.zeros(cap, np.bool_)
         vhost[:n] = True if validity is None else validity
     return data, vhost
+
+
+def decimal_words(arr: pa.Array) -> np.ndarray:
+    """A decimal128 array's values as `(n, 2)` int64 words (low, high):
+    its value buffer as it lies, no per-value conversion.  Null slots
+    hold whatever the buffer holds."""
+    n = len(arr)
+    buf = arr.buffers()[1]
+    if buf is None:
+        return np.zeros((n, 2), np.int64)
+    words = np.frombuffer(buf, np.int64)
+    return words[2 * arr.offset: 2 * (arr.offset + n)].reshape(n, 2)
+
+
+def _decimal_to_arrow(vals: np.ndarray, valid: np.ndarray,
+                      dtype: T.DecimalType, atype) -> pa.Array:
+    """Host words (an int64 per row, or `(n, 2)` words) -> decimal128,
+    built on the buffers."""
+    n = len(valid)
+    vals = np.asarray(vals, np.int64)
+    if vals.ndim == 1:
+        vals = np.stack([vals, vals >> 63], axis=1)
+    vals = np.where(valid[:, None], vals, 0)
+    bitmap = None
+    if n and not valid.all():
+        bitmap = pa.array(valid, pa.bool_()).buffers()[1]
+    return pa.Array.from_buffers(
+        atype, n, [bitmap, pa.py_buffer(np.ascontiguousarray(vals))])
 
 
 def _zero_value(dtype: T.DataType):
@@ -560,11 +589,8 @@ def _host_col_to_arrow(col, dtype: T.DataType, n: int,
     # fixed-width
     vals, valid = col.data[:n], col.validity[:n]
     if isinstance(dtype, T.DecimalType):
-        import decimal
-
-        pylist = [decimal.Decimal(int(vals[i])).scaleb(-dtype.scale)
-                  if valid[i] else None for i in range(n)]
-        return pa.array(pylist, type=atype)
+        return _decimal_to_arrow(vals, np.asarray(valid, bool), dtype,
+                                 atype)
     mask = ~valid if (~valid).any() else None
     if isinstance(dtype, T.DateType):
         return pa.array(vals.astype("int32"), pa.int32(),

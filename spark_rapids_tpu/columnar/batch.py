@@ -259,6 +259,9 @@ def _col_to_pylist(col, dtype: T.DataType, n: int) -> list:
                 for i in range(n)]
     vals = np.asarray(col.data)[:n]
     valid = np.asarray(col.validity)[:n]
+    if vals.ndim == 2:  # a wide decimal's words: its unscaled integer
+        return [(int(vals[i, 1]) << 64) + (int(vals[i, 0]) & (2**64 - 1))
+                if valid[i] else None for i in range(n)]
     return [(vals[i].item() if valid[i] else None) for i in range(n)]
 
 

@@ -146,7 +146,8 @@ def is_shared_array(a) -> bool:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class Column:
-    """A fixed-width device column: `data[capacity]` + `validity[capacity]`.
+    """A fixed-width device column: `data[capacity]` + `validity[capacity]`
+(`data[capacity, 2]` for a decimal past precision 18, `types.py`).
 
     Rows past the owning batch's `num_rows` are padding with arbitrary data
     and validity False.
@@ -213,8 +214,16 @@ class Column:
         n = len(values)
         cap = capacity if capacity is not None else pad_capacity(n)
         phys = T.to_numpy_dtype(dtype)
-        data = np.zeros(cap, dtype=phys)
-        data[:n] = values.astype(phys, copy=False)
+        if T.is_wide_decimal(dtype):
+            # two words a row (types.py); one value a row widens
+            data = np.zeros((cap, 2), dtype=phys)
+            values = np.asarray(values).astype(phys, copy=False)
+            if values.ndim == 1:
+                values = np.stack([values, values >> 63], axis=1)
+            data[:n] = values
+        else:
+            data = np.zeros(cap, dtype=phys)
+            data[:n] = values.astype(phys, copy=False)
         valid = np.zeros(cap, dtype=np.bool_)
         if validity is None:
             valid[:n] = True

@@ -135,6 +135,16 @@ def _decode_fixed_host(arr: pa.Array, dtype: T.DataType
     from spark_rapids_tpu.columnar.arrow import _zero_value
 
     phys = T.to_numpy_dtype(dtype)
+    if isinstance(dtype, T.DecimalType):
+        # one int64 word a value (narrow: the encoder refuses wide),
+        # read off the decimal128 buffer's low words
+        from spark_rapids_tpu.columnar.arrow import decimal_words
+
+        validity = np.asarray(arr.is_valid()) if arr.null_count else None
+        vals = decimal_words(arr)[:, 0]
+        if validity is not None:
+            vals = np.where(validity, vals, 0)
+        return np.ascontiguousarray(vals), validity
     if arr.null_count:
         validity = np.asarray(arr.is_valid())
         arr = arr.fill_null(_zero_value(dtype))
@@ -324,8 +334,10 @@ def encode_for_device(arrays: Sequence[pa.Array], schema: T.Schema,
                       n: int) -> Optional[tuple[list, tuple]]:
     """Encode decoded host Arrow columns into (components, plan).
 
-    Returns None when a column type has no wire encoding yet (decimal,
-    list) — callers fall back to the per-component padded upload path.
+    Returns None when a column type has no wire encoding yet (a decimal
+    past precision 18, list) — callers fall back to the per-component
+    padded upload path.  A decimal of one int64 word travels as that
+    word, under the integer encodings.
     Traced as `wire.encode` on the encoding thread; its `wire_bytes`
     are what `upload_components` will count for these components.
     """
@@ -344,8 +356,8 @@ def encode_for_device(arrays: Sequence[pa.Array], schema: T.Schema,
 def _encode_components(arrays: Sequence[pa.Array], schema: T.Schema,
                        n: int) -> Optional[tuple[list, tuple]]:
     for f in schema.fields:
-        if isinstance(f.dtype, (T.DecimalType, T.ListType,
-                                T.StructType, T.MapType)):
+        if T.is_wide_decimal(f.dtype) or isinstance(
+                f.dtype, (T.ListType, T.StructType, T.MapType)):
             return None
     if n == 0:
         return None

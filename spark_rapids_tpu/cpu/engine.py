@@ -1615,7 +1615,7 @@ def _aggregate_cpu(plan: L.Aggregate) -> pa.Table:
         op = fn.update_ops()[0]
         if op == "sum":
             arr = arr.cast(T.to_arrow_type(fn.partial_dtypes()[0]))
-        if fn.name == "average":
+        if fn.name == "average" and not _decimal_agg(fn):
             arr = arr.cast(pa.float64())
         cols.append(arr)
         names.append(in_name)
@@ -1644,13 +1644,13 @@ def _aggregate_cpu(plan: L.Aggregate) -> pa.Table:
             aggs.append(([], "count_all"))
         elif fname == "count":
             aggs.append((in_names[0], "count"))
-        elif fname == "average":
+        elif fname == "average" and not _decimal_agg(fn):
             aggs.append((in_names[0], "mean"))
         elif fname in ("first", "last"):
             # Spark defaults ignoreNulls=false; pyarrow defaults skip
             aggs.append((in_names[0], fname, pc.ScalarAggregateOptions(
                 skip_nulls=fn.ignore_nulls, min_count=0)))
-        elif fname in ("collectlist", "collectset"):
+        elif fname in ("collectlist", "collectset") or _decimal_agg(fn):
             aggs.append((in_names[0], "list"))
             nan_fix[si] = ("collect", in_names[0], fname)
         elif fname in ("min", "max") and pa.types.is_floating(
@@ -1685,6 +1685,14 @@ def _aggregate_cpu(plan: L.Aggregate) -> pa.Table:
         if isinstance(nan_fix.get(si), tuple):
             _tag, base, fname2 = nan_fix[si]
             lists = res.column(f"{base}_list").to_pylist()
+            if _decimal_agg(fn):
+                out_arrays.append(pa.array(
+                    [_decimal_agg_value(fn, [x for x in (lv or [])
+                                             if x is not None])
+                     for lv in lists],
+                    type=aschema.field(n_keys + si).type))
+                ai += 1
+                continue
             out = []
             for lv in lists:
                 xs = [x for x in (lv or []) if x is not None]
@@ -1720,10 +1728,52 @@ def _aggregate_cpu(plan: L.Aggregate) -> pa.Table:
                                 names=aschema.names).cast(aschema)
 
 
+def _decimal_agg(fn) -> bool:
+    """A sum or average over a decimal input: Spark's exact decimal
+    semantics, computed here in Python decimals."""
+    from spark_rapids_tpu.exprs import aggregates as AGG
+
+    return (isinstance(fn, (AGG.Sum, AGG.Average)) and fn.child is not None
+            and isinstance(fn.child.dtype, T.DecimalType))
+
+
+def _decimal_agg_value(fn, xs: list):
+    """Spark's answer of a decimal sum or average over the non-null
+    values `xs`: the exact sum, NULL past the sum type's precision; the
+    average as `sum / count` at the divide type's scale and then at the
+    result's, each rounded HALF_UP, NULL past either precision."""
+    import decimal as _dec
+
+    from spark_rapids_tpu.exprs import aggregates as AGG
+    from spark_rapids_tpu.exprs.decimal import divide_type
+
+    if not xs:
+        return None
+
+    def held(v, dt):
+        q = v.quantize(_dec.Decimal(1).scaleb(-dt.scale),
+                       rounding=_dec.ROUND_HALF_UP)
+        return None if abs(q) >= _dec.Decimal(10) ** (
+            dt.precision - dt.scale) else q
+
+    with _dec.localcontext() as ctx:
+        ctx.prec = 120
+        sum_type = fn.partial_dtypes()[0]
+        total = held(sum(xs, _dec.Decimal(0)), sum_type)
+        if total is None or isinstance(fn, AGG.Sum):
+            return total
+        q = held(total / len(xs), divide_type(sum_type))
+        return None if q is None else held(q, fn.dtype)
+
+
 def _grand_agg(proj: pa.Table, in_names, fname, fn=None) -> pa.Scalar:
     if fname == "count_all":
         return pa.scalar(proj.num_rows, pa.int64())
     col = proj.column(in_names[0])
+    if fn is not None and _decimal_agg(fn):
+        return pa.scalar(_decimal_agg_value(
+            fn, [x for x in col.to_pylist() if x is not None]),
+            T.to_arrow_type(fn.dtype))
     if fname == "count":
         return pa.scalar(len(col) - col.null_count, pa.int64())
     if fname == "average":

@@ -37,6 +37,7 @@ from spark_rapids_tpu.exprs.base import (
     Expression,
     bind_references,
 )
+from spark_rapids_tpu.ops import int128 as W
 from spark_rapids_tpu.ops.groupby import (
     AggSpec,
     RollupLevels,
@@ -71,14 +72,36 @@ _DEFER_SYNC_CAP = 1 << 18
 _PATHS: dict = {}
 
 
+#: program key -> the 128-bit decimal operations its trace holds
+#: (`ops/int128.note`), one entry an operation over the program's rows
+_WIDE_OPS: dict = {}
+
+#: the output key under which a program that can set decimals to NULL
+#: for overflow returns how many it did, beside its batch
+_OVERFLOWS = "decimal_overflow_rows"
+
+
 def _noting_path(key: tuple, fn):
-    """`fn`, remembering under `key` which group-by path it takes
-    when it is traced.  The wrapper runs at trace time alone."""
+    """`fn`, remembering under `key` which group-by path it takes and
+    which 128-bit decimal operations it holds when it is traced.  The
+    wrapper runs at trace time alone."""
     def traced(*args):
-        with noting_paths() as paths:
+        with noting_paths() as paths, W.noting() as wide:
             out = fn(*args)
         _PATHS[key] = "+".join(paths) or "none"
+        _WIDE_OPS[key] = tuple(wide)
         return out
+    return traced
+
+
+def _counting_overflows(fn):
+    """`fn`, returning beside its output how many values a decimal
+    precision check set to NULL in it, where its trace holds such a
+    check (a dict `{"out", _OVERFLOWS}`); its output alone otherwise."""
+    def traced(*args):
+        with W.counting_overflows() as found:
+            out = fn(*args)
+        return {"out": out, _OVERFLOWS: sum(found)} if found else out
     return traced
 
 
@@ -242,7 +265,75 @@ class TpuHashAggregateExec(TpuExec):
 
     def additional_metrics(self):
         return [("numMerges", "MODERATE"), ("specHits", "MODERATE"),
-                ("specOverflows", "MODERATE")]
+                ("specOverflows", "MODERATE"),
+                ("decimalOverflowRows", "ESSENTIAL")]
+
+    def _overflows_taken(self, out):
+        """A program's batch, its count of decimals set to NULL for
+        overflow (where it returns one) deferred into
+        `decimalOverflowRows`: summed when the metric is read, so no
+        readback here."""
+        if isinstance(out, dict) and _OVERFLOWS in out:
+            self.metrics["decimalOverflowRows"].add_lazy(out[_OVERFLOWS])
+            return out["out"]
+        return out
+
+    def _decimal_words_read(self) -> int:
+        """The device words a row of the decimal columns the update
+        program reads takes (one narrow, two wide): the source's
+        columns that the absorbed filters and projections and the
+        update's inputs refer to, all of the source's decimals where
+        the chain holds anything else."""
+        from spark_rapids_tpu.execs.basic import (
+            TpuFilterExec,
+            TpuProjectExec,
+        )
+
+        if self.mode == "final":
+            return 0
+        chain = self._absorbed_chain()
+        needed: set = set()
+        for e in self.input_exprs:
+            _ordinals_read(e, needed)
+        # the chain runs from the source up: walk it down
+        for e in reversed(chain[0] if chain is not None else []):
+            if isinstance(e, TpuFilterExec):
+                _ordinals_read(e.condition, needed)
+            elif isinstance(e, TpuProjectExec):
+                below: set = set()
+                for i in needed:
+                    _ordinals_read(e.exprs[i], below)
+                needed = below
+            else:
+                needed = set(range(len(self._source_node().schema)))
+                break
+        fields = self._source_node().schema.fields
+        return sum(2 if T.is_wide_decimal(fields[i].dtype) else 1
+                   for i in needed
+                   if isinstance(fields[i].dtype, T.DecimalType))
+
+    def _wide_count(self, key: tuple, rows, span, merge: bool) -> None:
+        """With the tracer on: one `decimal.wide` event per 128-bit
+        operation kind the program under `key` holds, with the rows it
+        ran over (its input's, padding included) times how often the
+        program applies it; on `span` (where given), `wide_decimal`, the
+        number of 128-bit accumulators the aggregate keeps."""
+        ops = _WIDE_OPS.get(key)
+        if not ops or not rows:
+            return
+        kinds: dict = {}
+        for op in ops:
+            # the group-by's decimal sum is an update or a merge by the
+            # program it runs in
+            op = op if op != "sum" else ("merge" if merge
+                                         else "sum_update")
+            kinds[op] = kinds.get(op, 0) + 1
+        for op, times in kinds.items():
+            _trace.event("decimal.wide", op=op, rows=rows * times)
+        if span is not None:
+            span.note(wide_decimal=sum(
+                1 for f in self.partial_schema.fields
+                if T.is_wide_decimal(f.dtype)))
 
     # -- traceable phases ------------------------------------------------ #
 
@@ -256,7 +347,7 @@ class TpuHashAggregateExec(TpuExec):
             for op, pdt in zip(ops, pdts):
                 # all current fns have <=1 input; count_star reads none
                 ord_ = io if n_in else 0
-                specs.append(AggSpec(op, ord_, out_dtype=pdt))
+                specs.append(AggSpec(op, ord_, out_dtype=pdt, raw=True))
             io += n_in
         return specs
 
@@ -357,8 +448,12 @@ class TpuHashAggregateExec(TpuExec):
         with _trace.span("agg.merge", capacity=partials.capacity,
                          rows=rows if isinstance(rows, int) else None,
                          pending=pending,
-                         path=_PATHS.get(self._path_keys[1])):
-            return self._jit_merge(_as_device_rows(partials))
+                         path=_PATHS.get(self._path_keys[1])) as sp:
+            out = self._jit_merge(_as_device_rows(partials))
+            if _trace.TRACER.enabled:
+                self._wide_count(self._path_keys[1], partials.capacity, sp,
+                                 merge=True)
+            return out
 
     def _tick_absorbed(self, batch) -> None:
         """Output rows of the absorbed execs whose count the input's
@@ -420,11 +515,15 @@ class TpuHashAggregateExec(TpuExec):
             (b.capacity, isinstance(b.num_rows, int),
              tuple(c.width for c in b.columns if hasattr(c, "width")))
             for b in batches)
-        fn = cached_jit(("aggdrainfused", self._cache_key(), struct),
-                        lambda: prog, op=self.name)
+        key = ("aggdrainfused", self._cache_key(), struct)
+        fn = cached_jit(key, lambda: _noting_path(
+            key, _counting_overflows(prog)), op=self.name)
         with MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
-            out = t.observe(fn([b.with_device_num_rows()
-                                for b in batches]))
+            out = t.observe(self._overflows_taken(
+                fn([b.with_device_num_rows() for b in batches])))
+        if _trace.TRACER.enabled:
+            self._wide_count(key, sum(b.capacity for b in batches), None,
+                             merge=True)
         for h in pending:
             h.close()
         pending.clear()
@@ -588,7 +687,7 @@ class TpuHashAggregateExec(TpuExec):
              for i, e in enumerate(self.input_exprs) if i != gid_position],
             T.Schema([f for i, f in enumerate(fields)
                       if i != gid_position]),
-            [AggSpec(s.op, ordinal(s.ordinal), s.out_dtype)
+            [AggSpec(s.op, ordinal(s.ordinal), s.out_dtype, s.raw)
              for s in self._update_specs()])
 
     def execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
@@ -677,7 +776,9 @@ class TpuHashAggregateExec(TpuExec):
                 upd_key = key + ("absorb", ckeys, "update") \
                     if rollup is None else key + ("absorb", ckeys, "rollup")
                 upd = cached_jit(upd_key,
-                                 lambda: _noting_path(upd_key, update_full),
+                                 lambda: _noting_path(
+                                     upd_key,
+                                     _counting_overflows(update_full)),
                                  op=self.name)
                 # the donated twin: same traced program, wire
                 # components donate_argnums'd so XLA reuses their HBM
@@ -691,7 +792,8 @@ class TpuHashAggregateExec(TpuExec):
                 )
 
                 upd_d = cached_jit(
-                    upd_key, lambda: _noting_path(upd_key, update_full),
+                    upd_key, lambda: _noting_path(
+                        upd_key, _counting_overflows(update_full)),
                     op=self.name,
                     donate=(0,)) if donation_enabled() else None
                 self._path_keys = (upd_key, key + ("merge",))
@@ -702,7 +804,9 @@ class TpuHashAggregateExec(TpuExec):
                                                     self._merge_batch),
                                op=self.name),
                     cached_jit(key + ("final",),
-                               lambda: self._finalize_batch,
+                               lambda: _noting_path(
+                                   key + ("final",), _counting_overflows(
+                                       self._finalize_batch)),
                                op=self.name))
             (self._jit_update, self._jit_update_donated,
              self._jit_merge, self._jit_finalize) = self._jits
@@ -869,6 +973,8 @@ class TpuHashAggregateExec(TpuExec):
 
         levels = {} if self._rollup is None \
             else {"levels": len(self._rollup.shape.levels)}
+        decimal_words_read = self._decimal_words_read() \
+            if _trace.TRACER.enabled else 0
 
         def dispatch(batch):
             """Async half: the update program for batch k+1 is
@@ -884,7 +990,7 @@ class TpuHashAggregateExec(TpuExec):
             with _trace.span("agg.update",
                              capacity=getattr(batch, "capacity", None),
                              path=_PATHS.get(self._path_keys[0]),
-                             **levels), \
+                             **levels) as sp, \
                     MetricTimer(self.metrics[TOTAL_TIME], op=self.name) as t:
                 enc = isinstance(batch, EncodedBatch)
                 if enc and self._jit_update_donated is not None:
@@ -894,14 +1000,22 @@ class TpuHashAggregateExec(TpuExec):
                     # tick (q*_fused_dispatch_savings would otherwise
                     # over-report under --chaos)
                     resumed = batch.consumed
-                    out = run_consuming(self._jit_update_donated, batch)
+                    out = self._overflows_taken(
+                        run_consuming(self._jit_update_donated, batch))
                     donated_units[id(out)] = batch
                     if not resumed:
                         record_fused_dispatch(chain_len,
                                               decode_fused=True)
                 else:
-                    out = self._jit_update(_as_device_rows(batch))
+                    out = self._overflows_taken(
+                        self._jit_update(_as_device_rows(batch)))
                     record_fused_dispatch(chain_len, decode_fused=enc)
+                if _trace.TRACER.enabled:
+                    self._wide_count(self._path_keys[0],
+                                     getattr(batch, "capacity", None), sp,
+                                     merge=False)
+                    if _WIDE_OPS.get(self._path_keys[0]):
+                        sp.note(decimal_input_words=decimal_words_read)
                 return t.observe(out)
 
         def merge_and_park(park):
@@ -1077,7 +1191,8 @@ class TpuHashAggregateExec(TpuExec):
             # SOURCE node's schema (the chain may include projections)
             eb = ColumnarBatch.empty(self._source_node().schema)
             if self.mode != "final":
-                eb = self._jit_update(_as_device_rows(eb))
+                eb = self._overflows_taken(
+                    self._jit_update(_as_device_rows(eb)))
             pending.append(store.register(
                 eb, SpillPriorities.AGGREGATE_PARTIAL))
 
@@ -1100,7 +1215,12 @@ class TpuHashAggregateExec(TpuExec):
                     m = self._merge_traced(m, len(pending))
                 if self.mode == "partial":
                     return m
-                return self._jit_finalize(_as_device_rows(m))
+                out = self._overflows_taken(
+                    self._jit_finalize(_as_device_rows(m)))
+                if _trace.TRACER.enabled:
+                    self._wide_count(self._cache_key() + ("final",),
+                                     m.capacity, None, merge=False)
+                return out
 
             out = R.run_with_oom_retry(final_att, desc="agg.drain")
             finish_drain()

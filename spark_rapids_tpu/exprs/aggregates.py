@@ -77,11 +77,23 @@ class AggregateFunction:
 
 
 class Sum(AggregateFunction):
+    """sum(expr).  Over a decimal(p, s) it is Spark's decimal(min(p +
+    10, 38), s), summed exactly (`ops/int128.py`): a partial that
+    overflowed carries a mark through the merge and the answer is
+    NULL."""
+
     def update_ops(self):
         return ["sum"]
 
     def merge_ops(self):
         return ["sum"]
+
+    def finalize_expr(self, partial_refs):
+        if isinstance(self.dtype, T.DecimalType):
+            from spark_rapids_tpu.exprs.decimal import DecimalSumResult
+
+            return DecimalSumResult(partial_refs[0])
+        return partial_refs[0]
 
 
 class Count(AggregateFunction):
@@ -200,7 +212,9 @@ class Average(AggregateFunction):
     """avg = sum / count, decomposed exactly like the reference's
     GpuAverage (AggregateFunctions.scala): partials [sum, count],
     merge [sum, sum], finalize sum/count (NULL when count == 0 — Divide
-    by zero yields NULL, matching Spark's null-safe average)."""
+    by zero yields NULL, matching Spark's null-safe average).  Over a
+    decimal(p, s) the sum is Spark's decimal(p + 10, s) and the answer
+    decimal(p + 4, s + 4), as `DecimalAverage` computes it."""
 
     def n_partials(self) -> int:
         return 2
@@ -211,14 +225,31 @@ class Average(AggregateFunction):
     def merge_ops(self):
         return ["sum", "sum"]
 
+    def _decimal_input(self):
+        dt = self.child.dtype if self.child is not None else None
+        return dt if isinstance(dt, T.DecimalType) else None
+
     def partial_dtypes(self):
+        dec = self._decimal_input()
+        if dec is not None:
+            return [agg_output_dtype(AggSpec("sum", 0), dec), T.LONG]
         return [T.DOUBLE, T.LONG]
 
     @property
     def dtype(self) -> T.DataType:
+        dec = self._decimal_input()
+        if dec is not None:
+            from spark_rapids_tpu.exprs.decimal import average_type
+
+            return average_type(dec)
         return T.DOUBLE
 
     def finalize_expr(self, partial_refs):
+        if self._decimal_input() is not None:
+            from spark_rapids_tpu.exprs.decimal import DecimalAverage
+
+            return DecimalAverage(partial_refs[0], partial_refs[1],
+                                  self.dtype)
         from spark_rapids_tpu.exprs.arithmetic import Divide
 
         return Divide(partial_refs[0], partial_refs[1])

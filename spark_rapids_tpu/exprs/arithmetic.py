@@ -80,58 +80,71 @@ class BinaryArithmetic(Expression):
 
 
 class _DecimalAddSub(BinaryArithmetic):
-    """Shared decimal path for +/-: Spark's analyzer result type
-    (p = max integral digits + max scale + 1, s = max scale, ref:
-    decimalExpressions.scala / DecimalPrecision) with operands rescaled
-    to the result scale — exact unscaled int64 math while the result
-    precision fits MAX_PRECISION; wider falls back."""
+    """Shared decimal path for +/-: Spark's result type (p = max
+    integral digits + max scale + 1, s = max scale, then
+    `adjustPrecisionScale`; ref: decimalExpressions.scala /
+    DecimalPrecision), an integral operand taken as a decimal of scale
+    0.  The operands rescale to the result scale and add exactly: in
+    one int64 up to precision 18, in 128 bits past it.  Where the
+    operands do not rescale within 38 digits (so the result would need
+    rounding or could overflow) the expression falls back."""
 
     def _decimal_result(self, l: T.DecimalType,
                         r: T.DecimalType) -> T.DecimalType:
         s = max(l.scale, r.scale)
         p = max(l.precision - l.scale, r.precision - r.scale) + s + 1
-        return T.DecimalType(min(p, T.DecimalType.MAX_PRECISION), s)
+        return T.adjust_precision_scale(p, s)
 
     @property
     def dtype(self) -> T.DataType:
-        l, r = self.left.dtype, self.right.dtype
-        if isinstance(l, T.DecimalType) and isinstance(r, T.DecimalType):
-            return self._decimal_result(l, r)
-        return result_numeric_type(l, r)
+        from spark_rapids_tpu.exprs import decimal as DEC
+
+        pair = DEC.decimal_pair_or_none(self.left, self.right)
+        if pair is not None:
+            return self._decimal_result(*pair)
+        return result_numeric_type(self.left.dtype, self.right.dtype)
 
     def check_supported(self) -> None:
+        from spark_rapids_tpu.exprs import decimal as DEC
+
         try:
-            l, r = self.left.dtype, self.right.dtype
+            pair = DEC.decimal_operands(self.left, self.right)
         except RuntimeError:
             return  # unbound; the planner re-checks after binding
-        ldec = isinstance(l, T.DecimalType)
-        rdec = isinstance(r, T.DecimalType)
-        if ldec != rdec:
-            raise TypeError("decimal +/- with a non-decimal operand "
-                            "falls back")
-        if ldec:
-            s = max(l.scale, r.scale)
-            p = max(l.precision - l.scale, r.precision - r.scale) + s + 1
-            if p > T.DecimalType.MAX_PRECISION:
-                raise TypeError(
-                    "decimal +/- beyond precision 18 falls back "
-                    "(unscaled int64 math would overflow)")
+        if pair is not None and not DEC.fits_128(*pair):
+            raise TypeError(
+                "decimal +/- whose operands do not rescale within "
+                "precision 38 falls back (the sum could need rounding)")
 
     def eval(self, ctx: EvalContext) -> AnyColumn:
+        from spark_rapids_tpu.exprs import decimal as DEC
+        from spark_rapids_tpu.ops import int128 as W
+
         out = self.dtype
         if not isinstance(out, T.DecimalType):
             return super().eval(ctx)
-        import jax.numpy as jnp
-
         lc = self.left.eval(ctx)
         rc = self.right.eval(ctx)
-        ls = out.scale - self.left.dtype.scale
-        rs = out.scale - self.right.dtype.scale
-        ld = lc.data * jnp.int64(10 ** ls) if ls else lc.data
-        rd = rc.data * jnp.int64(10 ** rs) if rs else rc.data
         valid = broadcast_validity(lc, rc)
-        data, valid = self.compute(ld, rd, valid)
-        return Column(data, valid, out)
+        ld = DEC.operand_data(lc, self.left)
+        rd = DEC.operand_data(rc, self.right)
+        lt, rt = DEC.layout(self.left), DEC.layout(self.right)
+        if not out.wide:
+            ls = out.scale - lt.scale
+            rs = out.scale - rt.scale
+            ld = ld * jnp.int64(10 ** ls) if ls else ld
+            rd = rd * jnp.int64(10 ** rs) if rs else rd
+            data, valid = self.compute(ld, rd, valid)
+            return Column(data, valid, out)
+        # the operands rescale within 38 digits (check_supported), so
+        # neither the rescale nor the sum can overflow 128 bits
+        W.note("add_sub")
+        a = W.words(W.rescaled(ld, lt, out)[0])
+        b = W.words(W.rescaled(rd, rt, out)[0])
+        if isinstance(self, Subtract):
+            b = W.neg(*b)
+        lo, hi, _ = W.add(a, b)
+        return Column(W.pack(lo, hi), valid, out)
 
 
 def _overflow_message(phys) -> str:
@@ -179,16 +192,47 @@ class Multiply(BinaryArithmetic):
 
     @property
     def dtype(self) -> T.DataType:
-        l, r = self.left.dtype, self.right.dtype
-        if isinstance(l, T.DecimalType) and isinstance(r, T.DecimalType):
-            # Spark DecimalPrecision: scale adds, precision p1+p2+1 —
-            # the declared type the CPU fallback must produce (device
-            # multiply over decimals is not supported; TypeSig refuses)
-            return T.DecimalType(
-                min(l.precision + r.precision + 1,
-                    T.DecimalType.MAX_PRECISION),
-                min(l.scale + r.scale, T.DecimalType.MAX_PRECISION))
-        return result_numeric_type(l, r)
+        from spark_rapids_tpu.exprs import decimal as DEC
+
+        pair = DEC.decimal_pair_or_none(self.left, self.right)
+        if pair is not None:
+            # Spark DecimalPrecision: scale adds, precision p1+p2+1,
+            # then adjustPrecisionScale (allowPrecisionLoss)
+            l, r = pair
+            return T.adjust_precision_scale(l.precision + r.precision + 1,
+                                            l.scale + r.scale)
+        return result_numeric_type(self.left.dtype, self.right.dtype)
+
+    def check_supported(self) -> None:
+        from spark_rapids_tpu.exprs import decimal as DEC
+
+        try:
+            DEC.decimal_operands(self.left, self.right)
+        except RuntimeError:
+            return  # unbound; the planner re-checks after binding
+
+    def eval(self, ctx: EvalContext) -> AnyColumn:
+        """A decimal product is exact in 32-bit limbs, then HALF_UP to
+        the result's scale and NULL past its precision (Spark's
+        CheckOverflow around Multiply, non-ANSI)."""
+        from spark_rapids_tpu.exprs import decimal as DEC
+        from spark_rapids_tpu.ops import int128 as W
+
+        out = self.dtype
+        if not isinstance(out, T.DecimalType):
+            return super().eval(ctx)
+        lc = self.left.eval(ctx)
+        rc = self.right.eval(ctx)
+        valid = broadcast_validity(lc, rc)
+        W.note("multiply")
+        data, ok = W.multiply(DEC.operand_data(lc, self.left),
+                              DEC.layout(self.left),
+                              DEC.operand_data(rc, self.right),
+                              DEC.layout(self.right), out)
+        if ok is None:
+            return Column(data, valid, out)
+        W.note_overflow(valid, ok)
+        return Column(data, valid & ok, out)
 
     def compute(self, ld, rd, valid):
         return ld * rd, valid

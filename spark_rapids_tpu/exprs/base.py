@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import decimal
 import threading
 from typing import Any, Optional, Sequence
 
@@ -446,6 +447,9 @@ class Literal(Expression):
                 dtype = T.DOUBLE
             elif isinstance(v, str):
                 dtype = T.STRING
+            elif isinstance(v, decimal.Decimal):
+                # a Spark decimal literal: its own digits and scale
+                dtype = T.decimal_of_literal(v)
             else:
                 raise TypeError(f"cannot infer literal type of {v!r}")
         return Literal(v, dtype)
@@ -463,8 +467,19 @@ class Literal(Expression):
             return StringColumn(chars, lengths, valid)
         phys = T.to_numpy_dtype(self._dtype)
         v = self.value if self.value is not None else 0
-        data = jnp.full(cap, v, dtype=phys)
         valid = jnp.full(cap, self.value is not None) & ctx.row_mask
+        if isinstance(self._dtype, T.DecimalType):
+            # the unscaled value, in the column's one or two words
+            unscaled = int(decimal.Decimal(v).scaleb(self._dtype.scale))
+            if self._dtype.wide:
+                from spark_rapids_tpu.ops.int128 import literal_words
+
+                data = jnp.broadcast_to(
+                    jnp.asarray(literal_words(unscaled), jnp.int64),
+                    (cap, 2))
+                return Column(data, valid, self._dtype)
+            v = unscaled
+        data = jnp.full(cap, v, dtype=phys)
         return Column(data, valid, self._dtype)
 
 

@@ -60,19 +60,18 @@ def cast_supported(src: T.DataType, dst: T.DataType) -> bool:
     if ts is T.StringType and td in _INTEGRAL:
         return True
     if ts is T.DecimalType and td is T.DecimalType:
-        # pure widening only (no value can overflow the int64 unscaled
-        # backing): integral digits and scale both non-decreasing.  This
-        # is the shape UNION member coercion produces; a narrowing
-        # decimal cast (overflow -> NULL/ANSI raise) is future work.
-        return (dst.scale >= src.scale
-                and dst.precision - dst.scale >= src.precision - src.scale)
+        # any pair: the value rescales HALF_UP and a value past the
+        # target's precision is NULL (ANSI reports it)
+        return True
     if ts in _INTEGRAL and td is T.DecimalType:
         # widening only: the target must hold every integral digit the
         # source type can produce
         return (dst.precision - dst.scale
                 >= T.INTEGRAL_DECIMAL_DIGITS[ts])
     if ts is T.DecimalType and td in _FLOATING:
-        return True
+        # one int64 word over a power of ten; a two-word value has no
+        # correctly rounded conversion here yet
+        return not src.wide
     return False
 
 
@@ -126,14 +125,32 @@ class Cast(Expression):
         if ts is T.BooleanType:
             return Column(d.astype(T.to_numpy_dtype(dst)), valid, dst)
         if ts is T.DecimalType and td is T.DecimalType:
-            # rescale the int64 unscaled value; cast_supported admits
-            # only widening shapes, so the shift is >= 0 and the result
-            # provably fits MAX_PRECISION digits (no overflow check)
-            shift = dst.scale - src.scale
-            return Column(d.astype(jnp.int64) * (10 ** shift), valid, dst)
+            if (not src.wide and not dst.wide and dst.scale >= src.scale
+                    and dst.precision - dst.scale
+                    >= src.precision - src.scale):
+                # widening within one int64: the shift cannot overflow
+                shift = dst.scale - src.scale
+                return Column(d.astype(jnp.int64) * (10 ** shift), valid,
+                              dst)
+            from spark_rapids_tpu.ops import int128 as W
+
+            W.note("rescale")
+            data, ok = W.rescaled(d, src, dst)
+            W.note_overflow(valid, ok)
+            if ansi_active():
+                ansi_report(valid & ~ok,
+                            f"value out of range for {dst.name} "
+                            "(ANSI cast overflow)")
+            return Column(data, valid & ok, dst)
         if ts in _INTEGRAL and td is T.DecimalType:
-            # widening only (cast_supported): value * 10^scale fits the
-            # MAX_PRECISION-digit unscaled int64
+            # widening only (cast_supported): value * 10^scale fits
+            if dst.wide:
+                from spark_rapids_tpu.ops import int128 as W
+
+                data, _ = W.rescaled(
+                    d.astype(jnp.int64),
+                    T.DecimalType(T.DecimalType.NARROW_PRECISION, 0), dst)
+                return Column(data, valid, dst)
             return Column(d.astype(jnp.int64) * (10 ** dst.scale),
                           valid, dst)
         if ts is T.DecimalType and td in _FLOATING:

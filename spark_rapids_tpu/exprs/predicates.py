@@ -72,9 +72,26 @@ class BinaryComparison(Expression):
     def dtype(self) -> T.DataType:
         return T.BOOLEAN
 
+    def check_supported(self) -> None:
+        from spark_rapids_tpu.exprs import decimal as DEC
+
+        try:
+            pair = DEC.decimal_operands(self.left, self.right)
+        except RuntimeError:
+            return  # unbound; the planner re-checks after binding
+        if pair is not None and not DEC.fits_128(*pair):
+            raise TypeError(
+                "decimals whose values do not rescale to one scale "
+                "within precision 38 compare on the CPU")
+
     def _cmp_columns(self, lc: AnyColumn, rc: AnyColumn):
         if isinstance(lc, StringColumn) or isinstance(rc, StringColumn):
             return _string_cmp(lc, rc)
+        from spark_rapids_tpu.exprs import decimal as DEC
+
+        pair = DEC.decimal_pair_or_none(self.left, self.right)
+        if pair is not None:
+            return DEC.compare(lc, self.left, rc, self.right)
         ct = T.common_type(self.left.dtype, self.right.dtype) \
             or self.left.dtype
         phys = T.to_numpy_dtype(ct)

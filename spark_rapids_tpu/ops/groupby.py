@@ -67,6 +67,7 @@ from spark_rapids_tpu.columnar.column import (
     StringColumn,
     pad_capacity,
 )
+from spark_rapids_tpu.ops import int128 as W
 from spark_rapids_tpu.ops.sort import (
     SortOrder,
     sort_permutation,
@@ -123,12 +124,17 @@ class AggSpec:
     op: str
     ordinal: int  # ignored for count_star
     out_dtype: Optional[T.DataType] = None
+    #: the values are an update's inputs, not partial results: a
+    #: decimal sum then needs no term for partials' overflow marks
+    raw: bool = False
 
 
 def _sum_dtype(dt: T.DataType) -> T.DataType:
     if isinstance(dt, (T.FloatType, T.DoubleType)):
         return T.DOUBLE
     if isinstance(dt, T.DecimalType):
+        # Spark's Sum: decimal(min(p + 10, 38), s), held in 128 bits
+        # past precision 18
         return T.DecimalType(min(dt.precision + 10, T.DecimalType.MAX_PRECISION),
                              dt.scale)
     return T.LONG
@@ -299,6 +305,17 @@ def _coded_groupby(batch: ColumnarBatch, key_ordinals: Sequence[int],
             continue
         out_dtype = agg_output_dtype(spec, vcol.dtype)
         operand = (id(vcol.data), id(vcol.validity))
+        if isinstance(out_dtype, T.DecimalType):
+            # exact: the value's 32-bit parts, each summed on its own
+            # in the int64 matrix, settled once a group (ops/int128.py)
+            if out_dtype.wide:
+                W.note("sum")
+            marks = not spec.raw
+            terms = W.sum_terms(vcol.data, vcol.dtype, valid, marks)
+            at = [column_of(i64_cols, ("dec", marks, k) + operand,
+                            lambda t=t: t) for k, t in enumerate(terms)]
+            slots.append(("dec", at, nv, out_dtype, marks))
+            continue
         if np.dtype(T.to_numpy_dtype(out_dtype)).kind == "f":
             vs = column_of(f64_cols, ("f64",) + operand, lambda: jnp.where(
                 valid, vcol.data.astype(jnp.float64), 0.0))
@@ -386,6 +403,11 @@ def _coded_groupby(batch: ColumnarBatch, key_ordinals: Sequence[int],
             out_cols.append(Column(
                 Sic[:, vs].astype(T.to_numpy_dtype(out_dtype)),
                 group_live & (Sc[:, nv] > 0), out_dtype))
+        elif slot[0] == "dec":
+            _, at, nv, out_dtype, marks = slot
+            data, _ = W.settle([Sic[:, k] for k in at], out_dtype, marks)
+            out_cols.append(Column(data, group_live & (Sc[:, nv] > 0),
+                                   out_dtype))
         else:
             out_cols.append(_eval_agg(spec, batch, row_seg, live,
                                       group_live, out_cap, cap))
@@ -563,7 +585,8 @@ def rollup_write(sorted_batch: ColumnarBatch, breaks: jax.Array,
                  for lv in range(n_levels)]
         data, validity = parts[0].data, parts[0].validity
         for lv in range(1, n_levels):
-            data = jnp.where(in_level[lv], parts[lv].data, data)
+            pick = in_level[lv].reshape((-1,) + (1,) * (data.ndim - 1))
+            data = jnp.where(pick, parts[lv].data, data)
             validity = validity | parts[lv].validity
         out_cols.append(Column(data, validity, parts[0].dtype))
     assert len(out_schema) == len(out_cols)
@@ -608,6 +631,18 @@ def _eval_agg(spec: AggSpec, sorted_batch: ColumnarBatch, seg_id: jax.Array,
 
     out_dtype = agg_output_dtype(spec, vcol.dtype)
     phys = T.to_numpy_dtype(out_dtype)
+    if spec.op == "sum" and isinstance(out_dtype, T.DecimalType):
+        if out_dtype.wide:
+            W.note("sum")
+        marks = not spec.raw
+        sums = jax.ops.segment_sum(
+            jnp.stack(W.sum_terms(vcol.data, vcol.dtype, valid, marks),
+                      axis=1),
+            seg_id, num_segments=num_segments)
+        data, _ = W.settle(
+            [sums[:, k] for k in range(W.terms_of(vcol.dtype, marks))],
+            out_dtype, marks)
+        return Column(data, group_live & (nvalid > 0), out_dtype)
     if spec.op == "sum":
         vals = jnp.where(valid, vcol.data.astype(phys), jnp.asarray(0, phys))
         sums = jax.ops.segment_sum(vals, seg_id, num_segments=num_segments)
@@ -683,6 +718,17 @@ def reduce_aggregate(batch: ColumnarBatch, aggs: Sequence[AggSpec],
         assert isinstance(vcol, Column)
         out_dtype = agg_output_dtype(spec, vcol.dtype)
         phys = T.to_numpy_dtype(out_dtype)
+        if spec.op == "sum" and isinstance(out_dtype, T.DecimalType):
+            if out_dtype.wide:
+                W.note("sum")
+            marks = not spec.raw
+            sums = [jnp.sum(t)[None] for t in W.sum_terms(
+                vcol.data, vcol.dtype, valid, marks)]
+            s, _ = W.settle(sums, out_dtype, marks)
+            data = jnp.zeros((cap,) + s.shape[1:], phys).at[0].set(s[0])
+            out_cols.append(Column(data, one_live & (nvalid > 0),
+                                   out_dtype))
+            continue
         if spec.op == "sum":
             s = jnp.sum(jnp.where(valid, vcol.data.astype(phys),
                                   jnp.asarray(0, phys)))

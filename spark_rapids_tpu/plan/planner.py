@@ -61,11 +61,18 @@ from spark_rapids_tpu.exprs import math as M  # noqa: E402
 from spark_rapids_tpu.exprs import strings as S  # noqa: E402
 from spark_rapids_tpu.exprs.cast import Cast  # noqa: E402
 
-_PASSTHROUGH = TS.ExprSig(TS.ALL)
+_PASSTHROUGH = TS.ExprSig(TS.ALL + TS.DECIMAL128)
 _ARITH = TS.ExprSig(
     TS.NUMERIC + TS.NULLSIG,
     "decimal arithmetic falls back (unscaled-value math would be wrong)")
+_DECIMAL_ARITH = TS.ExprSig(
+    TS.NUMERIC + TS.DECIMAL + TS.DECIMAL128 + TS.NULLSIG,
+    "Spark's decimal result types; +/- whose operands do not rescale "
+    "into 38 digits, and a decimal beside a float, fall back")
 _COMPARE = TS.ExprSig(TS.ORDERABLE)
+_ORDER_COMPARE = TS.ExprSig(
+    TS.ORDERABLE + TS.DECIMAL128,
+    "decimals of two scales compare rescaled, within 38 digits")
 _LOGIC = TS.ExprSig(TS.BOOLEAN + TS.NULLSIG)
 _MATH = TS.ExprSig(TS.NUMERIC + TS.NULLSIG)
 _BITS = TS.ExprSig(TS.INTEGRAL + TS.NULLSIG)
@@ -77,22 +84,25 @@ _COND = TS.ExprSig(TS.ORDERABLE)
 for _sig, _classes in (
     (_PASSTHROUGH, (B.Alias, B.BoundReference, B.ColumnReference,
                     B.Literal)),
-    (TS.ExprSig(TS.NUMERIC + TS.DECIMAL + TS.NULLSIG,
-                "decimal results wider than precision 18 fall back"),
-     (A.Add, A.Subtract)),
-    (_ARITH, (A.Multiply, A.Divide, A.IntegralDivide,
+    (_DECIMAL_ARITH, (A.Add, A.Subtract, A.Multiply)),
+    (_ARITH, (A.Divide, A.IntegralDivide,
               A.Remainder, A.Pmod, A.UnaryMinus, A.UnaryPositive, A.Abs,
               A.Least, A.Greatest)),
-    (_COMPARE, (P.EqualTo, P.LessThan, P.LessThanOrEqual, P.GreaterThan,
-                P.GreaterThanOrEqual, P.EqualNullSafe, P.In)),
+    (_ORDER_COMPARE, (P.EqualTo, P.LessThan, P.LessThanOrEqual,
+                      P.GreaterThan, P.GreaterThanOrEqual)),
+    (_COMPARE, (P.EqualNullSafe, P.In)),
     (_LOGIC, (P.And, P.Or, P.Not)),
     (_PASSTHROUGH, (P.IsNull, P.IsNotNull, P.AtLeastNNonNulls)),
     (TS.ExprSig(TS.NUMERIC + TS.NULLSIG), (P.IsNaN,)),
     (_COND, (P.Coalesce, P.If, P.CaseWhen)),
     (TS.ExprSig(TS.COMMON_N), (Murmur3Hash,)),
     (TS.ExprSig(TS.STRING + TS.NULLSIG), (Md5,)),
-    (TS.ExprSig(TS.DECIMAL + TS.NULLSIG),
-     (DEC.PromotePrecision, DEC.CheckOverflow, DEC.UnscaledValue)),
+    (TS.ExprSig(TS.DECIMAL + TS.DECIMAL128 + TS.NULLSIG),
+     (DEC.PromotePrecision, DEC.CheckOverflow)),
+    (TS.ExprSig(TS.DECIMAL + TS.NULLSIG), (DEC.UnscaledValue,)),
+    (TS.ExprSig(TS.DECIMAL + TS.DECIMAL128 + TS.INTEGRAL + TS.NULLSIG,
+                "the finish of a decimal sum or average"),
+     (DEC.DecimalSumResult, DEC.DecimalAverage)),
     (TS.ExprSig(TS.INTEGRAL + TS.DECIMAL + TS.NULLSIG),
      (DEC.MakeDecimal,)),
     (_MATH, (M.Sqrt, M.Cbrt, M.Exp, M.Expm1, M.Sin, M.Cos, M.Tan, M.Cot,
@@ -119,7 +129,8 @@ for _sig, _classes in (
             S.StringReplace, S.RegExpReplace, S.StringLPad, S.StringRPad,
             S.StringLocate, S.SubstringIndex, S.InitCap, S.ConcatWs,
             S.StringSplit, S.SplitPart, S.GetJsonObject)),
-    (TS.ExprSig(TS.ALL, "per-pair support matrix in check_supported"),
+    (TS.ExprSig(TS.ALL + TS.DECIMAL128,
+                "per-pair support matrix in check_supported"),
      (Cast,)),
 ):
     for _cls in _classes:
@@ -178,11 +189,15 @@ AGG_SIGS: dict[type, TS.ExprSig] = {
     AG.CollectSet: TS.ExprSig(
         TS.NUMERIC + TS.DATETIME + TS.BOOLEAN + TS.NULLSIG,
         "fixed-width elements only"),
-    AG.Sum: TS.ExprSig(TS.NUMERIC + TS.DECIMAL + TS.NULLSIG),
-    AG.Average: TS.ExprSig(TS.NUMERIC + TS.NULLSIG,
-                           "decimal avg needs scale-aware division"),
-    AG.Count: TS.ExprSig(TS.ALL),
-    AG.CountStar: TS.ExprSig(TS.ALL),
+    AG.Sum: TS.ExprSig(TS.NUMERIC + TS.DECIMAL + TS.DECIMAL128
+                       + TS.NULLSIG,
+                       "decimal sums in 128 bits, NULL on overflow"),
+    AG.Average: TS.ExprSig(TS.NUMERIC + TS.DECIMAL + TS.DECIMAL128
+                           + TS.NULLSIG,
+                           "decimal avg as Spark's Average: sum / "
+                           "count, rounded HALF_UP twice"),
+    AG.Count: TS.ExprSig(TS.ALL + TS.DECIMAL128),
+    AG.CountStar: TS.ExprSig(TS.ALL + TS.DECIMAL128),
     AG.Min: TS.ExprSig(TS.NUMERIC + TS.DECIMAL + TS.DATETIME
                        + TS.BOOLEAN + TS.NULLSIG,
                        "string min/max falls back"),
@@ -306,6 +321,19 @@ class PlanMeta:
         for c in self.children:
             c.tag()
 
+    def _forbid_wide_key(self, e, where: str) -> None:
+        """Keys are hashed, sorted and compared, and window frames
+        accumulated, as one word: a decimal past precision 18 (two
+        words) there runs on the CPU."""
+        try:
+            dt = e.dtype
+        except Exception:
+            return
+        if T.is_wide_decimal(dt):
+            self.will_not_work(
+                f"{dt.name} as {where} is not supported on TPU "
+                "(decimal keys past precision 18)")
+
     def _forbid_ansi_risky(self, e, where: str) -> None:
         """ANSI error flags are captured only by the FUSED
         project/filter/expand/generate pipelines; an overflow-capable
@@ -343,6 +371,7 @@ class PlanMeta:
         elif isinstance(p, L.Aggregate):
             for g in p.groups:
                 _check_expr(g, conf, self.reasons)
+                self._forbid_wide_key(g, "grouping key")
                 self._forbid_partition_aware(g, "grouping key")
                 self._forbid_ansi_risky(g, "grouping key")
             for na in p.aggs:
@@ -359,12 +388,14 @@ class PlanMeta:
         elif isinstance(p, L.Sort):
             for k in p.keys:
                 _check_expr(k.expr, conf, self.reasons)
+                self._forbid_wide_key(k.expr, "sort key")
                 self._forbid_partition_aware(k.expr, "sort key")
                 self._forbid_ansi_risky(k.expr, "sort key")
         elif isinstance(p, L.Window):
             for we, _name in p.window_exprs:
                 for e in we.children:
                     _check_expr(e, conf, self.reasons)
+                    self._forbid_wide_key(e, "window input")
                     self._forbid_partition_aware(e, "window input")
                     self._forbid_ansi_risky(e, "window input")
                 try:
@@ -374,6 +405,7 @@ class PlanMeta:
         elif isinstance(p, L.Join):
             for e in list(p.left_keys) + list(p.right_keys):
                 _check_expr(e, conf, self.reasons)
+                self._forbid_wide_key(e, "join key")
                 self._forbid_partition_aware(e, "join key")
                 self._forbid_ansi_risky(e, "join key")
             if p.condition is not None:

@@ -12,7 +12,11 @@ later, as the reference does).  Tagging walks each expression tree and
 turns every unsupported child dtype into a will-not-work reason — so a
 decimal multiply or an array-typed comparison falls back to the CPU
 engine with an explanation instead of silently computing wrong results
-or crashing mid-kernel."""
+or crashing mid-kernel.
+
+A decimal past precision 18 is its own kind, `decimal128`: it is held
+in two int64 words (`types.py`), and only the rules that list the kind
+take it, so every other operator refuses it by name."""
 
 from __future__ import annotations
 
@@ -40,11 +44,13 @@ _KIND_OF = {
 }
 
 KIND_ORDER = ["boolean", "byte", "short", "int", "long", "float",
-              "double", "decimal", "string", "date", "timestamp",
+              "double", "decimal", "decimal128", "string", "date", "timestamp",
               "null", "array", "struct", "map"]
 
 
 def kind_of(dtype: T.DataType) -> str:
+    if T.is_wide_decimal(dtype):
+        return "decimal128"
     return _KIND_OF[type(dtype)]
 
 
@@ -74,6 +80,7 @@ NUMERIC = INTEGRAL + TypeSig.of("float", "double")
 STRING = TypeSig.of("string")
 DATETIME = TypeSig.of("date", "timestamp")
 DECIMAL = TypeSig.of("decimal")
+DECIMAL128 = TypeSig.of("decimal128")
 NULLSIG = TypeSig.of("null")
 ARRAY = TypeSig.of("array")
 STRUCT = TypeSig.of("struct")

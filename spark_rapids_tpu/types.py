@@ -12,6 +12,11 @@ Physical mapping (TPU-first, not a cudf translation):
   (the reference is likewise UTC-only, GpuOverrides.scala:439);
 - DECIMAL(p<=18, s) -> int64 unscaled values (the reference uses
   DECIMAL64, DecimalUtil.scala);
+- DECIMAL(18<p<=38, s) -> a `(capacity, 2)` int64 array per column: the
+  128-bit two's-complement unscaled value as `[:, 0]` its low word (the
+  bits of a uint64) and `[:, 1]` its high word, signed.  That is
+  Arrow's decimal128 memory order, so a fetch is one buffer view; the
+  arithmetic over it lives in `ops/int128.py`;
 - STRING -> fixed-width uint8 byte matrix (n, width) + int32 lengths.
   XLA wants static shapes, so variable-width UTF-8 is padded to the
   batch's max byte length instead of cudf's offset+chars layout.
@@ -118,11 +123,14 @@ class TimestampType(DataType):
 
 @dataclasses.dataclass(frozen=True)
 class DecimalType(DataType):
-    """Decimal with precision <= 18 backed by int64 unscaled values."""
+    """Decimal with precision <= 38: unscaled values in one int64 up to
+    precision NARROW_PRECISION, in two int64 words past it."""
 
     precision: int = 10
     scale: int = 0
-    MAX_PRECISION = 18
+    MAX_PRECISION = 38
+    #: widest precision one int64 holds
+    NARROW_PRECISION = 18
 
     def __post_init__(self):
         if self.precision > self.MAX_PRECISION:
@@ -137,6 +145,11 @@ class DecimalType(DataType):
     @property
     def is_numeric(self) -> bool:
         return True
+
+    @property
+    def wide(self) -> bool:
+        """Held in two int64 words (precision past NARROW_PRECISION)."""
+        return self.precision > self.NARROW_PRECISION
 
     def __eq__(self, other) -> bool:
         return (
@@ -272,6 +285,37 @@ _NUMPY_DTYPES = {
 }
 
 
+def is_wide_decimal(dt: DataType) -> bool:
+    return isinstance(dt, DecimalType) and dt.wide
+
+
+def adjust_precision_scale(precision: int, scale: int) -> DecimalType:
+    """Spark's `DecimalType.adjustPrecisionScale` under
+    `spark.sql.decimalOperations.allowPrecisionLoss=true`: a result
+    wider than 38 digits keeps its integral digits and gives up scale,
+    down to min(scale, 6)."""
+    if precision <= DecimalType.MAX_PRECISION:
+        return DecimalType(precision, scale)
+    integral = precision - scale
+    min_scale = min(scale, 6)
+    return DecimalType(DecimalType.MAX_PRECISION,
+                       max(DecimalType.MAX_PRECISION - integral, min_scale))
+
+
+def decimal_of_literal(value) -> "DecimalType":
+    """The decimal type Spark gives a literal beside a decimal operand:
+    an integer's fewest digits (`DecimalType.fromLiteral` under
+    `spark.sql.legacy.literal.pickMinimumPrecision=true`), a
+    `decimal.Decimal`'s own digits and scale."""
+    import decimal
+
+    if isinstance(value, decimal.Decimal):
+        sign, digits, exp = value.as_tuple()
+        scale = max(-exp, 0)
+        return DecimalType(max(len(digits) + max(exp, 0), scale, 1), scale)
+    return DecimalType(max(len(str(abs(int(value)))), 1), 0)
+
+
 def to_numpy_dtype(dt: DataType) -> np.dtype:
     """Physical numpy/JAX dtype backing a fixed-width SQL type."""
     try:
@@ -309,7 +353,8 @@ def from_arrow_type(at) -> DataType:
     if pa.types.is_timestamp(at):
         return TIMESTAMP
     if pa.types.is_decimal(at):
-        if at.precision > DecimalType.MAX_PRECISION:
+        if at.precision > DecimalType.MAX_PRECISION \
+                or not pa.types.is_decimal128(at):
             raise TypeError(f"decimal precision {at.precision} unsupported")
         return DecimalType(at.precision, at.scale)
     if pa.types.is_list(at) or pa.types.is_large_list(at):
@@ -421,13 +466,13 @@ def common_type(a: DataType, b: DataType) -> Optional[DataType]:
         return [BYTE, SHORT, INT, LONG, FLOAT, DOUBLE][max(order[ta], order[tb])]
     if ta is DecimalType and tb is DecimalType:
         # Spark's DecimalPrecision.widerDecimalType: keep every integral
-        # digit and every fractional digit of both sides.  Past the
-        # int64-backed MAX_PRECISION Spark starts dropping scale; this
-        # engine cannot (no 128-bit unscaled), so that pair has no
-        # lossless common type here.
+        # digit and every fractional digit of both sides.  Widening
+        # (unions, conditionals, comparisons) stays within one int64:
+        # past NARROW_PRECISION the pair has no common type here, and a
+        # comparison of two decimals rescales them itself
         scale = max(a.scale, b.scale)
         integral = max(a.precision - a.scale, b.precision - b.scale)
-        if integral + scale > DecimalType.MAX_PRECISION:
+        if integral + scale > DecimalType.NARROW_PRECISION:
             return None
         return DecimalType(integral + scale, scale)
     if ta is DecimalType or tb is DecimalType:
@@ -436,13 +481,13 @@ def common_type(a: DataType, b: DataType) -> Optional[DataType]:
             # Spark's DecimalPrecision: decimal + fractional -> double
             return DOUBLE
         # integral -> decimal via DecimalType.forType digit counts;
-        # LONG needs 19 integral digits, past the int64-backed
-        # MAX_PRECISION, so decimal+long has no lossless common type
+        # LONG needs 19 integral digits, past NARROW_PRECISION, so
+        # decimal+long has no common type for widening
         digits = INTEGRAL_DECIMAL_DIGITS.get(type(other))
         if digits is None:
             return None
         integral = max(dec.precision - dec.scale, digits)
-        if integral + dec.scale > DecimalType.MAX_PRECISION:
+        if integral + dec.scale > DecimalType.NARROW_PRECISION:
             return None
         return DecimalType(integral + dec.scale, dec.scale)
     if {ta, tb} == {DateType, TimestampType}:

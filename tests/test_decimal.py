@@ -93,6 +93,10 @@ def test_mismatched_decimal_add_widens(session):
 
 
 def test_decimal_add_beyond_precision_falls_back(session):
+    """decimal(18,2) + decimal(18,2) is decimal(19,2), now a 128-bit
+    add on the device; an add whose operands do not rescale within
+    precision 38 (decimal(38,0) + decimal(38,2) would need 40 digits)
+    still falls back to the CPU engine, by name."""
     t = pa.table({
         "a": pa.array([D("1.10")], pa.decimal128(18, 2)),
         "b": pa.array([D("1.10")], pa.decimal128(18, 2)),
@@ -102,8 +106,20 @@ def test_decimal_add_beyond_precision_falls_back(session):
     from spark_rapids_tpu.plan.planner import plan_query, CpuFallbackExec
 
     exec_, meta = plan_query(df._plan)
-    assert isinstance(exec_, CpuFallbackExec), meta.explain()
+    assert not isinstance(exec_, CpuFallbackExec), meta.explain()
+    assert df.schema.fields[0].dtype == T.DecimalType(19, 2)
     assert df.collect(engine="tpu").to_pydict()["s"] == [D("2.20")]
+
+    wide = pa.table({
+        "a": pa.array([D("1")], pa.decimal128(38, 0)),
+        "b": pa.array([D("1.10")], pa.decimal128(38, 2)),
+    })
+    df = session.create_dataframe(wide).select((col("a") + col("b"))
+                                               .alias("s"))
+    exec_, meta = plan_query(df._plan)
+    assert isinstance(exec_, CpuFallbackExec), meta.explain()
+    assert "within precision 38 falls back" in meta.explain()
+    assert df.collect(engine="tpu").to_pydict()["s"] == [D("2.10")]
 
 
 def test_check_overflow_scale_up_wraparound(session):
@@ -121,12 +137,25 @@ def test_check_overflow_scale_up_wraparound(session):
 
 
 def test_wide_decimal_fallback_nulls_not_crashes(session):
-    """CPU-fallback decimal multiply beyond the 18-digit engine cap
-    returns NULL (documented divergence) instead of raising."""
+    """A decimal product past 38 digits is NULL (Spark's non-ANSI
+    CheckOverflow), computed on the device: decimal(18,0)^2 is
+    decimal(37,0) and holds 10^32; decimal(38,0)^2 is cut to
+    decimal(38,0), which 10^40 overflows."""
     t = pa.table({"d": pa.array([D("10000000000000000"), D("2")],
                                 pa.decimal128(18, 0))})
     df = session.create_dataframe(t).select((col("d") * col("d"))
                                             .alias("sq"))
+    assert "does not support" not in df.explain()
     out = df.collect(engine="tpu").to_pydict()["sq"]
-    assert out[0] is None  # 10^32 cannot fit 18 digits
+    assert out == [D("100000000000000000000000000000000"), D("4")]
+    assert out == df.collect(engine="cpu").to_pydict()["sq"]
+
+    t = pa.table({"d": pa.array([D("10") ** 20, D("2")],
+                                pa.decimal128(38, 0))})
+    df = session.create_dataframe(t).select((col("d") * col("d"))
+                                            .alias("sq"))
+    assert df.schema.fields[0].dtype == T.DecimalType(38, 0)
+    out = df.collect(engine="tpu").to_pydict()["sq"]
+    assert out[0] is None  # 10^40 cannot fit 38 digits
     assert out[1] == D("4")
+    assert out == df.collect(engine="cpu").to_pydict()["sq"]

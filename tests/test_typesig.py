@@ -16,9 +16,9 @@ def session():
 
 
 def test_decimal_add_on_tpu_multiply_falls_back(session):
-    """Decimal +/- runs on TPU as unscaled int64 math (widened result
-    type); Multiply still refuses decimals and falls back with a
-    reason."""
+    """Decimal +/- and * run on TPU with Spark's result types (the
+    product exact in 128 bits); an arithmetic rule that still refuses
+    decimals (remainder) falls back with a reason."""
     t = pa.table({"d": pa.array(
         [decimal.Decimal("1.25"), decimal.Decimal("-2.50"), None],
         pa.decimal128(10, 2))})
@@ -31,10 +31,14 @@ def test_decimal_add_on_tpu_multiply_falls_back(session):
 
     dfm = session.create_dataframe(t).select(
         (col("d") * col("d")).alias("sq"))
-    why = dfm.explain()
+    assert "does not support" not in dfm.explain()
+    assert dfm.collect().to_pydict()["sq"] == [
+        decimal.Decimal("1.5625"), decimal.Decimal("6.2500"), None]
+
+    dfr = session.create_dataframe(t).select(
+        (col("d") % col("d")).alias("r"))
+    why = dfr.explain()
     assert "does not support input type decimal(10,2)" in why, why
-    assert dfm.collect().to_pydict()["sq"][0] == \
-        decimal.Decimal("1.5625")  # CPU fallback computes it right
 
 
 def test_decimal_sum_stays_on_tpu(session):
